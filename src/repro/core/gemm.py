@@ -46,7 +46,6 @@ preconditions, and fallback reasons.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Optional
 
 import jax
@@ -83,15 +82,11 @@ def _axis_size(mesh: Mesh, name: str) -> int:
     return mesh.shape[name]
 
 
-@contextlib.contextmanager
 def _mode_scope(mode: str):
-    """Name every mode's dispatch for both profiling surfaces: the HLO ops
-    it traces (`jax.named_scope` — a device profile / xprof segments by
-    `dit_gemm.<mode>`) and the host-side trace-time work
-    (`jax.profiler.TraceAnnotation`)."""
-    name = f"dit_gemm.{mode}"
-    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
-        yield
+    """Name the HLO ops every mode's dispatch traces (`jax.named_scope`):
+    a device profile segments by `dit_gemm.<mode>`. The host-side
+    trace-time work is `pmm`'s span (`pmm.<tag>`)."""
+    return jax.named_scope(f"dit_gemm.{mode}")
 
 
 # ---------------------------------------------------------------------------

@@ -266,8 +266,11 @@ def gqa_attention(p: Params, x: jax.Array, cfg: ModelConfig,
         new_cache = None
     else:
         idx = cache["index"]                              # scalar int32
-        ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, idx, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, idx, axis=1)
+        with jax.named_scope("kv_cache.write"):
+            ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, idx,
+                                                     axis=1)
+            cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, idx,
+                                                     axis=1)
         kv_len = jnp.full((b,), idx + s, dtype=jnp.int32)
         # thread the caller's causal flag — hard-coding True here broke
         # non-causal decode (prefix-LM scoring attends to the whole prefix)
@@ -339,8 +342,11 @@ def mla_attention(p: Params, x: jax.Array, cfg: ModelConfig,
 
     if cache is not None:
         idx = cache["index"]
-        c_kv = jax.lax.dynamic_update_slice_in_dim(cache["c_kv"], c_kv, idx, axis=1)
-        k_r = jax.lax.dynamic_update_slice_in_dim(cache["k_rope"], k_r, idx, axis=1)
+        with jax.named_scope("kv_cache.write"):
+            c_kv = jax.lax.dynamic_update_slice_in_dim(cache["c_kv"], c_kv,
+                                                       idx, axis=1)
+            k_r = jax.lax.dynamic_update_slice_in_dim(cache["k_rope"], k_r,
+                                                      idx, axis=1)
         new_cache = {"c_kv": c_kv, "k_rope": k_r, "index": idx + s}
         kv_len = idx + s
     else:

@@ -105,21 +105,23 @@ def norm_params(cfg: ModelConfig) -> Params:
 
 
 def apply_norm(p: Params, x: jax.Array, cfg: ModelConfig, eps: float = 1e-6) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    if cfg.norm == "rmsnorm":
-        xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-        xf = xf * p["scale"]
-    elif cfg.norm == "layernorm":
-        mu = jnp.mean(xf, axis=-1, keepdims=True)
-        var = jnp.var(xf, axis=-1, keepdims=True)
-        xf = (xf - mu) * jax.lax.rsqrt(var + eps) * p["scale"]
-    elif cfg.norm == "nonparam_ln":  # OLMo: no learned scale/bias
-        mu = jnp.mean(xf, axis=-1, keepdims=True)
-        var = jnp.var(xf, axis=-1, keepdims=True)
-        xf = (xf - mu) * jax.lax.rsqrt(var + eps)
-    else:
-        raise ValueError(cfg.norm)
-    return xf.astype(x.dtype)
+    with jax.named_scope("norm"):
+        xf = x.astype(jnp.float32)
+        if cfg.norm == "rmsnorm":
+            xf = xf * jax.lax.rsqrt(
+                jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+            xf = xf * p["scale"]
+        elif cfg.norm == "layernorm":
+            mu = jnp.mean(xf, axis=-1, keepdims=True)
+            var = jnp.var(xf, axis=-1, keepdims=True)
+            xf = (xf - mu) * jax.lax.rsqrt(var + eps) * p["scale"]
+        elif cfg.norm == "nonparam_ln":  # OLMo: no learned scale/bias
+            mu = jnp.mean(xf, axis=-1, keepdims=True)
+            var = jnp.var(xf, axis=-1, keepdims=True)
+            xf = (xf - mu) * jax.lax.rsqrt(var + eps)
+        else:
+            raise ValueError(cfg.norm)
+        return xf.astype(x.dtype)
 
 
 def rms_head_norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -138,11 +140,12 @@ def rope_tables(positions: jax.Array, dim: int, theta: float) -> Tuple[jax.Array
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     """x: (..., seq, heads, dim); cos/sin: (..., seq, dim/2)."""
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    c = cos[..., None, :]
-    s = sin[..., None, :]
-    return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c],
-                           axis=-1).astype(x.dtype)
+    with jax.named_scope("rope"):
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        c = cos[..., None, :]
+        s = sin[..., None, :]
+        return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c],
+                               axis=-1).astype(x.dtype)
 
 
 def activation(cfg: ModelConfig, gate: jax.Array, up: jax.Array) -> jax.Array:

@@ -144,7 +144,16 @@ def _dispatch_routed(ctx, x: jax.Array, w: jax.Array, shape: GEMMShape,
 
 
 def pmm(x: jax.Array, w: jax.Array, tag: str = "") -> jax.Array:
-    """Plan-routed `x @ w`. x: (..., K); w: (K, N) -> (..., N)."""
+    """Plan-routed `x @ w`. x: (..., K); w: (K, N) -> (..., N).
+
+    Every call, routed or not, emits its ops under
+    `jax.named_scope("pmm.<tag>")` (outside the routed `dit_gemm.<mode>`),
+    so a device profile attributes each GEMM to its call site."""
+    with jax.named_scope(f"pmm.{tag or 'untagged'}"):
+        return _pmm(x, w, tag)
+
+
+def _pmm(x: jax.Array, w: jax.Array, tag: str) -> jax.Array:
     ctx = shard_ctx.get_gemm_context()
     if ctx is None:
         return x @ w
@@ -256,7 +265,14 @@ def pattn(q: jax.Array, k: jax.Array, v: jax.Array, *, unfused,
     """Plan-routed attention. q: (b, sq, h, d); k/v: (b, skv, hkv, d|dv) ->
     (b, sq, h, dv). `unfused` is the zero-arg reference path the call
     degrades to when routing is off or the lowering says fused is illegal
-    — every degrade is counted and carries a machine-readable reason."""
+    — every degrade is counted and carries a machine-readable reason.
+    Either path emits its ops under `jax.named_scope("pattn.<tag>")`."""
+    with jax.named_scope(f"pattn.{tag or 'untagged'}"):
+        return _pattn(q, k, v, unfused, causal, tag, scale, q_positions,
+                      kv_len)
+
+
+def _pattn(q, k, v, unfused, causal, tag, scale, q_positions, kv_len):
     ctx = shard_ctx.get_gemm_context()
     if ctx is None:
         return unfused()

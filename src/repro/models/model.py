@@ -194,7 +194,8 @@ def _scan_group(stacked: Params, x, fn, remat: bool = True):
     """Run a stacked layer group as lax.scan over the leading axis (python
     loop in accounting mode so cost_analysis sees every layer)."""
     def pinned(layer_params, carry):
-        return shard_ctx.constrain_tokens(fn(layer_params, carry))
+        with jax.named_scope("block"):
+            return shard_ctx.constrain_tokens(fn(layer_params, carry))
 
     body = pinned
     if remat:
@@ -225,23 +226,26 @@ def forward(params: Params, tokens: jax.Array, cfg: ModelConfig,
     prefix_embeds: VLM/audio stub frontend output prepended to the sequence.
     encoder_embeds: enc-dec source-side embeddings (audio frames).
     """
-    x = shard_ctx.constrain_tokens(
-        jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype))
-    if prefix_embeds is not None:
-        pe = pmm(prefix_embeds.astype(cfg.dtype), params["frontend_proj"],
-                 tag="frontend.proj")
-        x = jnp.concatenate([pe, x], axis=1)
+    with jax.named_scope("embed"):
+        x = shard_ctx.constrain_tokens(
+            jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype))
+        if prefix_embeds is not None:
+            pe = pmm(prefix_embeds.astype(cfg.dtype), params["frontend_proj"],
+                     tag="frontend.proj")
+            x = jnp.concatenate([pe, x], axis=1)
     b, s, _ = x.shape
     positions = jnp.arange(s)
 
     if cfg.is_encoder_decoder:
-        enc = pmm(encoder_embeds.astype(cfg.dtype), params["frontend_proj"],
-                  tag="frontend.proj")
+        with jax.named_scope("embed"):
+            enc = pmm(encoder_embeds.astype(cfg.dtype),
+                      params["frontend_proj"], tag="frontend.proj")
         enc = _scan_group(
             params["encoder"], enc,
             lambda p, h: _attn_block(p, h, cfg, jnp.arange(enc.shape[1]),
                                      causal=False)[0], remat)
-        enc = apply_norm(params["ln_enc"], enc, cfg)
+        with jax.named_scope("final_norm"):
+            enc = apply_norm(params["ln_enc"], enc, cfg)
         x = _scan_group(
             params["decoder"], x,
             lambda p, h: _attn_block(p, h, cfg, positions, enc_out=enc)[0],
@@ -296,14 +300,16 @@ def forward(params: Params, tokens: jax.Array, cfg: ModelConfig,
     else:
         raise ValueError(cfg.block_pattern)
 
-    x = apply_norm(params["ln_f"], x, cfg)
+    with jax.named_scope("final_norm"):
+        x = apply_norm(params["ln_f"], x, cfg)
     if return_hidden:
         return x
     # tied-embedding logits: x @ embed.T is the same dot_general the einsum
     # lowered to, expressed as a routable dense GEMM (lm_head_weight
     # transposes for the tied case)
-    logits = pmm(x, lm_head_weight(params, cfg), tag="lm_head")
-    return logits.astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = pmm(x, lm_head_weight(params, cfg), tag="lm_head")
+        return logits.astype(jnp.float32)
 
 
 def lm_head_weight(params: Params, cfg: ModelConfig) -> jax.Array:
@@ -366,24 +372,35 @@ def decode_step(params: Params, caches: Dict[str, Any], tokens: jax.Array,
                 position: jax.Array, cfg: ModelConfig,
                 encoder_out: Optional[jax.Array] = None
                 ) -> Tuple[jax.Array, Dict[str, Any]]:
-    """One decode step: tokens (B, 1) -> logits (B, vocab), updated caches."""
-    x = shard_ctx.constrain_tokens(
-        jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype))
+    """One decode step: tokens (B, 1) -> logits (B, vocab), updated caches.
+
+    Every op the step emits sits under one top-level `jax.named_scope`
+    (`embed`, `block`, `final_norm`, `lm_head`), except the layer scan's
+    own slicing of the stacked weights and caches and its stacking of the
+    new caches, which stay outside `block` so a device profile tells the
+    plumbing from the layers (docs/observability.md)."""
+    with jax.named_scope("embed"):
+        x = shard_ctx.constrain_tokens(
+            jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype))
     positions = position[None] if position.ndim == 0 else position
 
     def scan_layers(stacked_params, stacked_cache, x, block_fn):
+        def block(lp, h, lc):
+            with jax.named_scope("block"):
+                return block_fn(lp, h, lc)
+
         if accounting.UNROLL_LAYERS:
             n = jax.tree.leaves(stacked_params)[0].shape[0]
             new_cs = []
             for i in range(n):
-                x, nc = block_fn(jax.tree.map(lambda a: a[i], stacked_params),
-                                 x, jax.tree.map(lambda a: a[i], stacked_cache))
+                x, nc = block(jax.tree.map(lambda a: a[i], stacked_params),
+                              x, jax.tree.map(lambda a: a[i], stacked_cache))
                 new_cs.append(nc)
             return x, jax.tree.map(lambda *xs: jnp.stack(xs), *new_cs)
 
         def step(carry, pc):
             lp, lc = pc
-            out, new_c = block_fn(lp, carry, lc)
+            out, new_c = block(lp, carry, lc)
             return out, new_c
         x, new_caches = jax.lax.scan(step, x, (stacked_params, stacked_cache))
         return x, new_caches
@@ -423,9 +440,10 @@ def decode_step(params: Params, caches: Dict[str, Any], tokens: jax.Array,
                 pp, cc = pcc
                 out, nc = _mamba_block(pp, c, cfg, state=cc)
                 return out, nc
-            h, new_cm = accounting.scan(inner, h, (pm, cm))
-            h, new_ca = _attn_block(params["shared_attn"], h, cfg, positions,
-                                    cache=ca)
+            with jax.named_scope("block"):
+                h, new_cm = accounting.scan(inner, h, (pm, cm))
+                h, new_ca = _attn_block(params["shared_attn"], h, cfg,
+                                        positions, cache=ca)
             return h, (new_cm, new_ca)
 
         x, (new_cm, new_ca) = accounting.scan(
@@ -454,8 +472,9 @@ def decode_step(params: Params, caches: Dict[str, Any], tokens: jax.Array,
                 pp, cc = pcc
                 out, nc = _mlstm_block(pp, c, cfg, state=cc)
                 return out, nc
-            h, new_cm = accounting.scan(inner, h, (pm, cm))
-            h, new_csl = _slstm_block(psl, h, cfg, state=csl)
+            with jax.named_scope("block"):
+                h, new_cm = accounting.scan(inner, h, (pm, cm))
+                h, new_csl = _slstm_block(psl, h, cfg, state=csl)
             return h, (new_cm, new_csl)
 
         x, (new_cm, new_csl) = accounting.scan(
@@ -465,6 +484,9 @@ def decode_step(params: Params, caches: Dict[str, Any], tokens: jax.Array,
             lambda a: a.reshape(n_super * (per - 1), *a.shape[2:]), new_cm)
         new_caches["slstm"] = new_csl
 
-    x = apply_norm(params["ln_f"], x, cfg)
-    logits = pmm(x, lm_head_weight(params, cfg), tag="lm_head")
-    return logits[:, -1].astype(jnp.float32), new_caches
+    with jax.named_scope("final_norm"):
+        x = apply_norm(params["ln_f"], x, cfg)
+    with jax.named_scope("lm_head"):
+        logits = pmm(x, lm_head_weight(params, cfg), tag="lm_head")
+        logits = logits[:, -1].astype(jnp.float32)
+    return logits, new_caches

@@ -9,21 +9,28 @@ permanently (benchmarks/tracing_bench.py asserts the bound).
 Spans are *host-side trace-time* measurements: GEMM shapes are static
 under jit, so a `pmm` span covers the plan consult + schedule lowering +
 shard_map tracing of one callsite, not the per-step device execution
-(device-side segmentation is `core.gemm`'s `jax.named_scope` wrapping —
-see docs/observability.md). Each span carries the dispatch provenance
-(`tag`, shape, hit/bucketed/fallback, plan + calibration digests, resolved
-mode, fallback reasons, predicted cost), which is also what
+(device-side segmentation is the model's `jax.named_scope`s: `embed`,
+`block`, `pmm.<tag>`, `dit_gemm.<mode>`, ... — see docs/observability.md).
+Each span carries the dispatch provenance (`tag`, shape,
+hit/bucketed/fallback, plan + calibration digests, resolved mode, fallback
+reasons, predicted cost), which is also what
 `obs.report.dispatch_provenance` lifts into the run report.
 
 Export is the Chrome trace-event JSON format, loadable directly at
 https://ui.perfetto.dev: complete events (`ph: "X"`, microsecond `ts`/
 `dur`) plus `ph: "M"` process-name metadata.
+
+Where jax is already imported, every span also enters
+`jax.profiler.TraceAnnotation(name)`, so it lands in the host plane of any
+`jax.profiler` capture, on the clock the device trace is aligned to. The
+package never imports jax itself.
 """
 from __future__ import annotations
 
 import contextlib
 import json
 import os
+import sys
 import tempfile
 import time
 from typing import Any, Dict, Iterator, List, Optional
@@ -59,15 +66,21 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, name: str, cat: str = CAT_PMM,
              **args: Any) -> Iterator[Dict[str, Any]]:
-        """A complete ("X") trace event around the block.
+        """A complete ("X") trace event around the block, and a
+        `jax.profiler.TraceAnnotation` of the same name once jax is
+        imported.
 
         Yields the event's mutable `args` dict so callers can attach
         provenance discovered mid-span (resolved mode, plan digest, ...).
         """
         span_args: Dict[str, Any] = dict(args)
+        jax = sys.modules.get("jax")
+        annotation = (contextlib.nullcontext() if jax is None
+                      else jax.profiler.TraceAnnotation(name))
         t0 = self._now_us()
         try:
-            yield span_args
+            with annotation:
+                yield span_args
         finally:
             dur = self._now_us() - t0
             span_args["dur_us"] = round(dur, 1)

@@ -414,3 +414,40 @@ def test_serve_run_report_multidevice(tmp_path):
     assert proc.returncode == 0, (f"stdout:\n{proc.stdout}\n"
                                   f"stderr:\n{proc.stderr}")
     assert "ALL_OK" in proc.stdout
+
+
+# ---------------------------------------------------------------------------
+# one clock: tracer spans land in a jax.profiler capture
+# ---------------------------------------------------------------------------
+
+def test_span_lands_in_profiler_capture(tmp_path):
+    """A `maybe_span` under an installed tracer is also a host event of a
+    `jax.profiler` capture, on the clock the device trace is aligned to."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracing(Tracer()) as tracer:
+            with maybe_span("serve.probe_step", position=1):
+                jnp.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    assert [e["name"] for e in tracer.events] == ["serve.probe_step"]
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    host = [e for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:") for line in plane.lines
+            for e in line.events if e.name == "serve.probe_step"]
+    assert len(host) == 1 and host[0].duration_ns > 0
+
+
+def test_obs_imports_without_jax():
+    code = ("import sys, repro.obs; "
+            "assert 'jax' not in sys.modules, 'repro.obs loaded jax'")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src")]
+        + os.environ.get("PYTHONPATH", "").split(os.pathsep)))
+    subprocess.run([sys.executable, "-c", code], check=True, env=env)

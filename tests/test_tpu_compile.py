@@ -36,6 +36,7 @@ from repro.launch.mesh import make_mesh
 from repro.models import shard_ctx
 from repro.models.model import decode_init, init_params
 from repro.train.steps import make_serve_step
+from serve_scopes import assert_names_every_part
 
 BATCH, DECODE_MAX_LEN, PREFILL_LEN = 8, 2048, 256
 OLMO = get_config("olmo-1b")
@@ -140,7 +141,8 @@ def test_flat_attention_merge_compiles(chip_mesh, one_chip, tpu_compile):
 def test_routed_decode_step_compiles(planner, chip_mesh, one_chip,
                                      tpu_compile):
     """One routed olmo-1b decode step (full width, two layers) through
-    `pmm` -> `dit_gemm` -> `local_matmul`: the Pallas kernel is in it."""
+    `pmm` -> `dit_gemm` -> `local_matmul`: the Pallas kernel is in it, and
+    the step names every part of its work as the CPU compile does."""
     cfg = dataclasses.replace(OLMO, n_layers=2)
 
     def on_chip(tree):
@@ -158,3 +160,4 @@ def test_routed_decode_step_compiles(planner, chip_mesh, one_chip,
             params, caches, tok, pos).compile()
     assert ctx.stats.routed and ctx.stats.silent_degrades == 0
     assert "tpu_custom_call" in compiled.as_text()
+    assert_names_every_part(compiled.as_text())

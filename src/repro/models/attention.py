@@ -14,7 +14,9 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
+from repro.models import shard_ctx
 from repro.models.common import (ModelConfig, Params, apply_rope, dense_init,
                                  rms_head_norm, rope_tables)
 from repro.models.matmul import pattn, pmm
@@ -198,17 +200,19 @@ def chunked_sdpa(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
 def _sdpa(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
           q_positions: Optional[jax.Array] = None,
           kv_len: Optional[jax.Array] = None,
-          scale: Optional[float] = None) -> jax.Array:
-    """q: (B, Sq, H, D), k/v: (B, Sk, Hkv, D) with GQA head grouping.
+          scale: Optional[float] = None, kv_order: str = "bkhd") -> jax.Array:
+    """q: (B, Sq, H, D), k/v: (B, Sk, Hkv, D) with GQA head grouping, or
+    (B, Hkv, Sk, D) with kv_order="bhkd", the KV cache's order, which the
+    products then read as stored.
 
     q_positions: positions of the queries (decode: the cache write index);
     kv_len: valid cache length mask bound (decode against a preallocated cache).
     """
     b, sq, h, d = q.shape
-    _, sk, hkv, _ = k.shape
+    sk, hkv = k.shape[kv_order.index("k")], k.shape[kv_order.index("h")]
     groups = h // hkv
     qg = q.reshape(b, sq, hkv, groups, d)
-    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
+    logits = jnp.einsum(f"bqhgd,{kv_order}->bhgqk", qg, k,
                         preferred_element_type=jnp.float32)
     logits *= (d ** -0.5) if scale is None else scale
     kpos = jnp.arange(sk)
@@ -220,8 +224,47 @@ def _sdpa(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
         valid = kpos[None, :] < kv_len[:, None]          # (b, sk)
         logits = jnp.where(valid[:, None, None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
+    out = jnp.einsum(f"bhgqk,{kv_order}->bqhgd", probs, v)
     return out.reshape(b, sq, h, v.shape[-1])
+
+
+def _layer(stack: jax.Array, layer) -> jax.Array:
+    """Layer `layer` of a stacked cache tensor, read where it lies."""
+    return jax.lax.dynamic_index_in_dim(stack, layer, keepdims=False)
+
+
+def _write_rows(cache: Dict[str, jax.Array], layer, rows: Dict[str, jax.Array],
+                seq_axis: int) -> Tuple[Dict[str, jax.Array], jax.Array]:
+    """Write this step's rows into layer `layer` of each stacked cache
+    tensor at the layer's write index, in place: one dynamic_update_slice
+    per tensor, at (layer, 0, .., index, .., 0) with the index on
+    `seq_axis` of the layer. Returns the updated cache and the index."""
+    idx = _layer(cache["index"], layer)
+    s = next(iter(rows.values())).shape[seq_axis]
+    with jax.named_scope("kv_cache.write"):
+        new = {"index": jax.lax.dynamic_update_index_in_dim(
+            cache["index"], idx + s, layer, 0)}
+        for name, x in rows.items():
+            at = [0] * x.ndim
+            at[seq_axis] = idx
+            new[name] = _stored_order(jax.lax.dynamic_update_slice(
+                cache[name], x[None], (layer, *at)))
+    return new, idx
+
+
+def _stored_order(stack: jax.Array) -> jax.Array:
+    """`stack` kept in its stored, row-major order through the layer loop.
+
+    Where no matrix unit pins the order, XLA lays a carried stack out anew
+    inside the loop, at the cost of a copy of the whole stack on each side
+    of it: MHA decode's attention is a batch of matrix-vector products,
+    which XLA computes as multiply-reduces, and the one-row write then
+    prefers the sequence axis major. The SPMD partitioner gathers the
+    operand of a layout constraint whole onto every device, so a program
+    partitioned over an activation mesh goes without it."""
+    if shard_ctx.get_mesh() is not None:
+        return stack
+    return with_layout_constraint(stack, Layout(tuple(range(stack.ndim))))
 
 
 def gqa_attention(p: Params, x: jax.Array, cfg: ModelConfig,
@@ -229,9 +272,12 @@ def gqa_attention(p: Params, x: jax.Array, cfg: ModelConfig,
                   cache: Optional[Dict[str, jax.Array]] = None,
                   causal: bool = True,
                   kv_input: Optional[jax.Array] = None,
+                  layer=0,
                   ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
-    """Self- or cross-attention. With `cache`, runs one decode step: writes
-    this step's K/V at position `cache['index']` and attends to the prefix.
+    """Self- or cross-attention. With `cache` (a stack of layers, see
+    `make_kv_cache`), runs one decode step of layer `layer`: writes this
+    step's K/V into that layer at its `cache['index']` and attends to the
+    prefix; returns the updated stack.
     kv_input: encoder output for cross-attention (no cache update then unless
     it is the first step)."""
     if cache is not None and kv_input is not None:
@@ -265,21 +311,21 @@ def gqa_attention(p: Params, x: jax.Array, cfg: ModelConfig,
                     unfused=unfused)
         new_cache = None
     else:
-        idx = cache["index"]                              # scalar int32
-        with jax.named_scope("kv_cache.write"):
-            ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, idx,
-                                                     axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, idx,
-                                                     axis=1)
+        new_cache, idx = _write_rows(
+            cache, layer, {"k": k.swapaxes(1, 2), "v": v.swapaxes(1, 2)},
+            seq_axis=2)
+        ck, cv = _layer(new_cache["k"], layer), _layer(new_cache["v"], layer)
         kv_len = jnp.full((b,), idx + s, dtype=jnp.int32)
         # thread the caller's causal flag — hard-coding True here broke
-        # non-causal decode (prefix-LM scoring attends to the whole prefix)
-        out = pattn(q, ck, cv, causal=causal, q_positions=positions,
-                    kv_len=kv_len, tag="attn.decode",
+        # non-causal decode (prefix-LM scoring attends to the whole prefix).
+        # pattn's contract is (b, skv, hkv, d): the routed flat_attention
+        # gets a transposed view of the layer, which XLA materialises; the
+        # unfused path reads the cache's (b, hkv, skv, d) as stored.
+        out = pattn(q, ck.swapaxes(1, 2), cv.swapaxes(1, 2), causal=causal,
+                    q_positions=positions, kv_len=kv_len, tag="attn.decode",
                     unfused=lambda: _sdpa(q, ck, cv, causal=causal,
                                           q_positions=positions,
-                                          kv_len=kv_len))
-        new_cache = {"k": ck, "v": cv, "index": idx + s}
+                                          kv_len=kv_len, kv_order="bhkd"))
     return pmm(out.reshape(b, s, cfg.n_heads * hd), p["wo"],
                tag="attn.o"), new_cache
 
@@ -310,6 +356,7 @@ def mla_params(key, cfg: ModelConfig) -> Params:
 def mla_attention(p: Params, x: jax.Array, cfg: ModelConfig,
                   positions: jax.Array,
                   cache: Optional[Dict[str, jax.Array]] = None,
+                  layer=0,
                   ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """MLA. Two execution forms, as in DeepSeek-V2's own deployment:
 
@@ -341,13 +388,11 @@ def mla_attention(p: Params, x: jax.Array, cfg: ModelConfig,
     k_r = apply_rope(k_r, cos, sin)
 
     if cache is not None:
-        idx = cache["index"]
-        with jax.named_scope("kv_cache.write"):
-            c_kv = jax.lax.dynamic_update_slice_in_dim(cache["c_kv"], c_kv,
-                                                       idx, axis=1)
-            k_r = jax.lax.dynamic_update_slice_in_dim(cache["k_rope"], k_r,
-                                                      idx, axis=1)
-        new_cache = {"c_kv": c_kv, "k_rope": k_r, "index": idx + s}
+        new_cache, idx = _write_rows(cache, layer,
+                                     {"c_kv": c_kv, "k_rope": k_r},
+                                     seq_axis=1)
+        c_kv = _layer(new_cache["c_kv"], layer)
+        k_r = _layer(new_cache["k_rope"], layer)
         kv_len = idx + s
     else:
         new_cache = None
@@ -399,16 +444,24 @@ def mla_attention(p: Params, x: jax.Array, cfg: ModelConfig,
     return pmm(out, p["wo"], tag="mla.o"), new_cache
 
 
-def make_kv_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, jax.Array]:
-    """Preallocated per-layer cache pytree (decode shapes of the brief)."""
+def make_kv_cache(cfg: ModelConfig, n_layers: int, batch: int,
+                  max_len: int) -> Dict[str, jax.Array]:
+    """Preallocated cache of a stack of `n_layers` attention layers, with
+    each layer's write position in `index`. GQA K/V are (L, B, Hkv, S, D),
+    the order the attention products read, so a decode step reads a layer
+    where it lies and writes its rows in place. MLA caches the latent
+    (L, B, S, r) and the shared rotary key (L, B, S, 1, dr)."""
     if cfg.attn == "mla":
         return {
-            "c_kv": jnp.zeros((batch, max_len, cfg.kv_lora_rank), cfg.dtype),
-            "k_rope": jnp.zeros((batch, max_len, 1, cfg.rope_head_dim), cfg.dtype),
-            "index": jnp.zeros((), jnp.int32),
+            "c_kv": jnp.zeros((n_layers, batch, max_len, cfg.kv_lora_rank),
+                              cfg.dtype),
+            "k_rope": jnp.zeros((n_layers, batch, max_len, 1,
+                                 cfg.rope_head_dim), cfg.dtype),
+            "index": jnp.zeros((n_layers,), jnp.int32),
         }
+    kv = (n_layers, batch, cfg.n_kv_heads, max_len, cfg.hd)
     return {
-        "k": jnp.zeros((batch, max_len, cfg.n_kv_heads, cfg.hd), cfg.dtype),
-        "v": jnp.zeros((batch, max_len, cfg.n_kv_heads, cfg.hd), cfg.dtype),
-        "index": jnp.zeros((), jnp.int32),
+        "k": jnp.zeros(kv, cfg.dtype),
+        "v": jnp.zeros(kv, cfg.dtype),
+        "index": jnp.zeros((n_layers,), jnp.int32),
     }

@@ -55,13 +55,14 @@ def _attn_block_params(key, cfg: ModelConfig, with_mlp: bool = True,
 
 
 def _attn_block(p: Params, x, cfg: ModelConfig, positions, cache=None,
-                enc_out=None, causal=True):
+                enc_out=None, causal=True, layer=0):
     h = apply_norm(p["ln1"], x, cfg)
     if cfg.attn == "mla":
-        a, new_cache = attn.mla_attention(p["attn"], h, cfg, positions, cache)
+        a, new_cache = attn.mla_attention(p["attn"], h, cfg, positions, cache,
+                                          layer=layer)
     else:
         a, new_cache = attn.gqa_attention(p["attn"], h, cfg, positions, cache,
-                                          causal=causal)
+                                          causal=causal, layer=layer)
     x = x + a
     if enc_out is not None:
         h = apply_norm(p["ln_x"], x, cfg)
@@ -82,8 +83,9 @@ def _moe_block_params(key, cfg: ModelConfig) -> Params:
     return p
 
 
-def _moe_block(p: Params, x, cfg: ModelConfig, positions, cache=None):
-    x, new_cache = _attn_block(p, x, cfg, positions, cache)
+def _moe_block(p: Params, x, cfg: ModelConfig, positions, cache=None,
+               layer=0):
+    x, new_cache = _attn_block(p, x, cfg, positions, cache, layer=layer)
     h = apply_norm(p["ln2"], x, cfg)
     x = x + moe_lib.apply_moe(p["moe"], h, cfg)
     return x, new_cache
@@ -321,49 +323,48 @@ def lm_head_weight(params: Params, cfg: ModelConfig) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# decode (serve) path — per-layer python loop over UNSTACKED params would
-# re-trace; instead we scan over layers carrying the cache pytree.
+# decode (serve) path — one scan over each layer group. The group's weights
+# are the scan's xs; its stacked KV cache rides in the carry beside the layer
+# counter, so each layer reads its K/V where they lie and writes its rows in
+# place. Recurrent states (mamba2, xLSTM), KB to MB a layer, stay in xs/ys.
 # ---------------------------------------------------------------------------
 
 def decode_init(params: Params, cfg: ModelConfig, batch: int,
                 max_len: int) -> Dict[str, Any]:
     """Preallocated cache/state pytree for one-token decode steps."""
-    def stack_caches(n, make):
+    def stack_states(n, make):
         return jax.tree.map(lambda *xs: jnp.stack(xs), *[make() for _ in range(n)])
+
+    def kv(n):
+        return attn.make_kv_cache(cfg, n, batch, max_len)
 
     caches: Dict[str, Any] = {}
     if cfg.is_encoder_decoder:
-        caches["decoder"] = stack_caches(
-            cfg.n_layers, lambda: attn.make_kv_cache(cfg, batch, max_len))
+        caches["decoder"] = kv(cfg.n_layers)
         return caches
     if cfg.block_pattern == "attn":
         n_dense = cfg.n_dense_layers if cfg.n_experts else 0
         if cfg.n_experts:
             if n_dense:
-                caches["dense"] = stack_caches(
-                    n_dense, lambda: attn.make_kv_cache(cfg, batch, max_len))
-            caches["moe"] = stack_caches(
-                cfg.n_layers - n_dense,
-                lambda: attn.make_kv_cache(cfg, batch, max_len))
+                caches["dense"] = kv(n_dense)
+            caches["moe"] = kv(cfg.n_layers - n_dense)
         else:
-            caches["layers"] = stack_caches(
-                cfg.n_layers, lambda: attn.make_kv_cache(cfg, batch, max_len))
+            caches["layers"] = kv(cfg.n_layers)
     elif cfg.block_pattern == "mamba2_hybrid":
         per = cfg.hybrid_attn_every
         n_super, rem = divmod(cfg.n_layers, per)
-        caches["mamba"] = stack_caches(n_super * per,
+        caches["mamba"] = stack_states(n_super * per,
                                        lambda: ssm.mamba2_state(cfg, batch))
         if rem:
-            caches["mamba_tail"] = stack_caches(rem,
-                                                lambda: ssm.mamba2_state(cfg, batch))
-        caches["shared_attn"] = stack_caches(
-            n_super, lambda: attn.make_kv_cache(cfg, batch, max_len))
+            caches["mamba_tail"] = stack_states(
+                rem, lambda: ssm.mamba2_state(cfg, batch))
+        caches["shared_attn"] = kv(n_super)
     elif cfg.block_pattern == "xlstm":
         per = cfg.slstm_every
         n_super = cfg.n_layers // per
-        caches["mlstm"] = stack_caches(n_super * (per - 1),
+        caches["mlstm"] = stack_states(n_super * (per - 1),
                                        lambda: ssm.mlstm_state(cfg, batch))
-        caches["slstm"] = stack_caches(n_super,
+        caches["slstm"] = stack_states(n_super,
                                        lambda: ssm.slstm_state(cfg, batch))
     return caches
 
@@ -376,54 +377,59 @@ def decode_step(params: Params, caches: Dict[str, Any], tokens: jax.Array,
 
     Every op the step emits sits under one top-level `jax.named_scope`
     (`embed`, `block`, `final_norm`, `lm_head`), except the layer scan's
-    own slicing of the stacked weights and caches and its stacking of the
-    new caches, which stay outside `block` so a device profile tells the
-    plumbing from the layers (docs/observability.md)."""
+    own slicing of the stacked weights (and of recurrent states), which
+    stays outside `block` so a device profile tells the plumbing from the
+    layers (docs/observability.md). KV caches are carried, not sliced:
+    their only writes are the in-place ones under `kv_cache.write`."""
     with jax.named_scope("embed"):
         x = shard_ctx.constrain_tokens(
             jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype))
     positions = position[None] if position.ndim == 0 else position
 
-    def scan_layers(stacked_params, stacked_cache, x, block_fn):
-        def block(lp, h, lc):
-            with jax.named_scope("block"):
-                return block_fn(lp, h, lc)
+    def block(fn, *args):
+        with jax.named_scope("block"):
+            return fn(*args)
 
+    def scan_kv_layers(stacked_params, cache, x, block_fn):
+        """block_fn(layer_params, h, cache, layer) -> (h, cache)."""
         if accounting.UNROLL_LAYERS:
             n = jax.tree.leaves(stacked_params)[0].shape[0]
-            new_cs = []
             for i in range(n):
-                x, nc = block(jax.tree.map(lambda a: a[i], stacked_params),
-                              x, jax.tree.map(lambda a: a[i], stacked_cache))
-                new_cs.append(nc)
-            return x, jax.tree.map(lambda *xs: jnp.stack(xs), *new_cs)
+                x, cache = block(block_fn,
+                                 jax.tree.map(lambda a: a[i], stacked_params),
+                                 x, cache, i)
+            return x, cache
 
-        def step(carry, pc):
-            lp, lc = pc
-            out, new_c = block(lp, carry, lc)
-            return out, new_c
-        x, new_caches = jax.lax.scan(step, x, (stacked_params, stacked_cache))
-        return x, new_caches
+        def step(carry, lp):
+            h, layer, c = carry
+            h, c = block(block_fn, lp, h, c, layer)
+            return (h, layer + 1, c), None
+        (x, _, cache), _ = jax.lax.scan(
+            step, (x, jnp.zeros((), jnp.int32), cache), stacked_params)
+        return x, cache
 
     new_caches = dict(caches)
     if cfg.is_encoder_decoder:
-        x, new_caches["decoder"] = scan_layers(
+        x, new_caches["decoder"] = scan_kv_layers(
             params["decoder"], caches["decoder"], x,
-            lambda lp, h, lc: _attn_block(lp, h, cfg, positions, cache=lc,
-                                          enc_out=encoder_out))
+            lambda lp, h, c, l: _attn_block(lp, h, cfg, positions, cache=c,
+                                            enc_out=encoder_out, layer=l))
     elif cfg.block_pattern == "attn":
         if cfg.n_experts:
             if cfg.n_dense_layers:
-                x, new_caches["dense"] = scan_layers(
+                x, new_caches["dense"] = scan_kv_layers(
                     params["dense_layers"], caches["dense"], x,
-                    lambda lp, h, lc: _attn_block(lp, h, cfg, positions, cache=lc))
-            x, new_caches["moe"] = scan_layers(
+                    lambda lp, h, c, l: _attn_block(lp, h, cfg, positions,
+                                                    cache=c, layer=l))
+            x, new_caches["moe"] = scan_kv_layers(
                 params["moe_layers"], caches["moe"], x,
-                lambda lp, h, lc: _moe_block(lp, h, cfg, positions, cache=lc))
+                lambda lp, h, c, l: _moe_block(lp, h, cfg, positions,
+                                               cache=c, layer=l))
         else:
-            x, new_caches["layers"] = scan_layers(
+            x, new_caches["layers"] = scan_kv_layers(
                 params["layers"], caches["layers"], x,
-                lambda lp, h, lc: _attn_block(lp, h, cfg, positions, cache=lc))
+                lambda lp, h, c, l: _attn_block(lp, h, cfg, positions,
+                                                cache=c, layer=l))
     elif cfg.block_pattern == "mamba2_hybrid":
         per = cfg.hybrid_attn_every
         n_super = jax.tree.leaves(caches["shared_attn"])[0].shape[0]
@@ -433,8 +439,8 @@ def decode_step(params: Params, caches: Dict[str, Any], tokens: jax.Array,
             lambda a: a.reshape(n_super, per, *a.shape[1:]), caches["mamba"])
 
         def super_step(carry, pc):
-            h = carry
-            (pm, cm), ca = pc
+            h, layer, ca = carry
+            pm, cm = pc
 
             def inner(c, pcc):
                 pp, cc = pcc
@@ -442,19 +448,19 @@ def decode_step(params: Params, caches: Dict[str, Any], tokens: jax.Array,
                 return out, nc
             with jax.named_scope("block"):
                 h, new_cm = accounting.scan(inner, h, (pm, cm))
-                h, new_ca = _attn_block(params["shared_attn"], h, cfg,
-                                        positions, cache=ca)
-            return h, (new_cm, new_ca)
+                h, ca = _attn_block(params["shared_attn"], h, cfg,
+                                    positions, cache=ca, layer=layer)
+            return (h, layer + 1, ca), new_cm
 
-        x, (new_cm, new_ca) = accounting.scan(
-            super_step, x, ((grouped_p, grouped_c), caches["shared_attn"]))
+        (x, _, new_caches["shared_attn"]), new_cm = accounting.scan(
+            super_step, (x, jnp.zeros((), jnp.int32), caches["shared_attn"]),
+            (grouped_p, grouped_c))
         new_caches["mamba"] = jax.tree.map(
             lambda a: a.reshape(n_super * per, *a.shape[2:]), new_cm)
-        new_caches["shared_attn"] = new_ca
         if "mamba_tail" in params:
-            x, new_caches["mamba_tail"] = scan_layers(
-                params["mamba_tail"], caches["mamba_tail"], x,
-                lambda lp, h, lc: _mamba_block(lp, h, cfg, state=lc))
+            x, new_caches["mamba_tail"] = accounting.scan(
+                lambda h, ps: block(_mamba_block, ps[0], h, cfg, ps[1]),
+                x, (params["mamba_tail"], caches["mamba_tail"]))
     elif cfg.block_pattern == "xlstm":
         per = cfg.slstm_every
         n_super = jax.tree.leaves(caches["slstm"])[0].shape[0]

@@ -126,16 +126,16 @@ def cache_spec(path: Tuple[Any, ...], leaf: jax.ShapeDtypeStruct,
     if name == "index":
         return P(*([None] * len(leaf.shape)))
     # leading dim is stacked layers, second is batch
-    if name in ("k", "v"):          # (L, B, S, n_kv, hd)
+    if name in ("k", "v"):          # (L, B, n_kv, S, hd)
         # prefer kv-head sharding over 'model'; GQA archs with n_kv < |model|
         # (qwen3/phi4 kv=8, gemma kv=1) fall back to SEQUENCE sharding — the
         # cache is by far the largest decode tensor (32k x batch) and leaving
         # it replicated over 'model' costs ~16x HBM (observed 172 GB/dev).
-        n_kv = leaf.shape[3]
+        n_kv = leaf.shape[2]
         if n_kv % mesh.shape["model"] == 0:
-            spec = P(None, dp if batch_ok else None, None, "model", None)
-        else:
             spec = P(None, dp if batch_ok else None, "model", None, None)
+        else:
+            spec = P(None, dp if batch_ok else None, None, "model", None)
         return _fit(spec, leaf.shape, mesh)
     if name == "c_kv":              # (L, B, S, r)
         return _fit(P(None, dp if batch_ok else None,

@@ -342,12 +342,12 @@ def _gqa_decode_fixture():
     cfg = smoke_config("gemma-2b")
     rng = np.random.default_rng(9)
     p = gqa_params(jax.random.PRNGKey(0), cfg)
-    cache = {
+    cache = {                                   # one layer, (L, B, Hkv, S, D)
         "k": jnp.asarray(rng.standard_normal(
-            (1, 16, cfg.n_kv_heads, cfg.hd)), cfg.dtype),
+            (1, 1, cfg.n_kv_heads, 16, cfg.hd)), cfg.dtype),
         "v": jnp.asarray(rng.standard_normal(
-            (1, 16, cfg.n_kv_heads, cfg.hd)), cfg.dtype),
-        "index": jnp.asarray(8, jnp.int32),
+            (1, 1, cfg.n_kv_heads, 16, cfg.hd)), cfg.dtype),
+        "index": jnp.asarray([8], jnp.int32),
     }
     x = jnp.asarray(rng.standard_normal((1, 1, cfg.d_model)), cfg.dtype)
     return cfg, p, cache, x
@@ -402,12 +402,12 @@ def test_mla_absorbed_decode_counts_per_head():
     rng = np.random.default_rng(12)
     p = mla_params(jax.random.PRNGKey(1), cfg)
     b, max_len = 2, 8
-    cache = {
+    cache = {                                   # one layer
         "c_kv": jnp.asarray(rng.standard_normal(
-            (b, max_len, cfg.kv_lora_rank)), cfg.dtype),
+            (1, b, max_len, cfg.kv_lora_rank)), cfg.dtype),
         "k_rope": jnp.asarray(rng.standard_normal(
-            (b, max_len, 1, cfg.rope_head_dim)), cfg.dtype),
-        "index": jnp.asarray(4, jnp.int32),
+            (1, b, max_len, 1, cfg.rope_head_dim)), cfg.dtype),
+        "index": jnp.asarray([4], jnp.int32),
     }
     x = jnp.asarray(rng.standard_normal((b, 1, cfg.d_model)), cfg.dtype)
     ctx = GemmContext(mesh=None)                # record-only

@@ -13,7 +13,8 @@ from hypothesis import strategies as st
 
 from repro.configs import smoke_config
 from repro.models import ssm
-from repro.models.attention import _sdpa, chunked_sdpa, mla_attention, mla_params
+from repro.models.attention import (_sdpa, chunked_sdpa, make_kv_cache,
+                                    mla_attention, mla_params)
 from repro.models.common import ModelConfig
 
 RNG = np.random.default_rng(0)
@@ -61,11 +62,7 @@ def test_mla_absorbed_prefill_vs_decode():
     x = _arr(2, 8, cfg.d_model).astype(cfg.dtype)
     full, _ = mla_attention(p, x, cfg, jnp.arange(8))
 
-    cache = {
-        "c_kv": jnp.zeros((2, 8, cfg.kv_lora_rank), cfg.dtype),
-        "k_rope": jnp.zeros((2, 8, 1, cfg.rope_head_dim), cfg.dtype),
-        "index": jnp.zeros((), jnp.int32),
-    }
+    cache = make_kv_cache(cfg, 1, 2, 8)
     outs = []
     for i in range(8):
         o, cache = mla_attention(p, x[:, i:i + 1], cfg, jnp.asarray([i]), cache)

@@ -1,6 +1,10 @@
-"""The routed serve step names every part of its work (docs/observability.md):
-olmo-1b at smoke width, routed through the planner on the host mesh,
-compiled on the CPU for a prefill chunk and a decode step."""
+"""The routed serve step names every part of its work (docs/observability.md)
+and carries its KV cache through the layer loop in place: olmo-1b at smoke
+width, routed through the planner on the host mesh, compiled on the CPU for
+a prefill chunk and a decode step."""
+import dataclasses
+import math
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -11,7 +15,8 @@ from repro.launch.mesh import host_mesh_grid, make_host_mesh
 from repro.models import shard_ctx
 from repro.models.model import decode_init, init_params
 from repro.train.steps import make_serve_step
-from serve_scopes import assert_names_every_part, instructions
+from serve_scopes import (assert_names_every_part, compile_donated_steps,
+                          instructions, kv_copies)
 
 BATCH, CHUNK, MAX_LEN = 2, 16, 48
 
@@ -43,14 +48,52 @@ def test_routed_serve_step_names_every_part(routed_steps, kind):
 
 @pytest.mark.parametrize("kind", ["prefill", "decode"])
 def test_scan_plumbing_stays_outside_the_block(routed_steps, kind):
-    """The layer scan's slicing of the stacked weights and caches, and its
-    stacking of the new caches, carry no top-level scope; the norms, RoPE,
-    embedding gather and LM head carry theirs."""
+    """The layer scan's slicing of the stacked weights carries no top-level
+    scope, and the scan stacks nothing: the KV cache rides in its carry, so
+    the only cache writes are the block's own under `kv_cache.write`. The
+    norms, RoPE, embedding gather and LM head carry their scopes."""
     names = [n for _, n in instructions(routed_steps[kind])]
-    scan = [n for n in names if n.endswith(("/while/body/dynamic_slice",
-                                            "/while/body/"
-                                            "dynamic_update_slice"))]
-    assert scan
+    assert any(n.endswith("/while/body/dynamic_slice") for n in names)
+    assert not [n for n in names
+                if n.endswith("/dynamic_update_slice")
+                and "/kv_cache.write/" not in n]
     for part in ("/embed/", "/final_norm/norm/", "/block/norm/",
                  "/block/rope/", "/lm_head/pmm.lm_head/"):
         assert any(part in n for n in names), part
+
+
+# The cache guard's own sizes: no activation of the step has a layer's
+# K/V dims (B, Hkv, S, D) = (2, 4, 40, 16) in any order.
+GUARD_CHUNK, GUARD_MAX_LEN = 8, 40
+
+
+@pytest.fixture(scope="module")
+def donated_f32_steps(tmp_path_factory):
+    """The routed olmo smoke step as the benchmark compiles it, cache
+    donated, in float32: the CPU runs a bfloat16 dynamic_update_slice
+    through float32 copies of the whole operand, which the TPU does not."""
+    cfg = dataclasses.replace(get_config("olmo-1b-smoke"), dtype=jnp.float32)
+    planner = serve.warm_plan_cache(
+        cfg, BATCH, GUARD_CHUNK, GUARD_MAX_LEN,
+        str(tmp_path_factory.mktemp("plans")), host_mesh_grid(),
+        max_candidates=12)
+    ctx = shard_ctx.GemmContext(mesh=make_host_mesh(), planner=planner)
+    with shard_ctx.gemm_context(ctx):
+        steps = compile_donated_steps(cfg, BATCH, GUARD_CHUNK, GUARD_MAX_LEN)
+    assert ctx.stats.routed and ctx.stats.silent_degrades == 0
+    return cfg, steps
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_kv_cache_is_carried_and_written_in_place(donated_f32_steps, kind):
+    """No buffer of the step copies, slices out or relayouts a whole
+    layer's K/V or a whole stack, beyond the in-place write under
+    `kv_cache.write` and the layer read the CPU's dot takes as its operand;
+    the donated cache comes back in its own buffers."""
+    cfg, steps = donated_f32_steps
+    layer = (BATCH, cfg.n_kv_heads, GUARD_MAX_LEN, cfg.hd)
+    compiled = steps[kind]
+    assert kv_copies(compiled.as_text(), layer, cfg.n_layers,
+                     dot_operands=True) == []
+    stacks = 2 * cfg.n_layers * math.prod(layer) * 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= stacks

@@ -1,4 +1,6 @@
 """Sharding rule table + fused-loss numerics."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -32,26 +34,42 @@ def test_param_spec_fitting_drops_indivisible_axes():
     assert spec == P(None, None)     # 8 % 32 != 0
 
 
-def test_cache_spec_prefers_heads_then_seq():
+class _KeyEntry:
+    def __init__(self, k):
+        self.key = k
+
+
+class _Mesh16x16:
+    axis_names = ("data", "model")
+    shape = {"data": 16, "model": 16}
+
+
+@pytest.mark.parametrize("n_kv,axis", [(8, 3), (32, 2)],
+                         ids=["seq_fallback", "heads"])
+def test_cache_spec_prefers_heads_then_seq(n_kv, axis):
+    """K/V are (L, B, Hkv, S, D): KV heads over 'model' when they divide
+    it, else the sequence (qwen3: kv=8 < 16)."""
     from repro.parallel.spec_rules import cache_spec
     cfg = smoke_config("qwen3-14b")
+    leaf = jax.ShapeDtypeStruct((40, 128, n_kv, 32768, 128), jnp.bfloat16)
+    spec = cache_spec((_KeyEntry("layers"), _KeyEntry("k")), leaf,
+                      _Mesh16x16, cfg, 128)
+    assert [i for i, a in enumerate(spec) if a == "model"] == [axis]
 
-    class M:
-        axis_names = ("data", "model")
-        shape = {"data": 16, "model": 16}
 
-    class KeyEntry:
-        def __init__(self, k):
-            self.key = k
-
-    # qwen3: kv=8 < 16 -> sequence sharding fallback on dim 2
-    leaf = jax.ShapeDtypeStruct((40, 128, 32768, 8, 128), jnp.bfloat16)
-    spec = cache_spec((KeyEntry("layers"), KeyEntry("k")), leaf, M, cfg, 128)
-    assert spec[3] is None and spec[2] == "model"
-    # kv divisible -> head sharding
-    leaf2 = jax.ShapeDtypeStruct((40, 128, 32768, 32, 128), jnp.bfloat16)
-    spec2 = cache_spec((KeyEntry("layers"), KeyEntry("k")), leaf2, M, cfg, 128)
-    assert spec2[3] == "model"
+def test_cache_spec_follows_decode_init_layout():
+    """The rule reads the axes `decode_init` lays the cache out in: the
+    'model' axis of K and V lands on the KV-head dim."""
+    from repro.models.model import decode_init
+    from repro.parallel.spec_rules import cache_spec
+    cfg = dataclasses.replace(smoke_config("qwen3-14b"), n_kv_heads=16,
+                              n_heads=32)
+    caches = jax.eval_shape(lambda: decode_init(None, cfg, 16, 64))
+    for name in ("k", "v"):
+        leaf = caches["layers"][name]
+        spec = cache_spec((_KeyEntry("layers"), _KeyEntry(name)), leaf,
+                          _Mesh16x16, cfg, 16)
+        assert spec[2] == "model" and leaf.shape[2] == cfg.n_kv_heads
 
 
 def test_chunked_xent_matches_direct():

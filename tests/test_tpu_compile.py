@@ -7,7 +7,10 @@ compile the planner's `mmad` blocks for olmo-1b's decode and prefill
 GEMMs, the `flat_attention` merge executor, and one routed decode step
 (two layers at full width), so such a refusal fails here at no chip time.
 Nothing runs: they assert only that the programs compile and, for the
-routed step, that the Pallas kernel is in it (`tpu_custom_call`).
+routed step, that the Pallas kernel is in it (`tpu_custom_call`). One more
+reads each benchmark cell's compiled serve step at the cell's sizes for
+the buffers it holds: the KV cache carried through the layer loop and
+written in place, with no copy of a layer or of the stack.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and the fixture skips the
@@ -17,7 +20,9 @@ that choice to "tpu" around each compile.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import math
 import os
 
 import jax
@@ -32,11 +37,16 @@ from repro.core.schedule import AttnSchedule, AttnShape, GEMMShape
 from repro.deploy import model_workload
 from repro.deploy.warmup import build_planner, warm_buckets
 from repro.kernels.ops import local_matmul
+from repro.launch import serve
 from repro.launch.mesh import make_mesh
 from repro.models import shard_ctx
 from repro.models.model import decode_init, init_params
 from repro.train.steps import make_serve_step
-from serve_scopes import assert_names_every_part
+from serve_scopes import (assert_names_every_part, compile_donated_steps,
+                          kv_copies)
+
+from bench import harness  # on the path serve_scopes sets
+from bench.reduce import routed_gemms
 
 BATCH, DECODE_MAX_LEN, PREFILL_LEN = 8, 2048, 256
 OLMO = get_config("olmo-1b")
@@ -78,18 +88,27 @@ def planner(tmp_path_factory):
     return p
 
 
-@pytest.fixture
-def tpu_compile(monkeypatch):
+@contextlib.contextmanager
+def compiling_for_tpu():
     """The TPU branch of `local_matmul`, and no persistent cache: entries
     written for a described chip cannot be read back without one."""
     from jax.experimental.compilation_cache import compilation_cache
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def tpu_compile():
+    with compiling_for_tpu():
+        yield
 
 
 def spec(shape, dtype, sharding):
@@ -161,3 +180,51 @@ def test_routed_decode_step_compiles(planner, chip_mesh, one_chip,
     assert ctx.stats.routed and ctx.stats.silent_degrades == 0
     assert "tpu_custom_call" in compiled.as_text()
     assert_names_every_part(compiled.as_text())
+
+
+GUARD_VOCAB = 4096
+
+
+@pytest.fixture(scope="module",
+                params=["olmo-1b.azure-code", "phi4-mini-3.8b.azure-conv"])
+def cell_steps(request, chip_mesh, one_chip, tmp_path_factory):
+    """A benchmark cell's routed serve step, compiled as the benchmark
+    compiles it (cache donated) for the cell's prefill chunk and decode
+    step: two layers at full width. The vocabulary is cut to 4096 so that
+    the tied LM head's transpose and padding (2.46 GB at phi4-mini's
+    200064) do not set the temporaries the cache guard reads."""
+    cell = harness.load_cell(request.param)
+    batch, chunk, max_len = (cell.traffic[k]
+                             for k in ("batch", "chunk", "max_len"))
+    cfg = dataclasses.replace(get_config(cell.config["arch"]), n_layers=2,
+                              vocab=GUARD_VOCAB)
+    planner = serve.warm_plan_cache(
+        cfg, batch, chunk, max_len, str(tmp_path_factory.mktemp("plans")),
+        (1, 1), max_candidates=12)
+    ctx = shard_ctx.GemmContext(mesh=chip_mesh, planner=planner)
+    with compiling_for_tpu(), shard_ctx.gemm_context(ctx):
+        steps = compile_donated_steps(cfg, batch, chunk, max_len,
+                                      sharding=one_chip)
+    assert ctx.stats.routed and ctx.stats.silent_degrades == 0
+    return cfg, (batch, cfg.n_kv_heads, max_len, cfg.hd), steps
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_step_carries_the_kv_cache_in_place(cell_steps, kind):
+    """Outside the in-place write under `kv_cache.write`, no buffer copies,
+    slices out or relayouts a whole layer's K/V or the whole stack; the
+    attention products read the layer where it lies. The donated cache
+    comes back in its own buffers, the layers stay one scan (routed GEMMs
+    (7, 1), as the benchmark logs them), and a decode step's temporaries
+    are smaller than one layer's K."""
+    cfg, layer, steps = cell_steps
+    compiled = steps[kind]
+    text = compiled.as_text()
+    assert kv_copies(text, layer, cfg.n_layers) == []
+    assert routed_gemms(text) == (7, 1)
+    assert "tpu_custom_call" in text
+    layer_bytes = math.prod(layer) * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * cfg.n_layers * layer_bytes
+    if kind == "decode":
+        assert mem.temp_size_in_bytes < layer_bytes

@@ -76,7 +76,7 @@ def planted(fault: str, vocab: int):
 
 def readings(cell, seed: int, seconds: float, fault: str = None,
              log=print) -> dict:
-    cfg = harness.program_config(cell.config)
+    cfg = cell.family.program_config(cell.config)
     vocab = cell.config["vocab_size"]
     with planted(fault, vocab) if fault else contextlib.nullcontext():
         setup = harness.set_up(cfg, cell, seed,
@@ -85,8 +85,7 @@ def readings(cell, seed: int, seconds: float, fault: str = None,
                                    seconds)
     harness.release(setup)
     seqs = harness.sample_sequences(rec["finished"], seed)
-    gaps = harness.reference_gaps(cell.config, cell.traffic, seed, seqs,
-                                  control=fault is None)
+    gaps = harness.reference_gaps(cell, seed, seqs, control=fault is None)
     limit = cell.limits["logit_gap_max"]["limit"]
     ok, worst, _ = harness.judge([g for g, _ in gaps], limit)
     out = {"seed": seed, "fault": fault, "sequences": len(seqs),

@@ -1,7 +1,12 @@
-"""The work a dense decoder's serve step needs, from the published widths.
+"""The work a dense decoder's serve step needs, from the published widths,
+and the roofline arithmetic every family shares (`gemm_flops`,
+`gemm_bytes`, `roofline_s`, `gemm_roofline_s`).
 
-Counts what the algorithm requires, not what the program executes, so a
-change that removes waste reads as a higher share of the roofline:
+The dense counts are the `dense_decoder` family's (bench/families/); the
+harness, the metrics and `bench/scopes.py` reach them only through a
+family. They count what the algorithm requires, not what the program
+executes, so a change that removes waste reads as a higher share of the
+roofline:
 
 - attention is causal over the real context, not over the preallocated
   cache length;
@@ -96,6 +101,16 @@ def attention_flops(dims: Dims, batch: int, q_start: int, q_len: int) -> float:
     over the keys up to and including its own position."""
     keys = q_len * q_start + q_len * (q_len + 1) // 2
     return 4.0 * batch * keys * dims.heads * dims.head_dim * dims.layers
+
+
+def attention_work(dims: Dims, step: dict) -> Tuple[float, float]:
+    """(needed FLOPs, needed bytes) of one step's attention over every
+    layer: causal over the real context; the step's Q and output, and the
+    K/V of the context up to its last new position read once, bf16."""
+    b, q0, q = step["batch"], step["q_start"], step["q_len"]
+    q_and_out = 2 * b * q * dims.heads * dims.head_dim * dims.layers
+    nbytes = q_and_out * BF16 + b * (q0 + q) * dims.kv_bytes_per_position()
+    return attention_flops(dims, b, q0, q), float(nbytes)
 
 
 def step_flops(dims: Dims, batch: int, q_start: int, q_len: int,

@@ -3,19 +3,24 @@
 Everything that belongs to one configuration, traffic mix or metric sits in
 a file of its own and is found by the name BENCHMARK.json gives:
 
-    bench/configs/<config>.json    published sizes, departures, deployment
+    bench/configs/<config>.json    published sizes, departures, deployment,
+                                   and the family that knows the model
+    bench/families/<family>.py     the model's program configuration, shape
+                                   object, weights, reference and needed
+                                   work (`FAMILY_CONTRACT`)
     bench/traffic/<mix>.json       batch, prompt/answer length cycles,
                                    max_len, prefill chunk, trace slice
     bench/metrics/<metric>.py      `read(run) -> float | None`
     bench/limits/<cell>.json       the limit of each number compared
 
-A run: random weights from the seed (`bench.weights`), the planner's
+A run: random weights from the seed (the family's), the planner's
 warm-up on a fresh plan cache, one jitted serve step compiled for the
 cell's two shapes (prefill chunk (B, C) and decode (B, 1)) with the cache
 donated, a warm-up batch; then a window of lockstep batches in a closed
 loop, each token copied to the host as it is produced; then the served
 tokens of one finished sequence for every row of the batch checked against
-the plain reference (`bench.reference`).
+the family's plain reference. The harness knows no architecture: what it
+needs of the model it asks the cell's family.
 """
 from __future__ import annotations
 
@@ -27,8 +32,10 @@ import os
 import resource
 import shutil
 import statistics
+import sys
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -37,10 +44,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from bench import reduce as red
-from bench import weights
-from bench.flops import Dims
-from bench.reference.decoder import served_gaps
-from repro.configs import get_config
 from repro.launch import serve
 from repro.launch.mesh import host_mesh_grid, make_host_mesh
 from repro.models import shard_ctx
@@ -51,6 +54,22 @@ ROOT = Path(__file__).resolve().parents[1]
 BENCH_DIR = ROOT / "bench"
 RESULTS = ROOT / "results" / "bench"
 MAX_PLAN_CANDIDATES = 12
+# What a family module provides (bench/families/<family>.py):
+#   program_config(config) -> the registry ModelConfig, checked (and cut) by
+#       the file;  dims(config) -> a hashable shape object;
+#   make_weights(config, seed, sharding=None), to_program(w, config);
+#   reference_gaps(w, config, tokens, start, served, n_out, control)
+#       -> (served-token gaps, float8 control's gaps), each (n_out,);
+#   for one logged step dict (`Server.steps`: kind, batch, q_start, q_len,
+#   logits_rows): step_work(dims, step) -> (FLOPs, bytes),
+#       step_gemms(dims, step) -> [(tag, M, N, K)], the LM head's tagged
+#       `lm_head`;  attention_work(dims, step) -> (FLOPs, bytes);
+#   routed_expected(dims, kind) -> routed GEMM kernels (inside the layer
+#       loop, outside it) a step of `kind` compiles, the head among those
+#       outside.
+FAMILY_CONTRACT = ("program_config", "dims", "make_weights", "to_program",
+                   "reference_gaps", "step_work", "step_gemms",
+                   "attention_work", "routed_expected")
 
 
 def load_json(path: Path) -> dict:
@@ -63,6 +82,7 @@ class Cell:
     """One entry of BENCHMARK.json's `workloads`, with its files read."""
     name: str
     config: dict
+    family: ModuleType
     traffic: dict
     limits: dict
     end_to_end: List[dict]
@@ -80,39 +100,42 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     def applies(metric: dict) -> bool:
         return "workloads" not in metric or name in metric["workloads"]
 
-    return Cell(name=name,
-                config=load_json(bench / "configs" / f"{w['config']}.json"),
+    config = load_json(bench / "configs" / f"{w['config']}.json")
+    return Cell(name=name, config=config,
+                family=load_family(config["family"], bench),
                 traffic=load_json(bench / "traffic" / f"{w['traffic']}.json"),
                 limits=load_json(bench / "limits" / f"{name}.json"),
                 end_to_end=[m for m in spec["end_to_end"] if applies(m)],
                 per_layer=[m for m in spec["per_layer"] if applies(m)])
 
 
+def _module(path: Path, kind: str) -> ModuleType:
+    """The Python file at `path`, loaded as module `bench_<kind>_<stem>`."""
+    name = f"bench_{kind}_" + path.stem.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    # registered before it runs: a dataclass defined in the file looks its
+    # module up there
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def metric_reader(name: str, bench: Path = BENCH_DIR) -> Callable:
     """`read` of bench/metrics/<name>.py."""
-    path = bench / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(bench / "metrics" / f"{name}.py", "metric").read
 
 
-def program_config(config: dict):
-    """The registry configuration the file names, checked against the
-    published widths the file holds."""
-    cfg = get_config(config["arch"])
-    d = Dims.from_config(config)
-    got = (cfg.d_model, cfg.n_layers, cfg.n_heads, cfg.n_kv_heads, cfg.hd,
-           cfg.d_ff, cfg.vocab, cfg.norm, cfg.tie_embeddings, cfg.rope_theta)
-    want = (d.d, d.layers, d.heads, d.kv_heads, d.head_dim, d.ffn, d.vocab,
-            config["norm"], config["tie_word_embeddings"],
-            config["rope_theta"])
-    if got != want or cfg.n_experts or cfg.attn != "gqa" \
-            or cfg.block_pattern != "attn" or cfg.act != "swiglu":
-        raise SystemExit(f"{config['arch']}: the program's configuration "
-                         f"{got} is not the file's {want}")
-    return cfg
+def load_family(name: str, bench: Path = BENCH_DIR) -> ModuleType:
+    """bench/families/<name>.py, refused unless it provides the whole
+    `FAMILY_CONTRACT`."""
+    path = bench / "families" / f"{name}.py"
+    mod = _module(path, "family")
+    missing = [f for f in FAMILY_CONTRACT
+               if not callable(getattr(mod, f, None))]
+    if missing:
+        raise SystemExit(f"{path}: the family lacks {missing}")
+    return mod
 
 
 span = jax.profiler.TraceAnnotation
@@ -333,12 +356,13 @@ def judge(gaps, limit: float):
     return worst is not None and worst <= limit, worst, failed
 
 
-def reference_gaps(config: dict, traffic: dict, seed: int, seqs,
-                   control: bool = False) -> List[np.ndarray]:
-    """Gap of each served token below the reference's best logit, per
-    sequence (and with `control`, the float8 control's gaps as well)."""
-    dims = Dims.from_config(config)
-    w = weights.make(dims, config["norm"], seed)
+def reference_gaps(cell: Cell, seed: int, seqs,
+                   control: bool = False) -> List[tuple]:
+    """Gap of each served token below the family's reference's best logit,
+    per sequence, with the float8 control's gaps beside it (zeros unless
+    `control`): [(gaps, control's gaps)]."""
+    fam, config, traffic = cell.family, cell.config, cell.traffic
+    w = fam.make_weights(config, seed)
     n_out = max(traffic["answer_cycle"])
     t_pad = -(-(max(traffic["prompt_cycle"]) + n_out) // 128) * 128
     out = []
@@ -348,10 +372,9 @@ def reference_gaps(config: dict, traffic: dict, seed: int, seqs,
         toks[:len(seq)] = seq
         pad = np.zeros(n_out, np.int32)
         pad[:len(served)] = served
-        gap, ctrl = served_gaps(
-            w, toks, np.int32(len(prompt) - 1), pad, dims=dims,
-            norm=config["norm"], eps=config["norm_eps"],
-            theta=config["rope_theta"], n_out=n_out, control=control)
+        gap, ctrl = fam.reference_gaps(w, config, toks,
+                                       np.int32(len(prompt) - 1), pad,
+                                       n_out, control)
         g = len(served)
         out.append((np.asarray(gap)[:g], np.asarray(ctrl)[:g]))
     return out
@@ -376,9 +399,10 @@ def set_up(cfg, cell: Cell, seed: int, plan_dir: Path) -> Setup:
     phases = {}
     mesh, grid = make_host_mesh(), host_mesh_grid()
     t = time.perf_counter()
-    w = weights.make(Dims.from_config(cell.config), cell.config["norm"], seed,
-                     NamedSharding(mesh, PartitionSpec()))
-    params = weights.to_program(w, cell.config["norm"])
+    fam = cell.family
+    w = fam.make_weights(cell.config, seed,
+                         NamedSharding(mesh, PartitionSpec()))
+    params = fam.to_program(w, cell.config)
     jax.block_until_ready(params)
     phases["weights_s"] = time.perf_counter() - t
     t = time.perf_counter()
@@ -451,7 +475,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
              peak: dict, t_start: float, device: dict,
              log: Callable[[str], None] = print) -> dict:
     """One run of `cell`: the result object of the last output line."""
-    cfg = program_config(cell.config)
+    cfg = cell.family.program_config(cell.config)
     tr = cell.traffic
     out_dir = RESULTS / cell.name
     counter = CompileCounter()
@@ -488,8 +512,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     run = {"setup_s": setup_s, "setup": setup.phases,
            "window_s": rec["window_s"], "tokens": rec["tokens"],
            "itl_s": rec["itl_s"], "ttft_s": rec["ttft_s"],
-           "dims": Dims.from_config(cell.config), "peak": peak,
-           "routed_gemms": server.routed, "trace": None, "steps": []}
+           "family": cell.family, "dims": cell.family.dims(cell.config),
+           "peak": peak, "routed_gemms": server.routed, "trace": None,
+           "steps": []}
     breakdown = None
     if trace is not None:
         path = trace.xplane()
@@ -513,7 +538,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
 
     seqs = sample_sequences(rec["finished"], seed)
     t = time.perf_counter()
-    gaps = reference_gaps(cell.config, tr, seed, seqs)
+    gaps = reference_gaps(cell, seed, seqs)
     limit = cell.limits["logit_gap_max"]["limit"]
     correct, worst, failed = judge([g for g, _ in gaps], limit)
     log(f"checked {len(seqs)} sequences, {sum(g.size for g, _ in gaps)} "

@@ -1,8 +1,7 @@
-"""Bytes the decode steps of the traced slice need (weights, the KV cache
-of the real context, the new K/V and the logits) over the device time of
-those steps' programs, as a share of the chip's HBM bandwidth, in
-percent."""
-from bench import flops
+"""Bytes the decode steps of the traced slice need (the family's
+`step_work`: weights, the KV cache of the real context, the new K/V and
+the logits) over the device time of those steps' programs, as a share of
+the chip's HBM bandwidth, in percent."""
 
 
 def read(run):
@@ -13,5 +12,5 @@ def read(run):
     dec = [s for s in run["steps"] if s["kind"] == "decode"]
     if not secs or not dec:
         return None
-    need = sum(flops.step_work(run["dims"], s)[1] for s in dec)
+    need = sum(run["family"].step_work(run["dims"], s)[1] for s in dec)
     return 100.0 * need / secs / run["peak"]["hbm_bytes_per_s"]

@@ -8,7 +8,8 @@ Two stages, so that the second can be tested on a small recorded trace:
    events of the harness's own spans (`bench.*`) and of program launches.
 2. `reduce` aligns the device clock to the host's, clips to the traced
    window (the host span `bench.window`), and computes busy time (the union
-   of op intervals), time under `dit_gemm.*` scopes, device time per step
+   of op intervals), time under `dit_gemm.*` scopes, time by the model's
+   scopes (`scope_s`: attention, LM head, unscoped), device time per step
    kind, and the idle gaps attributed to the host span they fall in.
 
 Op events name HLO instructions; the scope of each (`jax.named_scope`, such
@@ -26,6 +27,9 @@ LAUNCH = "PJRT_LoadedExecutable_Execute"
 HOST_SPANS = ("bench.batch_reset", "bench.prefill", "bench.decode_step",
               "bench.token_to_host")
 GEMM_SCOPE = "dit_gemm."
+# the serve step's top-level scopes (docs/observability.md)
+TOP_LEVEL = ("embed", "block", "final_norm", "lm_head")
+SCOPE_GROUPS = ("attention", "lm_head", "unscoped")
 _CONTROL = {"while", "body", "closed_call", "cond"}
 _OP_NAME = re.compile(
     r'^\s*(?:ROOT\s+)?(%[\w.\-]+) = .*?metadata=\{op_name="([^"]*)"', re.M)
@@ -119,6 +123,23 @@ def clock_offset(modules: Sequence[Event], host: Sequence[Event]) -> float:
     return max(0.0, max(h[1] - m[1] for h, m in zip(launches, modules)))
 
 
+def scope_groups(op_name: Optional[str]) -> List[str]:
+    """The `scope_s` groups an op's time counts in: `attention` (a path
+    segment starting with `pattn.`), `lm_head` (a segment equal to
+    `lm_head`; `pmm.lm_head` alone does not match) and `unscoped` (under
+    none of the top-level scopes, as an op XLA names after an argument,
+    `params['embed']`, is)."""
+    segs = op_name.split("/") if op_name else []
+    out = []
+    if any(p.startswith("pattn.") for p in segs):
+        out.append("attention")
+    if "lm_head" in segs:
+        out.append("lm_head")
+    if not any(p in TOP_LEVEL for p in segs):
+        out.append("unscoped")
+    return out
+
+
 def scope_label(op_name: Optional[str], instr: str) -> str:
     """A short name for an op: its scope path without the step's own jit
     name and the loop plumbing, or the instruction kind if it has none."""
@@ -140,7 +161,8 @@ def reduce(events: dict, step_kinds: Sequence[str],
 
     `step_kinds` is the kind ('prefill' or 'decode') of each program launch
     the harness made inside the window, in order; `scopes` maps each kind to
-    its program's `op_scopes`. Busy and GEMM time are averaged over chips.
+    its program's `op_scopes`. Busy, GEMM and scope time are averaged over
+    chips.
     """
     windows = [h for h in events["host"] if h[0] == WINDOW_SPAN]
     if not windows or not events["chips"]:
@@ -162,6 +184,8 @@ def reduce(events: dict, step_kinds: Sequence[str],
     n = len(per_chip)
     for key in ("busy_s", "gemm_s"):
         out[key] = sum(c[key] for c in per_chip) / n
+    out["scope_s"] = {k: sum(c["scope_s"][k] for c in per_chip) / n
+                      for k in SCOPE_GROUPS}
     out["chips"] = n
     return out
 
@@ -177,6 +201,7 @@ def _reduce_chip(mods, ops, step_kinds, scopes, spans, w0, w1, top) -> dict:
         module_ns[kind or "unknown"] += d
         bounds.append((s, s + d, kind))
     by_label: Dict[str, float] = collections.Counter()
+    scope_ns = dict.fromkeys(SCOPE_GROUPS, 0.0)
     gemm_ns = 0.0
     j = 0
     for name, s, d in sorted(leaves(ops), key=lambda e: e[1]):
@@ -187,6 +212,8 @@ def _reduce_chip(mods, ops, step_kinds, scopes, spans, w0, w1, top) -> dict:
         op_name = scopes.get(kind, {}).get(instr) if kind else None
         if op_name and GEMM_SCOPE in op_name:
             gemm_ns += d
+        for group in scope_groups(op_name):
+            scope_ns[group] += d
         by_label[scope_label(op_name, instr)] += d
     idle: Dict[str, float] = collections.Counter()
     edges = [w0] + [x for iv in busy for x in iv] + [w1]
@@ -204,6 +231,7 @@ def _reduce_chip(mods, ops, step_kinds, scopes, spans, w0, w1, top) -> dict:
         "window_s": (w1 - w0) * ns,
         "busy_s": busy_ns * ns,
         "gemm_s": gemm_ns * ns,
+        "scope_s": {k: v * ns for k, v in scope_ns.items()},
         "modules": len(mods),
         "steps_matched": kinds is not None,
         "module_s": {k: v * ns for k, v in module_ns.items()},

@@ -8,24 +8,26 @@ inside them `pmm.<tag>` around every GEMM, `pattn.<tag>` around attention,
 stacking, copies XLA inserts and the harness's `argmax` carry none of the
 four: they are "unscoped".
 
-`scope_seconds` gives the device seconds of a traced window's leaf ops in
-three groups: `attention` (a path segment starting with `pattn.`),
-`lm_head` (a segment equal to `lm_head`; `pmm.lm_head` alone does not
-match) and `unscoped`. An op XLA names after an argument (`params['embed']`)
-has no scope path, and counts as unscoped. The three readers below read
-those seconds from `run["trace"]["scope_s"]`:
+The benchmark's reduction (`bench/reduce.py`) gives every traced run the
+device seconds of its leaf ops in the three groups of
+`bench.reduce.scope_groups`, as `scope_s`: `attention` (a path segment
+starting with `pattn.`), `lm_head` (a segment equal to `lm_head`;
+`pmm.lm_head` alone does not match) and `unscoped`. An op XLA names after
+an argument (`params['embed']`) has no scope path, and counts as unscoped.
+The three readers below read it from `run["trace"]["scope_s"]`, count the
+needed work through the cell's family (`run["family"]`), and are the
+per-layer metrics of the same names (`bench/metrics/<name>.py`):
 
 - `attention_roofline`: the roofline time of the attention the steps need
-  (causal over the real context; Q, the context's K/V read once and the
-  output, bf16) over `scope_s["attention"]`, in percent;
-- `lm_head_roofline`: the roofline time of the LM-head GEMM for the rows
-  whose logits are used over `scope_s["lm_head"]`, in percent: prefill
-  chunks whose logits go unused add device time and no need;
+  (the family's `attention_work`) over `scope_s["attention"]`, in percent;
+- `lm_head_roofline`: the roofline time of the LM-head GEMMs for the rows
+  whose logits are used (the GEMMs the family's `step_gemms` tags
+  `lm_head`) over `scope_s["lm_head"]`, in percent: prefill chunks whose
+  logits go unused add device time and no need;
 - `unscoped_share`: `scope_s["unscoped"]` over the busy time, in percent.
 
-The benchmark's reduction (`bench/reduce.py`) does not yet compute
-`scope_s`, so no cell reports these readings; this script does, for one
-traced window of a cell on the chip:
+This script runs one traced window of a cell on the chip and breaks its
+device time down further:
 
     python3 bench/scopes.py --workload <cell> --seed <n> [--seconds <s>]
 
@@ -51,7 +53,6 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 from bench import flops  # noqa: E402
 from bench import reduce as red  # noqa: E402
 
-TOP_LEVEL = ("embed", "block", "final_norm", "lm_head")
 FINER = ("kv_cache.write", "norm", "rope")
 
 # (step kind, op_name or None, `reduce.scope_label`) -> device seconds
@@ -93,37 +94,12 @@ def part(op_name: Optional[str]) -> str:
     """The top-level scope of an op and the finest named part inside it
     (`block/pmm.attn.q`, `lm_head/pmm.lm_head`, `block`), or `unscoped`."""
     segs = op_name.split("/") if op_name else []
-    top = next((p for p in segs if p in TOP_LEVEL), None)
+    top = next((p for p in segs if p in red.TOP_LEVEL), None)
     if top is None:
         return "unscoped"
     inner = next((p for p in segs if p.startswith(("pmm.", "pattn."))
                   or p in FINER), None)
     return f"{top}/{inner}" if inner else top
-
-
-def scope_seconds(by_op: OpSeconds) -> Dict[str, float]:
-    """`attention`, `lm_head` and `unscoped` device seconds."""
-    out = {"attention": 0.0, "lm_head": 0.0, "unscoped": 0.0}
-    for (_, op_name, _), secs in by_op.items():
-        segs = op_name.split("/") if op_name else []
-        if any(p.startswith("pattn.") for p in segs):
-            out["attention"] += secs
-        if "lm_head" in segs:
-            out["lm_head"] += secs
-        if not any(p in TOP_LEVEL for p in segs):
-            out["unscoped"] += secs
-    return out
-
-
-def attention_work(dims: flops.Dims, step: dict) -> Tuple[float, float]:
-    """(needed FLOPs, needed bytes) of one step's attention over every
-    layer: causal over the real context; the step's Q and output, and the
-    K/V of the context up to its last new position read once, bf16."""
-    b, q0, q = step["batch"], step["q_start"], step["q_len"]
-    q_and_out = 2 * b * q * dims.heads * dims.head_dim * dims.layers
-    nbytes = (q_and_out * flops.BF16
-              + b * (q0 + q) * dims.kv_bytes_per_position())
-    return flops.attention_flops(dims, b, q0, q), float(nbytes)
 
 
 def _scope_s(run: dict, key: str) -> Optional[float]:
@@ -135,19 +111,21 @@ def attention_roofline(run: dict) -> Optional[float]:
     secs = _scope_s(run, "attention")
     if not secs or not run["steps"]:
         return None
-    need = sum(flops.roofline_s(*attention_work(run["dims"], s),
-                                run["peak"]) for s in run["steps"])
+    work = run["family"].attention_work
+    need = sum(flops.roofline_s(*work(run["dims"], s), run["peak"])
+               for s in run["steps"])
     return 100.0 * need / secs
 
 
 def lm_head_roofline(run: dict) -> Optional[float]:
     secs = _scope_s(run, "lm_head")
-    used = [s["logits_rows"] for s in run["steps"] if s["logits_rows"]]
+    used = [s for s in run["steps"] if s["logits_rows"]]
     if not secs or not used:
         return None
-    d = run["dims"]
+    gemms = run["family"].step_gemms
     need = flops.gemm_roofline_s(
-        [("lm_head", rows, d.vocab, d.d) for rows in used], run["peak"])
+        [g for s in used for g in gemms(run["dims"], s) if g[0] == "lm_head"],
+        run["peak"])
     return 100.0 * need / secs
 
 
@@ -169,20 +147,19 @@ READERS = {"attention_roofline": attention_roofline,
 def summary(by_op: OpSeconds, trace: dict, run: dict,
             top: int = 12) -> dict:
     """What this script prints: parts by step kind, the longest unscoped
-    ops, `scope_s` and the readings."""
+    ops, and the reduction's `scope_s` and the readings."""
     parts: Dict[str, float] = collections.Counter()
     loose: Dict[str, float] = collections.Counter()
     for (kind, op_name, label), secs in by_op.items():
         parts[f"{kind}:{part(op_name)}"] += secs
         if part(op_name) == "unscoped":
             loose[label] += secs
-    trace = dict(trace, scope_s=scope_seconds(by_op))
     run = dict(run, trace=trace)
     return {"busy_s": trace.get("busy_s"), "window_s": trace.get("window_s"),
             "steps": collections.Counter(s["kind"] for s in run["steps"]),
             "parts": dict(sorted(parts.items(), key=lambda kv: -kv[1])),
             "unscoped_ops": sorted(loose.items(), key=lambda kv: -kv[1])[:top],
-            "scope_s": trace["scope_s"],
+            "scope_s": trace.get("scope_s"),
             "readings": {k: f(run) for k, f in READERS.items()}}
 
 
@@ -190,7 +167,7 @@ def traced_window(cell, seed: int, seconds: float, peak: dict) -> dict:
     """One traced window of `cell`, as `bench/run.py --trace 1` runs it,
     reduced by scope."""
     from bench import harness
-    cfg = harness.program_config(cell.config)
+    cfg = cell.family.program_config(cell.config)
     out_dir = harness.RESULTS / cell.name
     setup = harness.set_up(cfg, cell, seed, out_dir / "plan_cache")
     server = setup.server
@@ -203,8 +180,8 @@ def traced_window(cell, seed: int, seconds: float, peak: dict) -> dict:
     path = trace.xplane()
     events = red.extract(path) if path else {"chips": [], "host": []}
     kinds = [s["kind"] for s in steps]
-    run = {"steps": steps, "dims": flops.Dims.from_config(cell.config),
-           "peak": peak}
+    run = {"steps": steps, "family": cell.family,
+           "dims": cell.family.dims(cell.config), "peak": peak}
     result = summary(op_seconds(events, kinds, scopes),
                      red.reduce(events, kinds, scopes), run)
     result["routed_gemms"] = server.routed

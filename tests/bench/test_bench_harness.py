@@ -20,8 +20,8 @@ sys.path.insert(0, str(ROOT))
 from bench import harness  # noqa: E402
 
 SMOKE_CONFIG = {
-    "arch": "olmo-1b-smoke", "hidden_size": 64, "intermediate_size": 160,
-    "num_hidden_layers": 3, "num_attention_heads": 4,
+    "family": "dense_decoder", "arch": "olmo-1b-smoke", "hidden_size": 64,
+    "intermediate_size": 160, "num_hidden_layers": 3, "num_attention_heads": 4,
     "num_key_value_heads": 4, "vocab_size": 256, "rope_theta": 10000.0,
     "tie_word_embeddings": True, "norm": "nonparam_ln", "norm_eps": 1e-6,
 }
@@ -35,7 +35,9 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 def smoke_cell(**limits):
     spec = harness.load_json(ROOT / "BENCHMARK.json")
     return harness.Cell(
-        name="smoke", config=dict(SMOKE_CONFIG), traffic=dict(SMOKE_TRAFFIC),
+        name="smoke", config=dict(SMOKE_CONFIG),
+        family=harness.load_family(SMOKE_CONFIG["family"]),
+        traffic=dict(SMOKE_TRAFFIC),
         limits={"logit_gap_max": {"limit": limits.get("limit", 0.05)}},
         end_to_end=spec["end_to_end"], per_layer=spec["per_layer"])
 
@@ -55,7 +57,7 @@ def test_every_named_file_is_found():
         cell = harness.load_cell(w["name"], ROOT)
         assert cell.config["arch"] and cell.traffic["batch"] > 0
         assert cell.limits["logit_gap_max"]["limit"] > 0
-        harness.program_config(cell.config)
+        cell.family.program_config(cell.config)
     for m in spec["end_to_end"] + spec["per_layer"]:
         assert callable(harness.metric_reader(m["name"]))
 

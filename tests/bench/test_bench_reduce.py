@@ -17,9 +17,10 @@ sys.path.insert(0, str(ROOT))
 
 from bench import flops  # noqa: E402
 from bench import reduce as red  # noqa: E402
-from bench.harness import load_json, metric_reader  # noqa: E402
+from bench.harness import load_family, load_json, metric_reader  # noqa: E402
 
 OLMO = load_json(ROOT / "bench" / "configs" / "olmo-1b.json")
+FAMILY = load_family(OLMO["family"])
 PEAK = load_json(ROOT / "bench" / "peaks.json")["TPU v5 lite"]
 RECORDED = ROOT / "bench" / "testdata" / "trace_olmo-1b.prefill-2k.json"
 
@@ -129,8 +130,8 @@ def test_recorded_trace_metrics_stay_under_their_peaks(recorded):
               "logits_rows": 4},
              {"kind": "decode", "batch": 4, "q_start": 2049, "q_len": 1,
               "logits_rows": 4}]
-    run = {"trace": r, "steps": steps, "peak": PEAK,
-           "dims": flops.Dims.from_config(OLMO),
+    run = {"trace": r, "steps": steps, "peak": PEAK, "family": FAMILY,
+           "dims": FAMILY.dims(OLMO),
            "routed_gemms": {"prefill": (7, 1), "decode": (7, 1)}}
     values = {m: metric_reader(m)(run) for m in
               ("gemm_roofline", "step_mfu", "decode_hbm_share",
@@ -160,8 +161,8 @@ def test_gemm_roofline_reads_nothing_once_a_gemm_leaves_the_routed_path():
     steps = [{"kind": "decode", "batch": 4, "q_start": 16, "q_len": 1,
               "logits_rows": 4}]
     trace = {"gemm_s": 1e-3, "window_s": 1e-2, "busy_s": 1e-2}
-    run = {"trace": trace, "steps": steps, "peak": PEAK,
-           "dims": flops.Dims.from_config(OLMO)}
+    run = {"trace": trace, "steps": steps, "peak": PEAK, "family": FAMILY,
+           "dims": FAMILY.dims(OLMO)}
     read = metric_reader("gemm_roofline")
     assert read(dict(run, routed_gemms={"decode": (7, 1)})) > 0
     # the LM head on a plain XLA dot, a projection off the path, no program

@@ -14,21 +14,22 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from bench import harness, weights  # noqa: E402
-from bench.flops import Dims  # noqa: E402
-from bench.reference.decoder import HI, hidden, served_gaps  # noqa: E402
+from bench import harness  # noqa: E402
+from bench.reference.decoder import HI, hidden  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.models.model import decode_init, init_params  # noqa: E402
 from repro.train.steps import make_serve_step  # noqa: E402
 
 SMOKE = {
     "olmo-1b-smoke": dict(
-        arch="olmo-1b-smoke", hidden_size=64, intermediate_size=160,
+        family="dense_decoder", arch="olmo-1b-smoke", hidden_size=64,
+        intermediate_size=160,
         num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=4,
         vocab_size=256, rope_theta=10000.0, tie_word_embeddings=True,
         norm="nonparam_ln", norm_eps=1e-6),
     "phi4-mini-3.8b-smoke": dict(
-        arch="phi4-mini-3.8b-smoke", hidden_size=64, intermediate_size=160,
+        family="dense_decoder", arch="phi4-mini-3.8b-smoke", hidden_size=64,
+        intermediate_size=160,
         num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2,
         vocab_size=256, rope_theta=10000.0, tie_word_embeddings=True,
         norm="rmsnorm", norm_eps=1e-6),
@@ -39,19 +40,22 @@ LOGITS_REL_TOL = 4 * 2.0 ** -8
 B, CHUNK, T = 2, 8, 24
 
 
+FAMILY = harness.load_family("dense_decoder")
+
+
 def _setup(arch, seed=3):
     config = SMOKE[arch]
-    dims = Dims.from_config(config)
-    w = weights.make(dims, config["norm"], seed)
+    dims = FAMILY.dims(config)
+    w = FAMILY.make_weights(config, seed)
     return config, dims, w
 
 
 @pytest.mark.parametrize("arch", sorted(SMOKE))
 def test_adapter_gives_the_program_layout(arch):
     config, dims, w = _setup(arch)
-    cfg = harness.program_config(config)
+    cfg = FAMILY.program_config(config)
     want = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    got = weights.to_program(w, config["norm"])
+    got = FAMILY.to_program(w, config)
     assert jax.tree.structure(got) == jax.tree.structure(want)
     for g, e in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert g.shape == e.shape and g.dtype == e.dtype
@@ -61,7 +65,7 @@ def test_adapter_gives_the_program_layout(arch):
 def test_reference_matches_prefill_then_decode(arch):
     config, dims, w = _setup(arch)
     cfg = get_config(arch)
-    params = weights.to_program(w, config["norm"])
+    params = FAMILY.to_program(w, config)
     step = jax.jit(make_serve_step(cfg))
     caches = decode_init(None, cfg, B, T)
     toks = np.random.default_rng(0).integers(0, dims.vocab, (B, T),
@@ -94,11 +98,11 @@ def test_served_gaps_reads_the_reference_best(arch):
                                 precision=HI))
     start, n = 9, 6
     best = logits[start:start + n].argmax(-1).astype(np.int32)
-    kw = dict(dims=dims, norm=config["norm"], eps=config["norm_eps"],
-              theta=config["rope_theta"], n_out=n)
-    gap, _ = served_gaps(w, toks, np.int32(start), best, **kw)
+    gap, _ = FAMILY.reference_gaps(w, config, toks, np.int32(start),
+                                   best, n, False)
     assert np.allclose(np.asarray(gap), 0.0, atol=1e-5)
     worst = logits[start:start + n].argmin(-1).astype(np.int32)
-    gap, _ = served_gaps(w, toks, np.int32(start), worst, **kw)
+    gap, _ = FAMILY.reference_gaps(w, config, toks, np.int32(start),
+                                   worst, n, False)
     spread = logits[start:start + n].max(-1) - logits[start:start + n].min(-1)
     assert np.allclose(np.asarray(gap), spread, rtol=1e-4)

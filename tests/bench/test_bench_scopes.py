@@ -69,7 +69,7 @@ def ns(x):
 def test_scope_seconds_of_a_synthetic_trace_by_hand():
     events, hlo = synthetic()
     by_op = scopes.op_seconds(events, ["prefill", "decode"], hlo)
-    got = scopes.scope_seconds(by_op)
+    got = red.reduce(events, ["prefill", "decode"], hlo)["scope_s"]
     # attention: the QK einsum; LM head: 10 + 6 (its transposed weight is
     # named after the argument, so it is unscoped); unscoped: the scan's
     # stacking 4, the squeeze 7, the transpose 5, the argmax 5
@@ -108,6 +108,21 @@ def test_reduce_keeps_its_numbers_on_scoped_op_names():
     assert ops["(no scope) squeeze"] == ns(7)
 
 
+def test_reduce_gives_the_scripts_scope_seconds_averaged_over_chips():
+    """`scope_s`, as every traced benchmark run reduces it: the script's
+    numbers on one chip, and their mean beside a chip that ran nothing."""
+    events, hlo = synthetic()
+    r = red.reduce(events, ["prefill", "decode"], hlo)
+    assert r["scope_s"] == {"attention": ns(6), "lm_head": ns(16),
+                            "unscoped": ns(21)}
+    idle = {"modules": events["chips"][0]["modules"], "ops": []}
+    two = dict(events, chips=events["chips"] + [idle])
+    r = red.reduce(two, ["prefill", "decode"], hlo)
+    assert r["chips"] == 2 and r["busy_s"] == ns(30)
+    assert r["scope_s"] == {"attention": ns(3), "lm_head": ns(8),
+                            "unscoped": ns(10.5)}
+
+
 @pytest.mark.parametrize("op_name, want", [
     (None, "unscoped"),
     ("params['embed']", "unscoped"),
@@ -124,9 +139,11 @@ def test_part_names_the_top_scope_and_the_finest_inside(op_name, want):
 
 
 def test_pmm_lm_head_alone_is_not_the_lm_head():
-    by_op = {("decode", "jit(s)/pmm.lm_head/dot_general", "x"): 1.0}
-    assert scopes.scope_seconds(by_op) == {"attention": 0.0, "lm_head": 0.0,
-                                           "unscoped": 1.0}
+    events, hlo = synthetic()
+    hlo["decode"]["%mmad.9"] = "jit(s)/pmm.lm_head/dot_general"
+    got = red.reduce(events, ["prefill", "decode"], hlo)["scope_s"]
+    # the decode step's head (6) leaves `lm_head` for `unscoped`
+    assert got == {"attention": ns(6), "lm_head": ns(10), "unscoped": ns(27)}
 
 
 # -- the readers, by hand ---------------------------------------------------
@@ -147,16 +164,19 @@ STEPS = [{"kind": "prefill", "batch": 2, "q_start": 0, "q_len": 2,
           "logits_rows": 2}]
 
 
+FAMILY = harness.load_family("dense_decoder")
+
+
 def run(**scope_s):
     s = {"attention": 14.08, "lm_head": 26.88, "unscoped": 3.0}
     s.update(scope_s)
-    return {"dims": DIMS, "peak": PEAK, "steps": STEPS,
+    return {"family": FAMILY, "dims": DIMS, "peak": PEAK, "steps": STEPS,
             "trace": {"busy_s": 12.0, "window_s": 13.0, "scope_s": s}}
 
 
 def test_attention_work_by_hand():
-    assert scopes.attention_work(DIMS, STEPS[0]) == (384.0, 384.0)
-    assert scopes.attention_work(DIMS, STEPS[1]) == (384.0, 320.0)
+    assert FAMILY.attention_work(DIMS, STEPS[0]) == (384.0, 384.0)
+    assert FAMILY.attention_work(DIMS, STEPS[1]) == (384.0, 320.0)
 
 
 def test_readers_by_hand():
@@ -169,7 +189,7 @@ def test_readers_by_hand():
 def test_readers_read_nothing_without_their_scope():
     for reader in scopes.READERS.values():
         assert reader(dict(run(), trace=None)) is None
-        # a trace reduced without scope_s (the benchmark's reduction today)
+        # a trace reduced without scope_s (a run with no device plane)
         assert reader(dict(run(), trace={"busy_s": 12.0})) is None
     assert scopes.attention_roofline(run(attention=0.0)) is None
     assert scopes.lm_head_roofline(run(lm_head=0.0)) is None
@@ -206,14 +226,15 @@ def test_recorded_trace_predates_the_scopes(recorded):
     r = red.reduce(recorded, kinds, hlo)
     total = sum(by_op.values())
     assert 0 < total <= r["busy_s"]
-    assert scopes.scope_seconds(by_op) == {
-        "attention": 0.0, "lm_head": 0.0, "unscoped": pytest.approx(total)}
+    assert r["scope_s"] == {"attention": 0.0, "lm_head": 0.0,
+                            "unscoped": pytest.approx(total, rel=1e-9)}
     assert sum(v for (_, n, _), v in by_op.items()
                if n and red.GEMM_SCOPE in n) == pytest.approx(r["gemm_s"])
     steps = [{"kind": k, "batch": 4, "q_start": 0, "q_len": 1,
               "logits_rows": 4} for k in kinds]
     out = scopes.summary(by_op, r, {"steps": steps, "peak": PEAK,
-                                    "dims": DIMS})
+                                    "family": FAMILY, "dims": DIMS})
+    assert out["scope_s"] == r["scope_s"]
     assert out["readings"] == dict.fromkeys(scopes.READERS)
     assert out["parts"]["prefill:unscoped"] > out["parts"]["decode:unscoped"]
 
@@ -223,7 +244,7 @@ def test_traced_window_runs_at_smoke_width():
     CPU trace has no TPU plane, so nothing is read."""
     from test_bench_harness import SMOKE_CONFIG, SMOKE_TRAFFIC
     cell = harness.Cell(name="smoke", config=dict(SMOKE_CONFIG),
-                        traffic=dict(SMOKE_TRAFFIC), limits={},
+                        family=FAMILY, traffic=dict(SMOKE_TRAFFIC), limits={},
                         end_to_end=[], per_layer=[])
     out = scopes.traced_window(cell, 2 ** 33 + 5, 1.0, PEAK)
     assert out["routed_gemms"] == {"prefill": (7, 1), "decode": (7, 1)}

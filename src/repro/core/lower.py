@@ -72,6 +72,7 @@ ATTN_SEQ_NOT_DIVISIBLE = "attn_seq_not_divisible"    # ring needs sq % dm -> fla
 ATTN_KV_NOT_DIVISIBLE = "attn_kv_not_divisible"      # skv % dm -> unfused_attn
 ATTN_HEADS_REPLICATED = "attn_heads_replicated"      # h/hkv vs dn -> replicate heads (kwarg demotion)
 ATTN_UNKNOWN_COMPOSITION = "attn_unknown_composition"  # unrecognized -> flat_merge
+ATTN_ONE_DEVICE_COPY = "attn_one_device_copy"  # K/V a copy, one device -> unfused_attn
 
 REASONS = (NON_SQUARE_SYSTOLIC, NON_SQUARE_INNER, INNER_GRID_MISMATCH,
            NON_SQUARE_OUTER, OUTER_RING_TOO_SMALL, GRID_MISMATCH, GK_IS_ONE,
@@ -79,7 +80,7 @@ REASONS = (NON_SQUARE_SYSTOLIC, NON_SQUARE_INNER, INNER_GRID_MISMATCH,
            K_NOT_DIVISIBLE, SCATTER_M_INDIVISIBLE, INNER_KERNEL_TOO_LARGE,
            INNER_KERNEL_UNALIGNED,
            ATTN_SEQ_NOT_DIVISIBLE, ATTN_KV_NOT_DIVISIBLE,
-           ATTN_HEADS_REPLICATED, ATTN_UNKNOWN_COMPOSITION)
+           ATTN_HEADS_REPLICATED, ATTN_UNKNOWN_COMPOSITION, ATTN_ONE_DEVICE_COPY)
 
 # modes an ExecPlan can resolve to (superset of gemm.MODES: the 3-D split-K
 # and both hierarchical modes need a mesh view, so they are plan-only).
@@ -440,7 +441,8 @@ def lower_schedule(schedule, mesh, row_axis: str = "data",
 
 
 def lower_attention(schedule, mesh, row_axis: str = "data",
-                    col_axis: str = "model", shape=None) -> ExecPlan:
+                    col_axis: str = "model", shape=None,
+                    kv_copy: bool = False) -> ExecPlan:
     """Resolve an `AttnSchedule` into an `ExecPlan` for `mesh`.
 
     Mirrors `lower_schedule`'s contract: duck-typed schedule, namespace
@@ -451,7 +453,11 @@ def lower_attention(schedule, mesh, row_axis: str = "data",
                   --attn_kv_not_divisible--> unfused_attn
 
     plus the kwarg demotion `attn_heads_replicated` (heads replicate over
-    the column axis instead of sharding; the mode stays fused). The degrade
+    the column axis instead of sharding; the mode stays fused), and
+    `attn_one_device_copy`: where the caller's K/V are a copy made for the
+    fused executor (`kv_copy`, MLA's concatenated latent view) and the mesh
+    is one device, the executor would distribute nothing and the copy is
+    pure cost, so the call lowers to `unfused_attn`. The degrade
     target is the explicit `unfused_attn` mode — the legacy
     projections+chunked_sdpa path — never the silent `auto`.
     """
@@ -482,7 +488,11 @@ def lower_attention(schedule, mesh, row_axis: str = "data",
     kwargs: Dict[str, Any] = {}
 
     # both fused compositions shard KV over the row axis
-    if shp.skv % dm:
+    if kv_copy and dm * dn == 1:
+        fall(ATTN_ONE_DEVICE_COPY, mode, "unfused_attn")
+        mode = "unfused_attn"
+        axes, kwargs = {"row": row_axis, "col": col_axis}, {}
+    elif shp.skv % dm:
         fall(ATTN_KV_NOT_DIVISIBLE, mode, "unfused_attn")
         mode = "unfused_attn"
         axes, kwargs = {"row": row_axis, "col": col_axis}, {}

@@ -404,14 +404,14 @@ class Planner:
 # ---------------------------------------------------------------------------
 
 def moe_dispatch_geometry(tokens: int, cfg, dp: int = 1) -> Tuple[int, int]:
-    """(dispatch groups, per-group expert capacity) for `tokens` tokens.
+    """(dispatch groups, per-group expert capacity) for `tokens` tokens on
+    the expert-sharded mesh path (`moe._capacity_dispatch`).
 
     Pure-int mirror of `repro.models.moe._dp_groups` / `_capacity` (the
     deploy layer must stay importable without jax, so the logic is duplicated
     here; tests/test_plan_routing.py pins the two in sync by comparing this
-    prediction against the shapes moe.apply_moe actually records). `dp` is
-    the data-parallel shard count the dispatch groups align to (1 when no
-    mesh is installed).
+    prediction against the shapes the mesh path records). `dp` is the
+    data-parallel shard count the dispatch groups align to.
     """
     group_tokens = 512                      # moe._GROUP_TOKENS
     if tokens % dp:
@@ -425,15 +425,18 @@ def moe_dispatch_geometry(tokens: int, cfg, dp: int = 1) -> Tuple[int, int]:
 
 
 def model_workload(cfg, batch: int, seq: int,
-                   kind: str = "prefill", dp: int = 1) -> List[GEMMShape]:
+                   kind: str = "prefill",
+                   dp: Optional[int] = None) -> List[GEMMShape]:
     """Deduplicated projection GEMMs of one forward pass of `cfg`.
 
     `cfg` is a `repro.models.common.ModelConfig` (duck-typed so the deploy
     layer stays importable without jax). Token dimension M is batch*seq for
     train/prefill and batch for decode; weights supply (K, N). `dp` is the
-    data-parallel shard count of the mesh the model will trace under (1
-    when no mesh is installed) — it feeds the MoE dispatch-group geometry,
-    which aligns groups to the DP axes.
+    data-parallel shard count of the activation mesh the model will trace
+    under, None when none is installed. It picks the MoE layer's expert
+    GEMMs: with no activation mesh the held experts' grouped GEMMs over
+    the static budget of tokens*k rows; under one, the capacity-bounded
+    dispatch buffers, whose groups align to the DP axes.
 
     These are the shapes the model stack actually traces through
     `models.matmul.pmm` — every entry is checked against the recorded
@@ -535,12 +538,14 @@ def model_workload(cfg, batch: int, seq: int,
         gemm(tokens, cfg.d_ff, d)                       # gate / up (identical)
         gemm(tokens, d, cfg.d_ff)                       # down
     if cfg.n_experts and cfg.moe_top_k:
-        # per-expert M is the capacity-bounded dispatch buffer, not the mean
-        # token count: each (group, expert) GEMM runs at exactly `cap` rows
-        _, cap = moe_dispatch_geometry(tokens, cfg, dp=dp)
+        # expert M: the grouped GEMMs' static row budget (tokens * k), or
+        # under a mesh the capacity-bounded dispatch buffer, at which each
+        # (group, expert) GEMM runs
+        rows = (tokens * cfg.moe_top_k if dp is None
+                else moe_dispatch_geometry(tokens, cfg, dp=dp)[1])
         gemm(tokens, cfg.n_experts, d)                  # router
-        gemm(cap, cfg.moe_d_ff, d)                      # expert gate / up
-        gemm(cap, d, cfg.moe_d_ff)                      # expert down
+        gemm(rows, cfg.moe_d_ff, d)                     # expert gate / up
+        gemm(rows, d, cfg.moe_d_ff)                     # expert down
         if getattr(cfg, "n_shared_experts", 0):
             sh_ff = cfg.moe_d_ff * cfg.n_shared_experts
             gemm(tokens, sh_ff, d)

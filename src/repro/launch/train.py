@@ -81,8 +81,9 @@ def main():
                                 args.plan_candidates)
         # dp: MoE dispatch groups align to the mesh's DP axes when the
         # activation-sharding context is installed (production runs)
-        dp = 1
+        dp = None
         if shard_ctx.get_mesh() is not None:
+            dp = 1
             for a in ("pod", "data"):
                 if a in mesh.axis_names:
                     dp *= mesh.shape[a]

@@ -18,7 +18,7 @@ from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.models import shard_ctx
 from repro.models.common import (ModelConfig, Params, apply_rope, dense_init,
-                                 rms_head_norm, rope_tables)
+                                 rms_head_norm, rms_norm, rope_tables)
 from repro.models.matmul import pattn, pmm
 
 NEG_INF = -1e30
@@ -234,19 +234,24 @@ def _layer(stack: jax.Array, layer) -> jax.Array:
 
 
 def _write_rows(cache: Dict[str, jax.Array], layer, rows: Dict[str, jax.Array],
-                seq_axis: int) -> Tuple[Dict[str, jax.Array], jax.Array]:
+                seq_axis) -> Tuple[Dict[str, jax.Array], jax.Array]:
     """Write this step's rows into layer `layer` of each stacked cache
     tensor at the layer's write index, in place: one dynamic_update_slice
     per tensor, at (layer, 0, .., index, .., 0) with the index on
-    `seq_axis` of the layer. Returns the updated cache and the index."""
+    `seq_axis` of the layer (one axis for every tensor, or a dict of them
+    by name). Returns the updated cache and the index."""
+    def axis(name):
+        return seq_axis[name] if isinstance(seq_axis, dict) else seq_axis
+
     idx = _layer(cache["index"], layer)
-    s = next(iter(rows.values())).shape[seq_axis]
+    name0, x0 = next(iter(rows.items()))
+    s = x0.shape[axis(name0)]
     with jax.named_scope("kv_cache.write"):
         new = {"index": jax.lax.dynamic_update_index_in_dim(
             cache["index"], idx + s, layer, 0)}
         for name, x in rows.items():
             at = [0] * x.ndim
-            at[seq_axis] = idx
+            at[axis(name)] = idx
             new[name] = _stored_order(jax.lax.dynamic_update_slice(
                 cache[name], x[None], (layer, *at)))
     return new, idx
@@ -344,8 +349,10 @@ def mla_params(key, cfg: ModelConfig) -> Params:
         "w_uv": dense_init(ks[3], cfg.kv_lora_rank, cfg.n_heads * dn, cfg.dtype),
         "wo": dense_init(ks[4], cfg.n_heads * dn, cfg.d_model, cfg.dtype),
     }
+    p["kv_norm"] = {"scale": jnp.ones((cfg.kv_lora_rank,), jnp.float32)}
     if cfg.q_lora_rank:
         p["w_dq"] = dense_init(ks[5], cfg.d_model, cfg.q_lora_rank, cfg.dtype)
+        p["q_norm"] = {"scale": jnp.ones((cfg.q_lora_rank,), jnp.float32)}
         p["w_uq"] = dense_init(ks[6], cfg.q_lora_rank,
                                cfg.n_heads * (dn + dr), cfg.dtype)
     else:
@@ -353,12 +360,50 @@ def mla_params(key, cfg: ModelConfig) -> Params:
     return p
 
 
+def _mla_scale(cfg: ModelConfig) -> float:
+    """The softmax scale: (dn + dr)^-1/2, times YaRN's mscale^2."""
+    scale = (cfg.nope_head_dim + cfg.rope_head_dim) ** -0.5
+    if cfg.rope_scaling is not None:
+        scale *= cfg.rope_scaling.softmax_factor()
+    return scale
+
+
+def _latent_sdpa(q_lat, q_rope, c_kv, k_rope_t, q_positions, kv_len,
+                 scale):
+    """Absorbed MLA attention against one layer of the latent cache as
+    stored: scores are q_lat . c_kv + q_rope . k_rope, the values c_kv
+    itself. q_lat (b, sq, h, r), q_rope (b, sq, h, dr); c_kv (b, sk, r),
+    and the rotary key transposed, k_rope_t (b, dr, sk). Each product is
+    one batched matrix product over the sq * h query rows of a sequence,
+    and reads the layer where it lies, with no concatenated (b, sk, r + dr)
+    copy of it."""
+    b, sq, h, r = q_lat.shape
+    sk = c_kv.shape[1]
+    rows = (b, sq * h)
+    logits = (jnp.einsum("bmr,bkr->bmk", q_lat.reshape(*rows, r), c_kv,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bmd,bdk->bmk", q_rope.reshape(*rows, -1),
+                           k_rope_t, preferred_element_type=jnp.float32))
+    logits = logits.reshape(b, sq, h, sk) * scale
+    kpos = jnp.arange(sk)
+    mask = ((kpos[None, :] <= q_positions[:, None])
+            & (kpos < kv_len)[None, :])                     # (sq, sk)
+    logits = jnp.where(mask[None, :, None, :], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1).astype(c_kv.dtype)
+    out = jnp.einsum("bmk,bkr->bmr", probs.reshape(*rows, sk), c_kv)
+    return out.reshape(b, sq, h, r)
+
+
 def mla_attention(p: Params, x: jax.Array, cfg: ModelConfig,
                   positions: jax.Array,
                   cache: Optional[Dict[str, jax.Array]] = None,
                   layer=0,
                   ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
-    """MLA. Two execution forms, as in DeepSeek-V2's own deployment:
+    """MLA. The query's and the KV's latents are RMS-normed after their
+    down-projections, and the normed KV latent is what is cached; the
+    rotary part uses the configuration's `rope_scaling` (YaRN), whose
+    mscale^2 also scales the softmax. Two execution forms, as in
+    DeepSeek-V2's own deployment:
 
     - train/prefill (no cache): the NAIVE form — up-project K/V from c_kv and
       run flash attention at head dim (dn + dr). Projection FLOPs are
@@ -373,32 +418,25 @@ def mla_attention(p: Params, x: jax.Array, cfg: ModelConfig,
     dn, dr, h, r = cfg.nope_head_dim, cfg.rope_head_dim, cfg.n_heads, cfg.kv_lora_rank
 
     if cfg.q_lora_rank:
-        q = pmm(pmm(x, p["w_dq"], tag="mla.q_down"), p["w_uq"],
-                tag="mla.q_up")
+        q_lat = pmm(x, p["w_dq"], tag="mla.q_down")
+        with jax.named_scope("norm"):
+            q_lat = rms_norm(p["q_norm"], q_lat)
+        q = pmm(q_lat, p["w_uq"], tag="mla.q_up")
     else:
         q = pmm(x, p["wq"], tag="mla.q")
     q = q.reshape(b, s, h, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
 
     c_kv = pmm(x, p["w_dkv"], tag="mla.kv_down")           # (b, s, r)
+    with jax.named_scope("norm"):
+        c_kv = rms_norm(p["kv_norm"], c_kv)
     k_r = pmm(x, p["w_kr"], tag="mla.k_rope").reshape(b, s, 1, dr)
 
-    cos, sin = rope_tables(positions, dr, cfg.rope_theta)
+    cos, sin = rope_tables(positions, dr, cfg.rope_theta, cfg.rope_scaling)
     q_rope = apply_rope(q_rope, cos, sin)
     k_r = apply_rope(k_r, cos, sin)
 
-    if cache is not None:
-        new_cache, idx = _write_rows(cache, layer,
-                                     {"c_kv": c_kv, "k_rope": k_r},
-                                     seq_axis=1)
-        c_kv = _layer(new_cache["c_kv"], layer)
-        k_r = _layer(new_cache["k_rope"], layer)
-        kv_len = idx + s
-    else:
-        new_cache = None
-        kv_len = None
-
-    scale = (dn + dr) ** -0.5
+    scale = _mla_scale(cfg)
     if cache is None:
         # naive form: up-project K/V once, flash attention at dim dn + dr.
         sk = c_kv.shape[1]
@@ -416,7 +454,7 @@ def mla_attention(p: Params, x: jax.Array, cfg: ModelConfig,
         out = pattn(q_full, k_full, v, causal=True, scale=scale,
                     tag="mla.sdpa", unfused=unfused)
         out = out.reshape(b, s, h * dn)
-        return pmm(out, p["wo"], tag="mla.o"), new_cache
+        return pmm(out, p["wo"], tag="mla.o"), None
 
     # absorbed form (decode): q_lat[h] = q_nope[h] @ W_uk[h]^T  (b,s,h,r)
     # per-head batched contraction, not a single dense GEMM — stays einsum
@@ -427,15 +465,27 @@ def mla_attention(p: Params, x: jax.Array, cfg: ModelConfig,
     record_gemm("mla.q_absorb", b * s, r, dn, count=h)
     w_uk = p["w_uk"].reshape(r, h, dn)
     q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk)
+    new_cache, idx = _write_rows(
+        cache, layer, {"c_kv": c_kv, "k_rope_t": k_r[:, :, 0].swapaxes(1, 2)},
+        seq_axis={"c_kv": 1, "k_rope_t": 2})
+    c_kv = _layer(new_cache["c_kv"], layer)                # (b, sk, r)
+    k_rt = _layer(new_cache["k_rope_t"], layer)            # (b, dr, sk)
+    kv_len = idx + s
+
+    def latent():
+        return _latent_sdpa(q_lat, q_rope, c_kv, k_rt, positions, kv_len,
+                            scale)
+
+    # the fused executor's MQA view of the layer (key dim r + dr): a
+    # concatenated copy of the latent cache, which `latent` never reads
     q_aug = jnp.concatenate([q_lat, q_rope], axis=-1)      # (b,s,h,r+dr)
-    k_aug = jnp.concatenate([c_kv[:, :, None, :], k_r], axis=-1)  # (b,sk,1,r+dr)
+    k_aug = jnp.concatenate([c_kv, k_rt.swapaxes(1, 2)],
+                            axis=-1)[:, :, None, :]        # (b,sk,1,r+dr)
     v_lat = c_kv[:, :, None, :]                            # (b,sk,1,r)
     kv_len_b = jnp.full((b,), kv_len, jnp.int32)
     o_lat = pattn(q_aug, k_aug, v_lat, causal=True, q_positions=positions,
                   kv_len=kv_len_b, scale=scale, tag="mla.decode",
-                  unfused=lambda: _sdpa(q_aug, k_aug, v_lat, causal=True,
-                                        q_positions=positions,
-                                        kv_len=kv_len_b, scale=scale))
+                  unfused=latent, kv_copy=True)
     # un-absorb the values: out[h] = o_lat @ W_uv[h] — again h per-head
     # (b*s, dn, r) contractions in one einsum
     record_gemm("mla.v_unabsorb", b * s, dn, r, count=h)
@@ -449,14 +499,16 @@ def make_kv_cache(cfg: ModelConfig, n_layers: int, batch: int,
     """Preallocated cache of a stack of `n_layers` attention layers, with
     each layer's write position in `index`. GQA K/V are (L, B, Hkv, S, D),
     the order the attention products read, so a decode step reads a layer
-    where it lies and writes its rows in place. MLA caches the latent
-    (L, B, S, r) and the shared rotary key (L, B, S, 1, dr)."""
+    where it lies and writes its rows in place. MLA caches the normed
+    latent (L, B, S, r) and the shared rotary key transposed,
+    (L, B, dr, S): the order the score product reads a 64-wide key in,
+    which XLA would otherwise relay around the layer loop."""
     if cfg.attn == "mla":
         return {
             "c_kv": jnp.zeros((n_layers, batch, max_len, cfg.kv_lora_rank),
                               cfg.dtype),
-            "k_rope": jnp.zeros((n_layers, batch, max_len, 1,
-                                 cfg.rope_head_dim), cfg.dtype),
+            "k_rope_t": jnp.zeros((n_layers, batch, cfg.rope_head_dim,
+                                   max_len), cfg.dtype),
             "index": jnp.zeros((n_layers,), jnp.int32),
         }
     kv = (n_layers, batch, cfg.n_kv_heads, max_len, cfg.hd)

@@ -211,7 +211,8 @@ def _attn_shape(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def _dispatch_attn(ctx, q, k, v, shape: AttnShape, causal, scale,
-                   q_positions, kv_len, unfused, prov: dict, tracer):
+                   q_positions, kv_len, unfused, prov: dict, tracer,
+                   kv_copy: bool = False):
     """Routed attention dispatch: plan consult -> lower_attention ->
     flat_attention, mirroring `_dispatch_routed` step for step. A shape
     with no plan (or one lowered to `unfused_attn`) executes the caller's
@@ -238,7 +239,8 @@ def _dispatch_attn(ctx, q, k, v, shape: AttnShape, causal, scale,
         return unfused()
     from repro.core.lower import lower_attention
     exec_plan = lower_attention(getattr(plan, "schedule", plan), ctx.mesh,
-                                ctx.row_axis, ctx.col_axis, shape=shape)
+                                ctx.row_axis, ctx.col_axis, shape=shape,
+                                kv_copy=kv_copy)
     ctx.stats.record_lowering(exec_plan)
     prov.update(provenance=kind, mode=exec_plan.mode,
                 reasons=list(exec_plan.reasons()),
@@ -261,18 +263,22 @@ def _dispatch_attn(ctx, q, k, v, shape: AttnShape, causal, scale,
 
 def pattn(q: jax.Array, k: jax.Array, v: jax.Array, *, unfused,
           causal: bool = True, tag: str = "", scale=None,
-          q_positions=None, kv_len=None) -> jax.Array:
+          q_positions=None, kv_len=None, kv_copy: bool = False) -> jax.Array:
     """Plan-routed attention. q: (b, sq, h, d); k/v: (b, skv, hkv, d|dv) ->
     (b, sq, h, dv). `unfused` is the zero-arg reference path the call
     degrades to when routing is off or the lowering says fused is illegal
     — every degrade is counted and carries a machine-readable reason.
+    `kv_copy`: q/k/v are a view built for the fused executor that `unfused`
+    does not read (so XLA drops it whenever the call degrades), and on one
+    device the call takes `unfused` (`lower_attention`).
     Either path emits its ops under `jax.named_scope("pattn.<tag>")`."""
     with jax.named_scope(f"pattn.{tag or 'untagged'}"):
         return _pattn(q, k, v, unfused, causal, tag, scale, q_positions,
-                      kv_len)
+                      kv_len, kv_copy)
 
 
-def _pattn(q, k, v, unfused, causal, tag, scale, q_positions, kv_len):
+def _pattn(q, k, v, unfused, causal, tag, scale, q_positions, kv_len,
+           kv_copy):
     ctx = shard_ctx.get_gemm_context()
     if ctx is None:
         return unfused()
@@ -290,13 +296,15 @@ def _pattn(q, k, v, unfused, causal, tag, scale, q_positions, kv_len):
         return unfused()
     if tracer is None:
         return _dispatch_attn(ctx, q, k, v, shape, causal, scale,
-                              q_positions, kv_len, unfused, {}, None)
+                              q_positions, kv_len, unfused, {}, None,
+                              kv_copy)
     t0 = time.perf_counter()
     with tracer.span(f"pattn.{tag or 'untagged'}", cat=obs_trace.CAT_PMM,
                      tag=tag, shape=[shape.b, shape.sq, shape.skv, shape.h,
                                      shape.hkv, shape.d, shape.dv]) as prov:
         out = _dispatch_attn(ctx, q, k, v, shape, causal, scale,
-                             q_positions, kv_len, unfused, prov, tracer)
+                             q_positions, kv_len, unfused, prov, tracer,
+                             kv_copy)
     dispatch_us = (time.perf_counter() - t0) * 1e6
     tracer.metrics.counter(f"pattn.provenance.{prov['provenance']}").inc()
     tracer.metrics.observe(

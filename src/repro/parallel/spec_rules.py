@@ -140,10 +140,9 @@ def cache_spec(path: Tuple[Any, ...], leaf: jax.ShapeDtypeStruct,
     if name == "c_kv":              # (L, B, S, r)
         return _fit(P(None, dp if batch_ok else None,
                       None if batch_ok else "data", "model"), leaf.shape, mesh)
-    if name == "k_rope":            # (L, B, S, 1, dr)
-        return _fit(P(None, dp if batch_ok else None,
-                      None if batch_ok else "data", None, None),
-                    leaf.shape, mesh)
+    if name == "k_rope_t":          # (L, B, dr, S)
+        return _fit(P(None, dp if batch_ok else None, None,
+                      None if batch_ok else "data"), leaf.shape, mesh)
     if name == "h":                 # mamba (L, B, H, N, P) / slstm (L,B,H,hd)
         return _fit(P(None, dp if batch_ok else None, "model"), leaf.shape, mesh)
     if name in ("c", "n", "m"):     # xlstm states (L, B, H, ...)

@@ -100,6 +100,23 @@ def test_lower_kv_indivisible_demotes_to_unfused():
     assert ep.kwargs == {}
 
 
+@pytest.mark.parametrize("grid", [(1, 1), (4, 4)], ids=["one", "4x4"])
+def test_lower_kv_copy_takes_unfused_on_one_device(grid):
+    # K/V built only for the fused executor (MLA's latent view): on one
+    # device it distributes nothing, so the call degrades, with its reason
+    import types
+    mesh = types.SimpleNamespace(shape=dict(zip(("data", "model"), grid)))
+    ep = lower_attention(_sched(_shape(hkv=1)), mesh, "data", "model",
+                         kv_copy=True)
+    if grid == (1, 1):
+        assert ep.mode == "unfused_attn" and ep.kwargs == {}
+        assert ep.reasons() == (lower.ATTN_ONE_DEVICE_COPY,)
+        plain = lower_attention(_sched(_shape(hkv=1)), mesh, "data", "model")
+        assert plain.mode == "flat_merge" and not plain.reasons()
+    else:
+        assert ep.mode == "flat_merge" and not ep.reasons()
+
+
 def test_lower_heads_replicated_is_kwarg_demotion():
     # hkv=2 neither divides dn=4 nor is 1 -> replicate heads, mode unchanged
     ep = lower_attention(_sched(_shape(hkv=2)), FakeMesh(), "data", "model")
@@ -405,8 +422,8 @@ def test_mla_absorbed_decode_counts_per_head():
     cache = {                                   # one layer
         "c_kv": jnp.asarray(rng.standard_normal(
             (1, b, max_len, cfg.kv_lora_rank)), cfg.dtype),
-        "k_rope": jnp.asarray(rng.standard_normal(
-            (1, b, max_len, 1, cfg.rope_head_dim)), cfg.dtype),
+        "k_rope_t": jnp.asarray(rng.standard_normal(
+            (1, b, cfg.rope_head_dim, max_len)), cfg.dtype),
         "index": jnp.asarray([4], jnp.int32),
     }
     x = jnp.asarray(rng.standard_normal((b, 1, cfg.d_model)), cfg.dtype)

@@ -247,23 +247,34 @@ def test_model_workload_cross_validation_decode(kind):
     assert cov["missing"] == [], f"never-executed shapes: {cov['missing']}"
 
 
-def test_moe_geometry_prediction_matches_model():
-    """moe_dispatch_geometry (deploy, jax-free) must stay in sync with the
-    dispatch-group/capacity logic moe.apply_moe actually uses — the expert
-    GEMM shapes it records are the check."""
+@pytest.mark.parametrize("meshed", [False, True],
+                         ids=["held_experts", "expert_sharded_mesh"])
+def test_moe_geometry_prediction_matches_model(meshed):
+    """The expert GEMM shapes model_workload (deploy, jax-free) predicts
+    are the ones moe.apply_moe records: with no activation mesh, the held
+    experts' grouped GEMMs at the static budget of tokens * k rows; under
+    one, the capacity-bounded dispatch buffers (moe_dispatch_geometry)."""
     cfg = smoke_config("deepseek-moe-16b")
     b, s = 2, 16
-    _, cap = moe_dispatch_geometry(b * s, cfg)
+    rows = (moe_dispatch_geometry(b * s, cfg)[1] if meshed
+            else b * s * cfg.moe_top_k)
+    predicted = set(model_workload(cfg, b, s, dp=1 if meshed else None))
     ctx = GemmContext(mesh=None)
-    with shard_ctx.gemm_context(ctx):
-        pshapes = jax.eval_shape(
-            lambda: init_params(jax.random.PRNGKey(0), cfg))
-        toks = jax.ShapeDtypeStruct((b, s), jnp.int32)
-        jax.eval_shape(lambda p, t: forward(p, t, cfg), pshapes, toks)
+    mesh = make_mesh((1, 1), ("data", "model")) if meshed else None
+    shard_ctx.set_mesh(mesh)
+    try:
+        with shard_ctx.gemm_context(ctx):
+            pshapes = jax.eval_shape(
+                lambda: init_params(jax.random.PRNGKey(0), cfg))
+            toks = jax.ShapeDtypeStruct((b, s), jnp.int32)
+            jax.eval_shape(lambda p, t: forward(p, t, cfg), pshapes, toks)
+    finally:
+        shard_ctx.set_mesh(None)
     expert_shapes = {shape for (tag, shape) in ctx.stats.observed
                      if tag.startswith("moe.expert")}
-    assert expert_shapes == {GEMMShape(cap, cfg.moe_d_ff, cfg.d_model),
-                             GEMMShape(cap, cfg.d_model, cfg.moe_d_ff)}
+    assert expert_shapes == {GEMMShape(rows, cfg.moe_d_ff, cfg.d_model),
+                             GEMMShape(rows, cfg.d_model, cfg.moe_d_ff)}
+    assert expert_shapes <= predicted
 
 
 # ---------------------------------------------------------------------------

@@ -135,12 +135,15 @@ def test_decode_matches_forward_logits(arch, dtype, tol):
     """Chunked prefill, then one token a step, must agree with
     teacher-forced forward at every position a step returns: MHA, GQA,
     MLA and the MoE groups, each layer with weights of its own, so a step
-    that read or wrote another layer's cache would miss. Expert buffers
-    hold every token, so that forward and the steps drop none."""
+    that read or wrote another layer's cache would miss. The MoE layer
+    drops no token, so forward over 16 tokens and a step over 4 or 1 route
+    each token alike (the capacity-bounded layer this replaced needed its
+    buffers raised here to drop none)."""
     cfg = dataclasses.replace(smoke_config(arch), dtype=dtype)
     if cfg.n_experts:
-        cfg = dataclasses.replace(
-            cfg, capacity_factor=cfg.n_experts / cfg.moe_top_k)
+        # half the experts held: the steps and forward compute the same
+        # share, and a token routed elsewhere must add nothing in either
+        cfg = dataclasses.replace(cfg, moe_n_held=cfg.n_experts // 2)
     params = init_params(KEY, cfg)
     rng = np.random.default_rng(3)
     b, prompt, chunk, total = 2, 12, 4, 16

@@ -10,7 +10,10 @@ Nothing runs: they assert only that the programs compile and, for the
 routed step, that the Pallas kernel is in it (`tpu_custom_call`). One more
 reads each benchmark cell's compiled serve step at the cell's sizes for
 the buffers it holds: the KV cache carried through the layer loop and
-written in place, with no copy of a layer or of the stack.
+written in place, with no copy of a layer or of the stack. The
+DeepSeek-V2 cell's step, at its whole cut, is read the same way for its
+latent cache, and for MoE layers that run their held experts as grouped
+GEMMs with no one-hot dispatch tensor.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and the fixture skips the
@@ -42,8 +45,8 @@ from repro.launch.mesh import make_mesh
 from repro.models import shard_ctx
 from repro.models.model import decode_init, init_params
 from repro.train.steps import make_serve_step
-from serve_scopes import (assert_names_every_part, compile_donated_steps,
-                          kv_copies)
+from serve_scopes import (_buffers, assert_names_every_part,
+                          compile_donated_steps, kv_copies)
 
 from bench import harness  # on the path serve_scopes sets
 from bench.reduce import routed_gemms
@@ -228,3 +231,69 @@ def test_step_carries_the_kv_cache_in_place(cell_steps, kind):
     assert mem.alias_size_in_bytes >= 2 * cfg.n_layers * layer_bytes
     if kind == "decode":
         assert mem.temp_size_in_bytes < layer_bytes
+
+
+MLA_MOE_CELL = "deepseek-v2-236b-ep8.azure-conv-b128"
+
+
+@pytest.fixture(scope="module")
+def mla_moe_steps(chip_mesh, one_chip, tmp_path_factory):
+    """The DeepSeek-V2 cell's routed serve step at its whole cut (the dense
+    layer and 4 MoE layers of 20 held experts, full widths and vocabulary)
+    for its prefill chunk and decode step, compiled as the benchmark
+    compiles it."""
+    cell = harness.load_cell(MLA_MOE_CELL)
+    cfg = cell.family.program_config(cell.config)
+    batch, chunk, max_len = (cell.traffic[k]
+                             for k in ("batch", "chunk", "max_len"))
+    planner = serve.warm_plan_cache(
+        cfg, batch, chunk, max_len, str(tmp_path_factory.mktemp("plans")),
+        (1, 1), max_candidates=12)
+    ctx = shard_ctx.GemmContext(mesh=chip_mesh, planner=planner)
+    with compiling_for_tpu(), shard_ctx.gemm_context(ctx):
+        steps = compile_donated_steps(cfg, batch, chunk, max_len,
+                                      sharding=one_chip)
+    assert ctx.stats.routed and ctx.stats.silent_degrades == 0
+    return cell, cfg, (batch, max_len), steps
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_mla_moe_step_groups_experts_and_carries_the_latent_cache(
+        mla_moe_steps, kind):
+    """The MoE layers run the held experts as grouped GEMMs (the Pallas
+    `megablox.gmm` kernel, three under `moe.experts`, no XLA `ragged-dot`)
+    and build no (groups, tokens, experts, capacity) one-hot dispatch
+    tensor; the latent cache and rotary key stacks are donated, carried
+    through both layer loops and written in place, with no copy of a layer
+    or a stack; the routed GEMM kernels are those the family expects."""
+    cell, cfg, (batch, max_len), steps = mla_moe_steps
+    compiled = steps[kind]
+    text = compiled.as_text()
+    grouped = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and "/moe.experts/" in line]
+    assert "ragged-dot" not in text
+    assert len(grouped) == 3, grouped
+    assert "gtec" not in text
+    assert not any(len(d) == 4 and d[2] in (cfg.n_experts,
+                                             cfg.held_experts[1])
+                   for _, _, dims, _, _ in _buffers(text) for d in dims)
+    n_moe = cfg.n_layers - cfg.n_dense_layers
+    cache_bytes = 0
+    for width in (cfg.kv_lora_rank, cfg.rope_head_dim):
+        layer = (batch, max_len, width)
+        cache_bytes += math.prod(layer) * 2 * cfg.n_layers
+        # the dense group's stack is one layer deep, so its in-place write
+        # reads as a layer to the MoE group's check; XLA may move that
+        # 21 MB rotary stack to another memory space and back
+        # (copy-start/copy-done, no relayout)
+        moved = ("copy-start", "copy-done")
+        assert [c for c in kv_copies(text, layer, cfg.n_dense_layers)
+                if c[0] not in moved] == []
+        for opcode, _, op_name in kv_copies(text, layer, n_moe):
+            assert opcode in moved or (
+                opcode == "dynamic-update-slice"
+                and "/kv_cache.write/" in op_name), (opcode, op_name)
+    dims = cell.family.dims(cell.config)
+    assert routed_gemms(text) == cell.family.routed_expected(dims, kind)
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes

@@ -338,6 +338,26 @@ def _aligned_block(dim: int, unit: int, align: int) -> int:
     return dim if dim < unit else unit
 
 
+# A tile of at most STREAM_ROWS rows (a decode GEMM's) does little work a
+# byte of B: its kernel streams B from HBM, and each grid step has a fixed
+# cost, so its B blocks are as large as STREAM_EDGE and the budget allow.
+STREAM_ROWS = 128
+STREAM_EDGE = 1024
+
+
+def _streaming_kernel(bm: int, tn: int, k_local: int, dtype: str,
+                      budget: int) -> Optional[InnerKernel]:
+    """The double-buffered kernel with the largest B block of LANE-multiple
+    edges up to STREAM_EDGE that divide the tile's N and K (no pad) and fit
+    `budget`; None where no such edge divides both."""
+    def edges(dim):
+        return [e for e in range(STREAM_EDGE, LANE - 1, -LANE) if dim % e == 0]
+    fits = [InnerKernel(bm, bn, bk, depth=2, dtype=dtype)
+            for bn in edges(tn) for bk in edges(k_local)]
+    fits = [ik for ik in fits if ik.working_set_bytes() <= budget]
+    return max(fits, key=lambda ik: ik.bn * ik.bk, default=None)
+
+
 def inner_kernel_candidates(sched: Schedule, hw: AcceleratorConfig,
                             max_candidates: int = 3) -> Tuple[InnerKernel, ...]:
     """Closed-form inner-kernel shortlist for one outer schedule.
@@ -348,7 +368,9 @@ def inner_kernel_candidates(sched: Schedule, hw: AcceleratorConfig,
     tile dim below the unit; `bk` sweeps down from the full K-chunk (larger bk
     amortizes pipeline refills and the accumulator flush), and the pipeline
     depth degrades from double-buffered to serialized only when the deeper
-    working set cannot fit the VMEM budget. Deterministic and ordered
+    working set cannot fit the VMEM budget. A tile of at most STREAM_ROWS
+    rows puts first the kernel with the largest B block (`_streaming_kernel`),
+    whose `bk` may span several K-chunks. Deterministic and ordered
     best-prior-first, so the pricing sweep's tie-break (first strict
     minimum wins) prefers the planner-visible kernel over the opaque
     XLA-default path at equal predicted cost.
@@ -363,6 +385,10 @@ def inner_kernel_candidates(sched: Schedule, hw: AcceleratorConfig,
     bn = _aligned_block(tn, t.ce_cols, LANE)
     budget = min(t.l1_bytes, INNER_VMEM_BUDGET)
     out = []
+    if tm <= STREAM_ROWS:
+        streaming = _streaming_kernel(bm, tn, k_local, dtype, budget)
+        if streaming is not None:
+            out.append(streaming)
     for bk in (tk, tk // 2, tk // 4):
         if bk < 1 or tk % bk or (bk % LANE and bk != k_local):
             continue

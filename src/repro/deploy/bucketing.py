@@ -23,7 +23,7 @@ import dataclasses
 import math
 from typing import Iterable, List, Optional
 
-from repro.core.schedule import GEMMShape, Schedule
+from repro.core.schedule import LANE, GEMMShape, Schedule
 from repro.hw.config import AcceleratorConfig
 
 
@@ -71,17 +71,28 @@ def nearest_tuned(shape: GEMMShape, pool: Iterable[GEMMShape],
             if distance(shape, cand) <= policy.max_distance]
 
 
+def _dividing_edge(edge: int, dim: int) -> int:
+    """`edge`, or where it does not divide `dim` the largest LANE multiple
+    below it that does (none: `edge`, which the kernel pads to)."""
+    if dim % edge == 0:
+        return edge
+    return next((e for e in range(edge - edge % LANE, LANE - 1, -LANE)
+                 if dim % e == 0), edge)
+
+
 def adapt(schedule: Schedule, shape: GEMMShape,
           hw: AcceleratorConfig) -> Optional[Schedule]:
     """Re-target `schedule` to `shape`; None if the tiling doesn't transfer.
 
     Keeps the tuned decision (logical grid, iteration factors, dataflow,
     remap, buffering) and re-derives the shape-dependent parts: the K-chunk
-    is re-clamped to the new K_local, and pinned layouts are dropped so
-    `resolve_layouts` regenerates defaults for the new matrix shapes. Only
-    tiling divisibility is checked here — the caller prices the result with
-    `build_program` + `estimate`, which performs the full legality check
-    (L1 capacity included) as a side effect.
+    is re-clamped to the new K_local, an inner kernel's `bn` / `bk` are cut
+    to edges that divide the new tile (a padded B is a copy of it), and
+    pinned layouts are dropped so `resolve_layouts` regenerates defaults for
+    the new matrix shapes. Only tiling divisibility is checked here — the
+    caller prices the result with `build_program` + `estimate`, which
+    performs the full legality check (L1 capacity included) as a side
+    effect.
     """
     tiling = schedule.tiling
     if tiling.gk == 0 or shape.k % tiling.gk:
@@ -92,10 +103,14 @@ def adapt(schedule: Schedule, shape: GEMMShape,
         tk //= 2                  # largest pow-2 chunk dividing K_local
     if k_local % tk:
         return None
-    cand = dataclasses.replace(
-        schedule, shape=shape,
-        tiling=dataclasses.replace(tiling, tk=tk),
-        layouts=None)
+    tiling = dataclasses.replace(tiling, tk=tk)
+    ik = schedule.inner_kernel
+    if ik is not None:
+        _, tn, _ = tiling.tile_dims(shape)
+        ik = dataclasses.replace(ik, bn=_dividing_edge(ik.bn, tn),
+                                 bk=_dividing_edge(ik.bk, k_local))
+    cand = dataclasses.replace(schedule, shape=shape, tiling=tiling,
+                               layouts=None, inner_kernel=ik)
     try:
         cand.tiling.validate(shape, hw.n_tiles)
     except ValueError:

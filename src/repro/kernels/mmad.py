@@ -7,6 +7,11 @@ streams (bm x bk) / (bk x bn) blocks HBM->VMEM (the placement-scheme tiles of
 grid dimension — Pallas's implicit pipelining of the grid is the paper's
 §3.3.1 double-buffered DMA/compute overlap.
 
+B may be a stack of matrices (L, K, N), a model's stacked layer weights,
+with the layer to read as a scalar-prefetch operand: B's block index map
+reads it, so the kernel streams its blocks of that layer where they lie
+and no copy of the layer is made. A 2-D B is a stack of one.
+
 Block shapes default to MXU-aligned (128, 128, 128); the K loop is the
 innermost ("arbitrary") grid dimension so the accumulator scratch carries
 across it, while M/N are "parallel" dimensions.
@@ -22,8 +27,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _mmad_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int, out_dtype):
-    """One (i, j, k) grid step: acc += A[i,k] @ B[k,j]; flush at k == n_k-1."""
+def _mmad_kernel(layer_ref, a_ref, b_ref, o_ref, acc_ref, *, n_k: int,
+                 out_dtype):
+    """One (i, j, k) grid step: acc += A[i,k] @ B[k,j]; flush at k == n_k-1.
+    `layer_ref` is read by B's index map only."""
+    del layer_ref
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -42,13 +50,21 @@ def _mmad_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int, out_dtype):
 def mmad(a: jax.Array, b: jax.Array,
          block_shape: Tuple[int, int, int] = (128, 128, 128),
          interpret: bool = False,
-         out_dtype: Optional[jnp.dtype] = None) -> jax.Array:
+         out_dtype: Optional[jnp.dtype] = None,
+         layer: Optional[jax.Array] = None) -> jax.Array:
     """C = A @ B with VMEM-tiled blocks and a float32 accumulator.
 
-    Shapes must divide by the block shape (the ops.py wrapper pads otherwise).
+    `b` is (K, N), or a stack (L, K, N) with `layer` the (traced) index of
+    the matrix to multiply by, read in place. Shapes must divide by the
+    block shape (the ops.py wrapper pads otherwise).
     """
+    if (b.ndim == 3) != (layer is not None):
+        raise ValueError(f"a stacked B needs a layer index and a 2-D B none: "
+                         f"B {b.shape}, layer {layer}")
+    if layer is None:
+        b, layer = b[None], 0
     m, k = a.shape
-    k2, n = b.shape
+    _, k2, n = b.shape
     if k != k2:
         raise ValueError(f"inner dims mismatch: {a.shape} @ {b.shape}")
     bm, bn, bk = block_shape
@@ -60,15 +76,18 @@ def mmad(a: jax.Array, b: jax.Array,
     kernel = functools.partial(_mmad_kernel, n_k=n_k, out_dtype=out_dtype)
     return pl.pallas_call(
         kernel,
-        grid=(m // bm, n // bn, n_k),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(m // bm, n // bn, n_k),
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, j, kk, l: (i, kk)),
+                pl.BlockSpec((None, bk, bn), lambda i, j, kk, l: (l[0], kk, j)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, l: (i, j)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(a, b)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), a, b)

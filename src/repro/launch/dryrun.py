@@ -428,6 +428,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                 # or a scatter demotion still execute a tuned dataflow
                 "degraded": st.modes.get("auto", 0),
                 "silent_auto_degrades": st.silent_degrades,
+                "layer_weights_in_place": st.in_place,
+                "layer_weights_sliced": st.sliced,
                 "hits": st.hits, "bucketed": st.bucketed,
                 "fallback": st.fallback,
                 "resolve_rate": st.resolve_rate,

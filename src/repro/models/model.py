@@ -30,7 +30,7 @@ from repro.models import moe as moe_lib
 from repro.models import ssm
 from repro.models.common import (ModelConfig, Params, apply_mlp, apply_norm,
                                  dense_init, mlp_params, norm_params)
-from repro.models.matmul import pmm
+from repro.models.matmul import LayerWeight, pmm
 
 
 # ---------------------------------------------------------------------------
@@ -323,11 +323,28 @@ def lm_head_weight(params: Params, cfg: ModelConfig) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# decode (serve) path — one scan over each layer group. The group's weights
-# are the scan's xs; its stacked KV cache rides in the carry beside the layer
-# counter, so each layer reads its K/V where they lie and writes its rows in
-# place. Recurrent states (mamba2, xLSTM), KB to MB a layer, stay in xs/ys.
+# decode (serve) path — one scan over each layer group. Its stacked KV cache
+# rides in the carry beside the layer counter, so each layer reads its K/V
+# where they lie and writes its rows in place. Its GEMM weights stay out of
+# the scan's xs: each layer's GEMMs get their stack and the layer counter
+# (`LayerWeight`) and read the layer where it lies. The small leaves (norms,
+# biases, the router, MLA's w_uk / w_uv that XLA's einsums read) are the
+# xs. Recurrent states (mamba2, xLSTM), KB to MB a layer, stay in xs/ys.
 # ---------------------------------------------------------------------------
+
+# stacked leaves that are matrices a layer reads outside pmm and the grouped
+# expert GEMMs, or too narrow for a kernel block (the router's 160 columns)
+_SLICED_WEIGHTS = ("router", "w_uk", "w_uv")
+
+
+def _by_reference(path, leaf) -> bool:
+    """Whether the layer loop hands a stacked leaf to its layer as a
+    `LayerWeight`: the matrices its GEMMs read, (L, K, N) or the experts'
+    (L, E, K, N). Under an activation mesh every leaf is sliced: its
+    partitioned GEMMs and the capacity dispatch read whole arrays."""
+    return (shard_ctx.get_mesh() is None and leaf.ndim >= 3
+            and getattr(path[-1], "key", None) not in _SLICED_WEIGHTS)
+
 
 def decode_init(params: Params, cfg: ModelConfig, batch: int,
                 max_len: int) -> Dict[str, Any]:
@@ -377,10 +394,12 @@ def decode_step(params: Params, caches: Dict[str, Any], tokens: jax.Array,
 
     Every op the step emits sits under one top-level `jax.named_scope`
     (`embed`, `block`, `final_norm`, `lm_head`), except the layer scan's
-    own slicing of the stacked weights (and of recurrent states), which
-    stays outside `block` so a device profile tells the plumbing from the
-    layers (docs/observability.md). KV caches are carried, not sliced:
-    their only writes are the in-place ones under `kv_cache.write`."""
+    own slicing of its small stacked leaves (and of recurrent states),
+    which stays outside `block` so a device profile tells the plumbing from
+    the layers (docs/observability.md). KV caches are carried, not sliced:
+    their only writes are the in-place ones under `kv_cache.write`. GEMM
+    weights are not sliced either: each layer's GEMMs read their layer of
+    the stack where it lies."""
     with jax.named_scope("embed"):
         x = shard_ctx.constrain_tokens(
             jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype))
@@ -400,12 +419,20 @@ def decode_step(params: Params, caches: Dict[str, Any], tokens: jax.Array,
                                  x, cache, i)
             return x, cache
 
+        flat, tree = jax.tree_util.tree_flatten_with_path(stacked_params)
+        by_ref = [_by_reference(path, leaf) for path, leaf in flat]
+        xs = [None if r else leaf for r, (_, leaf) in zip(by_ref, flat)]
+
         def step(carry, lp):
             h, layer, c = carry
+            lp = jax.tree_util.tree_unflatten(tree, [
+                LayerWeight(leaf, layer) if r else sliced
+                for r, (_, leaf), sliced in zip(by_ref, flat, lp)])
             h, c = block(block_fn, lp, h, c, layer)
             return (h, layer + 1, c), None
         (x, _, cache), _ = jax.lax.scan(
-            step, (x, jnp.zeros((), jnp.int32), cache), stacked_params)
+            step, (x, jnp.zeros((), jnp.int32), cache), xs,
+            length=flat[0][1].shape[0])
         return x, cache
 
     new_caches = dict(caches)

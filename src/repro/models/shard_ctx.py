@@ -82,6 +82,12 @@ class GemmStats:
     silent_degrades: int = 0   # auto executions with NO recorded reason
     #                            (structurally 0: every ExecPlan fallback
     #                            carries a reason; kept as the cross-check)
+    # routed GEMMs handed one layer of a stacked weight (models.matmul.
+    # LayerWeight): read where it lies by the kernel, or sliced out first
+    # (blocks that do not divide it, a cast, a mesh that reshards it, or
+    # XLA's dot, which is handed the slice)
+    in_place: int = 0
+    sliced: int = 0
 
     def record(self, tag: str, shape, count: int = 1) -> None:
         """`count` > 1 logs one traced call that stands for `count` GEMMs of
@@ -140,6 +146,8 @@ class GemmStats:
             "modes": dict(sorted(self.modes.items())),
             "degrades": dict(sorted(self.degrades.items())),
             "silent_degrades": self.silent_degrades,
+            "in_place": self.in_place,
+            "sliced": self.sliced,
             "observed": [
                 {"tag": tag,
                  "shape": ([int(s.m), int(s.n), int(s.k)]
@@ -166,7 +174,9 @@ class GemmStats:
                     unroutable=int(d.get("unroutable", 0)),
                     modes=dict(d.get("modes", {})),
                     degrades=dict(d.get("degrades", {})),
-                    silent_degrades=int(d.get("silent_degrades", 0)))
+                    silent_degrades=int(d.get("silent_degrades", 0)),
+                    in_place=int(d.get("in_place", 0)),
+                    sliced=int(d.get("sliced", 0)))
         for rec in d.get("observed", []):
             key = (rec["tag"], GEMMShape(*rec["shape"]))
             stats.observed[key] = int(rec["count"])

@@ -53,6 +53,9 @@ def describe_routing(d: Dict[str, Any]) -> str:
     if d.get("degrades") or d.get("silent_degrades"):
         out += (f" degrades={dict(sorted(d['degrades'].items()))} "
                 f"silent={d['silent_degrades']}")
+    if d.get("in_place") or d.get("sliced"):
+        out += (f" layer-weights in-place={d['in_place']} "
+                f"sliced={d['sliced']}")
     return out
 
 
@@ -124,6 +127,10 @@ def render_run_report(report: Dict[str, Any]) -> List[str]:
             lines.append(f"routing degrades (by reason): "
                          f"{dict(sorted(routing['degrades'].items()))} "
                          f"silent-auto={routing['silent_degrades']}")
+        if routing.get("in_place") or routing.get("sliced"):
+            lines.append(f"stacked layer weights: read in place "
+                         f"{routing['in_place']}, sliced out "
+                         f"{routing['sliced']}")
     workload = report.get("workload")
     if workload is not None:
         lines.append(

@@ -164,7 +164,8 @@ def _engine_time(op: MMADOp, hw: AcceleratorConfig,
            * math.ceil(min(inner.bn, op.tn) / t.ce_cols))
     kchunks = math.ceil(op.tk / inner.bk)
     fills = fill if inner.depth >= 2 else kchunks * fill
-    cycles = blocks * sub * (kchunks * inner.bk + fills)
+    # a bk over the K-chunk spans several chunks: each chunk still costs tk
+    cycles = blocks * sub * (kchunks * min(inner.bk, op.tk) + fills)
     engine = cycles / t.clock_hz
     eb = ELEM_BYTES_OF_DTYPE.get(inner.dtype, t.elem_bytes)
     feed = (op.tm * op.tk + op.tk * op.tn) * eb / t.l1_bw

@@ -151,3 +151,30 @@ def kv_copies(hlo_text: str, layer: tuple, n_layers: int,
             continue
         bad.append((opcode, dims, op_name))
     return bad
+
+
+
+# XLA's asynchronous moves of a buffer to another memory space (VMEM) and
+# back: no relayout; a stack moved before the layer loop is still read once
+_MOVES = ("copy-start", "copy-done", "slice-start", "slice-done")
+
+
+def weight_copies(hlo_text: str, shapes, moves: bool = False):
+    """The instructions of a compiled serve step that hold a buffer of a
+    layer GEMM weight's or a weight stack's shape (`shapes`, dims in
+    order; unit dims aside), as (opcode, dims, op_name): copies, slices or
+    relayouts of a weight, in any memory space. The parameters themselves,
+    and ops that only pass a buffer on or view it (a bitcast), hold none.
+    With `moves`, XLA's asynchronous moves of a buffer between memory
+    spaces (`_MOVES`, and the `ConcatBitcast` that joins the pieces of a
+    sliced move) are let through."""
+    def key(d):
+        return [x for x in d if x != 1]
+    want = [key(s) for s in shapes]
+    joins = set(re.findall(r'%([\w.\-]+) = [^\n]*custom_call_target='
+                           r'"ConcatBitcast"', hlo_text)) if moves else set()
+    return [(opcode, dims, op_name)
+            for name, opcode, dims, _, op_name in _buffers(hlo_text)
+            if opcode not in _NO_BUFFER
+            and not (moves and (opcode in _MOVES or name in joins))
+            and any(key(d) in want for d in dims)]

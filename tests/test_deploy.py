@@ -348,3 +348,21 @@ def test_planner_end_to_end_batch_then_rerequest():
     assert planner.cache.stats.hits == hits_before + len(set(workload))
     for s in workload:
         assert second[s] is first[s]
+
+
+def test_adapt_fits_inner_kernel_blocks_to_the_new_shape():
+    """A transferred kernel's bn / bk that do not divide the new tile are
+    cut to the largest LANE multiple that does (a padded B is a copy of
+    it); edges that divide stay, and so does one no LANE multiple fits."""
+    from repro.core.schedule import InnerKernel
+    from repro.hw.config import tpu_pod_as_accelerator
+    src = Schedule(GEMMShape(128, 2048, 2048), Tiling(1, 1, 1, tk=256),
+                   "summa", elem_bytes=2,
+                   inner_kernel=InnerKernel(128, 1024, 1024, dtype="bfloat16"))
+    hw = tpu_pod_as_accelerator((1, 1))
+    assert adapt(src, GEMMShape(128, 1536, 5120), hw).inner_kernel \
+        .geometry() == (128, 768, 1024)
+    assert adapt(src, GEMMShape(128, 24576, 1536), hw).inner_kernel \
+        .geometry() == (128, 1024, 768)
+    assert adapt(src, GEMMShape(128, 160, 2048), hw).inner_kernel \
+        .geometry() == (128, 1024, 1024)

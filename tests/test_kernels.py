@@ -75,6 +75,33 @@ def test_mmad_rejects_ragged():
         mmad(a, b, block_shape=(128, 128, 128), interpret=True)
 
 
+@pytest.mark.parametrize("block", [(8, 128, 128), (16, 256, 128)],
+                         ids=lambda b: "x".join(map(str, b)))
+@pytest.mark.parametrize("layer", [0, 2])
+def test_mmad_reads_a_layer_of_a_stack(layer, block):
+    """B given as a stack (L, K, N) and a layer index reads that layer where
+    it lies: the same numbers as `mmad` on the layer sliced out."""
+    a = jnp.asarray(RNG.standard_normal((16, 256)), jnp.float32)
+    stack = jnp.asarray(RNG.standard_normal((3, 256, 512)), jnp.float32)
+    out = mmad(a, stack, block_shape=block, interpret=True,
+               layer=jnp.int32(layer))
+    np.testing.assert_array_equal(
+        out, mmad(a, stack[layer], block_shape=block, interpret=True))
+
+
+def test_stacked_weight_is_never_padded():
+    """A block that does not divide the layer is refused, not padded: a pad
+    of the stack would copy all of it, so the caller slices instead."""
+    a = jnp.ones((16, 384), jnp.float32)
+    stack = jnp.ones((2, 384, 256), jnp.float32)
+    with pytest.raises(ValueError, match="do not divide"):
+        tile_matmul(a, stack, block_shape=(8, 128, 256), interpret=True,
+                    use_kernel=True, layer=jnp.int32(1))
+    with pytest.raises(ValueError, match="cannot read"):
+        local_matmul(a, stack, InnerKernel(8, 128, 256, dtype="float32"),
+                     layer=jnp.int32(1))
+
+
 # ---------------------------------------------------------------------------
 # local_matmul: the schedule-resolved per-device GEMM
 # ---------------------------------------------------------------------------

@@ -567,3 +567,31 @@ def test_fig6c_tuned_schedule_routes_to_outer_systolic():
     assert proc.returncode == 0, (f"stdout:\n{proc.stdout}\n"
                                   f"stderr:\n{proc.stderr}")
     assert "ALL_OK" in proc.stdout
+
+
+@pytest.mark.parametrize("m, n, k, want", [
+    (16, 3072, 3072, (16, 1024, 1024)),      # phi4-mini's decode q / o
+    (16, 3072, 8192, (16, 1024, 1024)),
+    (128, 24576, 1536, (128, 1024, 768)),    # DeepSeek-V2's q_up, 1536 deep
+    (128, 512, 5120, (128, 512, 1024)),
+    (2048, 3072, 3072, None),                # a prefill chunk: not streamed
+], ids=lambda v: str(v))
+def test_decode_tiles_stream_the_largest_b_block(m, n, k, want):
+    """A tile of at most STREAM_ROWS rows puts first the double-buffered
+    kernel with the largest B block whose edges divide its N and K, and it
+    prices exactly like the planner's K-chunk kernel, so it wins the tie."""
+    from repro.core.schedule import (STREAM_ROWS, build_program,
+                                     inner_kernel_candidates)
+    from repro.hw.config import tpu_pod_as_accelerator
+    from repro.sim.perf import estimate_sweep
+    hw = tpu_pod_as_accelerator((1, 1))
+    s = Schedule(GEMMShape(m, n, k), Tiling(1, 1, 1, tk=256), "summa",
+                 elem_bytes=2)
+    cands = inner_kernel_candidates(s, hw)
+    if want is None:
+        assert m > STREAM_ROWS and cands[0].bk <= 256
+        return
+    assert cands[0].geometry() == want and cands[0].depth == 2
+    prog = build_program(s, hw)
+    (_, first), (_, chunked) = estimate_sweep(prog, hw, cands[:2])
+    assert first.total_time == chunked.total_time

@@ -22,7 +22,8 @@ import pytest
 from repro.configs import get_config, smoke_config
 from repro.models.attention import _mla_scale
 from repro.models.common import activation, rope_tables
-from repro.models.moe import apply_moe, moe_params, route
+from repro.models.matmul import LayerWeight
+from repro.models.moe import _grouped, apply_moe, moe_params, route
 
 V2 = get_config("deepseek-v2-236b")
 SMOKE = dataclasses.replace(smoke_config("deepseek-v2-236b"),
@@ -164,3 +165,20 @@ def test_yarn_at_the_published_numbers():
     np.testing.assert_allclose(np.asarray(cos0),
                                np.cos(np.asarray(pos)[:, None] * base),
                                atol=1e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 3])
+def test_grouped_reads_its_layer_of_the_expert_stack(layer):
+    """A layer's grouped GEMM over the (L·E, K, N) view of the expert stack,
+    with the sizes at the layer's experts and zero at every other layer's,
+    is the grouped GEMM over that layer's experts alone; rows past the last
+    group read zero either way."""
+    rng = np.random.default_rng(layer)
+    n_layers, e, k, n = 4, 5, 16, 24
+    stack = jnp.asarray(rng.standard_normal((n_layers, e, k, n)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((40, k)), jnp.float32)
+    sizes = jnp.asarray([7, 0, 12, 3, 9], jnp.int32)       # 31 of 40 rows
+    got = _grouped(x, LayerWeight(stack, jnp.int32(layer)), sizes)
+    want = jax.lax.ragged_dot(x, stack[layer], sizes)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    assert not np.asarray(got[31:]).any()

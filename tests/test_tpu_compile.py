@@ -10,9 +10,11 @@ Nothing runs: they assert only that the programs compile and, for the
 routed step, that the Pallas kernel is in it (`tpu_custom_call`). One more
 reads each benchmark cell's compiled serve step at the cell's sizes for
 the buffers it holds: the KV cache carried through the layer loop and
-written in place, with no copy of a layer or of the stack. The
-DeepSeek-V2 cell's step, at its whole cut, is read the same way for its
-latent cache, and for MoE layers that run their held experts as grouped
+written in place, with no copy of a layer or of the stack, and the layer
+GEMM weights read by their kernels in their stacks, with no copy of a
+layer or of a stack. The DeepSeek-V2 cell's step, at its whole cut, is
+read the same way for its latent cache and its weights, expert stacks
+among them, and for MoE layers that run their held experts as grouped
 GEMMs with no one-hot dispatch tensor.
 
 The topology is described inside a module fixture, never at import: only
@@ -46,7 +48,7 @@ from repro.models import shard_ctx
 from repro.models.model import decode_init, init_params
 from repro.train.steps import make_serve_step
 from serve_scopes import (_buffers, assert_names_every_part,
-                          compile_donated_steps, kv_copies)
+                          compile_donated_steps, kv_copies, weight_copies)
 
 from bench import harness  # on the path serve_scopes sets
 from bench.reduce import routed_gemms
@@ -209,7 +211,7 @@ def cell_steps(request, chip_mesh, one_chip, tmp_path_factory):
         steps = compile_donated_steps(cfg, batch, chunk, max_len,
                                       sharding=one_chip)
     assert ctx.stats.routed and ctx.stats.silent_degrades == 0
-    return cfg, (batch, cfg.n_kv_heads, max_len, cfg.hd), steps
+    return cfg, (batch, cfg.n_kv_heads, max_len, cfg.hd), steps, ctx.stats
 
 
 @pytest.mark.parametrize("kind", ["prefill", "decode"])
@@ -220,7 +222,7 @@ def test_step_carries_the_kv_cache_in_place(cell_steps, kind):
     comes back in its own buffers, the layers stay one scan (routed GEMMs
     (7, 1), as the benchmark logs them), and a decode step's temporaries
     are smaller than one layer's K."""
-    cfg, layer, steps = cell_steps
+    cfg, layer, steps, _ = cell_steps
     compiled = steps[kind]
     text = compiled.as_text()
     assert kv_copies(text, layer, cfg.n_layers) == []
@@ -231,6 +233,45 @@ def test_step_carries_the_kv_cache_in_place(cell_steps, kind):
     assert mem.alias_size_in_bytes >= 2 * cfg.n_layers * layer_bytes
     if kind == "decode":
         assert mem.temp_size_in_bytes < layer_bytes
+
+
+# stacked matrices the layer loop slices for its layers: the router (160
+# columns, under a kernel block) and MLA's w_uk / w_uv, which XLA's own
+# einsums read; every other matrix is a layer GEMM's weight
+SLICED = ("router", "w_uk", "w_uv")
+
+
+def layer_weight_shapes(cfg):
+    """The shapes of every layer GEMM weight and expert stack of `cfg`'s
+    parameters: each stack, and one layer of it."""
+    params = jax.eval_shape(lambda k: init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    out = set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        if leaf.ndim >= 3 and path[-1].key not in SLICED:
+            out |= {leaf.shape, leaf.shape[1:]}
+    return out
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_step_reads_layer_weights_in_place(cell_steps, kind):
+    """A decode step's kernels (one row block each) read their layer of
+    each stack where it lies: no buffer outside the parameters has a layer
+    GEMM weight's or a weight stack's shape, in any memory space. So do
+    phi4-mini's prefill kernels (2,048 rows, 4 row blocks); olmo-1b's
+    (6,144 rows, 12 row blocks, each of which would read all of B) are
+    handed their layer sliced out, which XLA may stage in VMEM, and no
+    stack is copied whole there either. XLA may still move a whole
+    two-layer stack to VMEM before the loop (asynchronous copies, no
+    relayout)."""
+    cfg, _, steps, stats = cell_steps
+    sliced_prefill = cfg.name.startswith("olmo")
+    assert (stats.in_place, stats.sliced) == (
+        (7, 7) if sliced_prefill else (14, 0))
+    shapes = layer_weight_shapes(cfg)
+    if kind == "prefill" and sliced_prefill:
+        shapes = {s for s in shapes if len(s) == 3}
+    assert weight_copies(steps[kind].as_text(), shapes, moves=True) == []
 
 
 MLA_MOE_CELL = "deepseek-v2-236b-ep8.azure-conv-b128"
@@ -254,7 +295,7 @@ def mla_moe_steps(chip_mesh, one_chip, tmp_path_factory):
         steps = compile_donated_steps(cfg, batch, chunk, max_len,
                                       sharding=one_chip)
     assert ctx.stats.routed and ctx.stats.silent_degrades == 0
-    return cell, cfg, (batch, max_len), steps
+    return cell, cfg, (batch, max_len), steps, ctx.stats
 
 
 @pytest.mark.parametrize("kind", ["prefill", "decode"])
@@ -266,7 +307,7 @@ def test_mla_moe_step_groups_experts_and_carries_the_latent_cache(
     tensor; the latent cache and rotary key stacks are donated, carried
     through both layer loops and written in place, with no copy of a layer
     or a stack; the routed GEMM kernels are those the family expects."""
-    cell, cfg, (batch, max_len), steps = mla_moe_steps
+    cell, cfg, (batch, max_len), steps, _ = mla_moe_steps
     compiled = steps[kind]
     text = compiled.as_text()
     grouped = [line for line in text.splitlines()
@@ -297,3 +338,28 @@ def test_mla_moe_step_groups_experts_and_carries_the_latent_cache(
     dims = cell.family.dims(cell.config)
     assert routed_gemms(text) == cell.family.routed_expected(dims, kind)
     assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_mla_moe_step_reads_layer_weights_in_place(mla_moe_steps, kind):
+    """No buffer outside the parameters has the shape of a layer GEMM
+    weight, an expert stack or a held layer's experts: the MLA, FFN and
+    shared-expert kernels read their layer of the stack in place (16 a
+    step kind: one row block at decode, 4 in a 2,048-row prefill chunk,
+    none sliced) and the grouped expert GEMMs their layer's experts in the
+    (layers x experts) view of the stack. Let through: XLA's asynchronous
+    moves of a whole stack to VMEM, and one relayout per step, before the
+    loops, of the 64-column `mla.k_rope` stacks, which the TPU stores
+    column-major. A prefill chunk's 12,288 expert rows (2,048 tokens x 6)
+    at width 5,120 have the dense layer's down-projection's shape, which
+    the decode step's check covers."""
+    cell, cfg, (batch, max_len), steps, stats = mla_moe_steps
+    assert (stats.in_place, stats.sliced) == (32, 0)
+    shapes = layer_weight_shapes(cfg)
+    if kind == "prefill":
+        rows = batch * cell.traffic["chunk"] * cfg.moe_top_k
+        shapes -= {(rows, cfg.d_model), (1, rows, cfg.d_model)}
+    left = weight_copies(steps[kind].as_text(), shapes, moves=True)
+    assert all(opcode == "copy" and dims[0][-1] < 128
+               and "/while/" not in op_name
+               for opcode, dims, op_name in left), left

@@ -41,14 +41,15 @@ from typing import List
 def _bench() -> dict:
     from repro.configs import smoke_config
     from repro.core.lower import lower_schedule, lowering_summary
-    from repro.deploy import Planner, model_workload
+    from repro.deploy import Planner
     from repro.hw.config import tpu_pod_as_accelerator
     from repro.launch.mesh import make_mesh
+    from repro.models.workload import gemm_workload
 
     cfg = smoke_config("gemma-2b")
     hw = tpu_pod_as_accelerator((4, 4))
     planner = Planner(hw, max_candidates=8)
-    workload = model_workload(cfg, batch=2, seq=16, kind="prefill")
+    workload = gemm_workload(cfg, batch=2, seq=16, kind="prefill")
 
     t0 = time.perf_counter()
     planner.batch_tune(workload)

@@ -27,9 +27,10 @@ single flag CI re-asserts):
 
   PYTHONPATH=src python benchmarks/serving_bench.py
 
-Pure virtual-clock + cost-model arithmetic — no jax, no devices, fully
-deterministic. docs/serving.md describes the traffic model; the artifact
-schema is in docs/benchmarking.md.
+Virtual-clock + cost-model arithmetic over GEMM workloads traced
+abstractly from the model (no devices), fully deterministic.
+docs/serving.md describes the traffic model; the artifact schema is in
+docs/benchmarking.md.
 """
 import argparse
 import json

@@ -74,17 +74,18 @@ def _bench_dispatch(reps: int) -> dict:
     import numpy as np
 
     from repro.configs import smoke_config
-    from repro.deploy import Planner, model_workload
+    from repro.deploy import Planner
     from repro.hw.config import tpu_pod_as_accelerator
     from repro.launch.mesh import make_mesh
     from repro.models import shard_ctx
     from repro.models.matmul import pmm
+    from repro.models.workload import gemm_workload
     from repro.obs import Tracer, set_tracer
 
     cfg = smoke_config("gemma-2b")
     hw = tpu_pod_as_accelerator((4, 4))
     planner = Planner(hw, max_candidates=8)
-    workload = model_workload(cfg, batch=2, seq=16, kind="prefill")
+    workload = gemm_workload(cfg, batch=2, seq=16, kind="prefill")
     planner.batch_tune(workload)
 
     mesh = make_mesh((4, 4), ("data", "model"))

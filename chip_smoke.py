@@ -50,7 +50,6 @@ from repro.core.gemm import dit_gemm
 from repro.core.lower import lower_schedule
 from repro.core.schedule import (GEMMShape, Schedule, Tiling,
                                  inner_kernel_candidates)
-from repro.deploy import model_workload
 from repro.deploy.warmup import build_planner, warm_buckets
 from repro.hw.config import tpu_pod_as_accelerator
 from repro.kernels.ops import local_matmul
@@ -59,6 +58,7 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import host_mesh_grid, make_host_mesh, make_mesh
 from repro.models import shard_ctx
 from repro.models.model import decode_init, init_params
+from repro.models.workload import gemm_workload
 from repro.obs import Tracer, render_run_report, set_tracer
 from repro.train.steps import make_serve_step
 
@@ -148,8 +148,8 @@ def fresh_plan_cache() -> str:
 def phase_local_matmul(cfg, planner, mesh, seed: int) -> None:
     """(b) The planner's inner kernel on every decode/prefill GEMM shape."""
     shapes = dict.fromkeys(
-        model_workload(cfg, BATCH, DECODE_MAX_LEN, kind="decode")
-        + model_workload(cfg, BATCH, PREFILL_LEN, kind="prefill"))
+        gemm_workload(cfg, BATCH, DECODE_MAX_LEN, kind="decode")
+        + gemm_workload(cfg, BATCH, PREFILL_LEN, kind="prefill"))
     key = jax.random.PRNGKey(seed)
     for i, shape in enumerate(shapes):
         ep = lower_schedule(planner.plan(shape).schedule, mesh, shape=shape)
@@ -300,9 +300,9 @@ def one_chip(cfg, seed: int) -> None:
     mesh, grid = make_host_mesh(), host_mesh_grid()
     plan_cache = fresh_plan_cache()
     planner = build_planner(plan_cache, grid, max_candidates=12)
-    warm_buckets(planner, model_workload(cfg, BATCH, DECODE_MAX_LEN,
-                                         kind="decode")
-                 + model_workload(cfg, BATCH, PREFILL_LEN, kind="prefill"))
+    warm_buckets(planner, gemm_workload(cfg, BATCH, DECODE_MAX_LEN,
+                                        kind="decode")
+                 + gemm_workload(cfg, BATCH, PREFILL_LEN, kind="prefill"))
     phase_local_matmul(cfg, planner, mesh, seed)
     params = olmo_params(cfg, mesh, seed)
     served = phase_serve(cfg, params, mesh, grid, plan_cache, seed)

@@ -2,9 +2,10 @@
 batch planner, turning one-shot autotuning into a reusable serving pipeline.
 
     from repro.deploy import Planner, PlanCache
+    from repro.models.workload import gemm_workload
 
     planner = Planner(hw, cache=PlanCache("results/plan_cache"))
-    planner.batch_tune(model_workload(cfg, batch=8, seq=4096))   # cold, once
+    planner.batch_tune(gemm_workload(cfg, batch=8, seq=4096))    # cold, once
     plan = planner.plan(shape)                                   # warm: O(1)
 """
 from repro.deploy.batcher import (BATCH_MODES, Batch, BatchPolicy,
@@ -18,16 +19,14 @@ from repro.deploy.plan import (DeploymentPlan, PLAN_SCHEMA_VERSION,
                                SOURCE_BUCKETED, SOURCE_TUNED, hw_fingerprint,
                                plan_from_tuning, schedule_from_dict,
                                schedule_to_dict, search_variant)
-from repro.deploy.planner import (Planner, arch_workload, model_workload,
-                                  moe_dispatch_geometry, workload_coverage)
+from repro.deploy.planner import Planner
 
 __all__ = [
     "BATCH_MODES", "Batch", "BatchPolicy", "BucketingPolicy", "CacheStats",
     "ContinuousBatcher", "DeploymentPlan", "PLAN_SCHEMA_VERSION",
     "PlanCache", "Planner", "Request", "SOURCE_BUCKETED", "SOURCE_TUNED",
-    "adapt", "arch_workload", "bucket_of", "bucket_pool", "decode_m",
-    "distance", "hw_fingerprint", "model_workload", "moe_dispatch_geometry",
-    "nearest_tuned", "next_pow2", "plan_from_tuning", "plan_key",
-    "schedule_from_dict", "schedule_to_dict", "search_variant",
-    "transfer_candidates", "workload_coverage",
+    "adapt", "bucket_of", "bucket_pool", "decode_m", "distance",
+    "hw_fingerprint", "nearest_tuned", "next_pow2", "plan_from_tuning",
+    "plan_key", "schedule_from_dict", "schedule_to_dict", "search_variant",
+    "transfer_candidates",
 ]

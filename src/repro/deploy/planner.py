@@ -19,9 +19,9 @@ I run this GEMM" question a serving stack asks:
   hook: re-tune bucket- and analytic-served shapes for real and upgrade
   their cache entries when the fresh schedule is no worse.
 
-`model_workload` extracts the deduplicated GEMM shapes of one model
-config's forward pass (projections, FFN, MoE experts, LM head) so a server
-can warm its planner from the architectures it will host.
+The shapes a server warms come from the model's own record-only trace of
+its steps (`repro.models.workload.gemm_workload`); the planner knows
+nothing of any model's layers.
 """
 from __future__ import annotations
 
@@ -398,186 +398,3 @@ class Planner:
         fresh = self._tune_shape(shape)
         return plan.report.total_time / fresh.report.total_time
 
-
-# ---------------------------------------------------------------------------
-# Workload extraction
-# ---------------------------------------------------------------------------
-
-def moe_dispatch_geometry(tokens: int, cfg, dp: int = 1) -> Tuple[int, int]:
-    """(dispatch groups, per-group expert capacity) for `tokens` tokens on
-    the expert-sharded mesh path (`moe._capacity_dispatch`).
-
-    Pure-int mirror of `repro.models.moe._dp_groups` / `_capacity` (the
-    deploy layer must stay importable without jax, so the logic is duplicated
-    here; tests/test_plan_routing.py pins the two in sync by comparing this
-    prediction against the shapes the mesh path records). `dp` is the
-    data-parallel shard count the dispatch groups align to.
-    """
-    group_tokens = 512                      # moe._GROUP_TOKENS
-    if tokens % dp:
-        dp = 1
-    g = dp
-    while tokens % (g * 2) == 0 and tokens // (g * 2) >= group_tokens:
-        g *= 2
-    tl = tokens // g
-    cap = max(int(tl * cfg.moe_top_k * cfg.capacity_factor / cfg.n_experts), 4)
-    return g, cap
-
-
-def model_workload(cfg, batch: int, seq: int,
-                   kind: str = "prefill",
-                   dp: Optional[int] = None) -> List[GEMMShape]:
-    """Deduplicated projection GEMMs of one forward pass of `cfg`.
-
-    `cfg` is a `repro.models.common.ModelConfig` (duck-typed so the deploy
-    layer stays importable without jax). Token dimension M is batch*seq for
-    train/prefill and batch for decode; weights supply (K, N). `dp` is the
-    data-parallel shard count of the activation mesh the model will trace
-    under, None when none is installed. It picks the MoE layer's expert
-    GEMMs: with no activation mesh the held experts' grouped GEMMs over
-    the static budget of tokens*k rows; under one, the capacity-bounded
-    dispatch buffers, whose groups align to the DP axes.
-
-    These are the shapes the model stack actually traces through
-    `models.matmul.pmm` — every entry is checked against the recorded
-    (tag, GEMMShape) pairs of a real forward pass in
-    tests/test_plan_routing.py, so launcher warm-ups tune exactly the GEMMs
-    that will be dispatched — including the encoder-decoder stacks
-    (encoder self-attention blocks over the frame prefix, and the decoder
-    cross-attention K/V projections that re-run over the encoder output on
-    every decode step).
-    """
-    tokens = batch * seq if kind in ("train", "prefill") else batch
-    tokens = max(1, tokens)
-    d, hd = cfg.d_model, cfg.hd
-    pattern = getattr(cfg, "block_pattern", "attn")
-    shapes: List[GEMMShape] = []
-
-    def gemm(m, n, k):
-        if m > 0 and n > 0 and k > 0:
-            shapes.append(GEMMShape(m, n, k))
-
-    # modality frontend stub: the learned (d x d) projection applied to the
-    # precomputed patch/frame embeddings (models.model.forward tags it
-    # frontend.proj). Decode steps never re-run the frontend. For the VLM
-    # frontends the projected prefix is prepended to the token sequence, so
-    # every downstream block GEMM runs at batch*(n_prefix + seq) rows.
-    front = getattr(cfg, "frontend", "none")
-    n_prefix = getattr(cfg, "n_prefix", 0)
-    if front in ("vision_stub", "audio_stub") and n_prefix \
-            and kind in ("train", "prefill"):
-        gemm(batch * n_prefix, d, d)                    # frontend.proj
-        if not getattr(cfg, "is_encoder_decoder", False):
-            tokens += batch * n_prefix                  # prefix joins the seq
-
-    # attention projections (xlstm stacks have no attention blocks)
-    if pattern == "xlstm":
-        d_inner = 2 * d
-        gemm(tokens, 2 * d_inner, d)                    # mLSTM up
-        gemm(tokens, d_inner, d_inner)                  # q / k / v (identical)
-        gemm(tokens, 2 * cfg.n_heads, d)                # i/f gate pre-acts
-        gemm(tokens, d, d_inner)                        # mLSTM down
-        gemm(tokens, 4 * d, d)                          # sLSTM in
-        gemm(tokens, d, d)                              # sLSTM out
-    elif getattr(cfg, "attn", "gqa") == "mla":
-        if cfg.q_lora_rank:
-            gemm(tokens, cfg.q_lora_rank, d)
-        qdim = cfg.n_heads * (cfg.nope_head_dim + cfg.rope_head_dim)
-        gemm(tokens, qdim, cfg.q_lora_rank or d)
-        # the model runs the KV down-projection and the shared rotary key as
-        # two separate matmuls (attention.mla_attention), not one fused GEMM
-        gemm(tokens, cfg.kv_lora_rank, d)
-        gemm(tokens, cfg.rope_head_dim, d)
-        if kind == "decode":
-            # absorbed form: W_uk folds into the query and W_uv un-absorbs
-            # the latent output — n_heads per-head (r x dn) contractions
-            # each, no K/V up-projection ever runs. The shape list is a
-            # set (coverage is membership-based); the per-head multiplicity
-            # lives in the observed counts: attention.mla_attention records
-            # these two with count=n_heads per call.
-            gemm(tokens, cfg.kv_lora_rank, cfg.nope_head_dim)
-            gemm(tokens, cfg.nope_head_dim, cfg.kv_lora_rank)
-        else:
-            # naive form: up-project K and V (identical shapes) from c_kv
-            gemm(tokens, cfg.n_heads * cfg.nope_head_dim, cfg.kv_lora_rank)
-        gemm(tokens, d, cfg.n_heads * cfg.nope_head_dim)
-    else:
-        gemm(tokens, cfg.n_heads * hd, d)               # Q
-        gemm(tokens, cfg.n_kv_heads * hd, d)            # K and V (identical)
-        gemm(tokens, d, cfg.n_heads * hd)               # O
-    # encoder-decoder stacks (seamless): the encoder runs full
-    # self-attention blocks over the frame prefix, and every decoder layer
-    # adds cross-attention whose Q/O run at decoder rows (identical to the
-    # self-attention shapes above) while K/V project the ENCODER output —
-    # and therefore re-run at encoder rows on every decode step too.
-    if getattr(cfg, "is_encoder_decoder", False):
-        enc_tokens = batch * n_prefix
-        if enc_tokens:
-            if kind in ("train", "prefill"):
-                # encoder self-attention blocks (prefill/train only; decode
-                # consumes the precomputed encoder output)
-                gemm(enc_tokens, cfg.n_heads * hd, d)       # enc Q
-                gemm(enc_tokens, cfg.n_kv_heads * hd, d)    # enc K and V
-                gemm(enc_tokens, d, cfg.n_heads * hd)       # enc O
-                if cfg.d_ff:
-                    gemm(enc_tokens, cfg.d_ff, d)           # enc gate / up
-                    gemm(enc_tokens, d, cfg.d_ff)           # enc down
-            # decoder cross-attention K/V over the encoder output (every
-            # kind — decode recomputes them each step, attention.py has no
-            # cross-attention cache)
-            gemm(enc_tokens, cfg.n_kv_heads * hd, d)
-    # SSM mixer projections of the hybrid stacks (zamba2); the shared
-    # attention block above supplies the attn/FFN shapes
-    if pattern == "mamba2_hybrid":
-        d_inner = 2 * d
-        nh = d_inner // cfg.mamba_headdim
-        gemm(tokens, 2 * d_inner + 2 * cfg.ssm_state + nh, d)   # fused in
-        gemm(tokens, d, d_inner)                                # out
-    # FFN (dense layers) and MoE experts
-    if cfg.d_ff and pattern != "xlstm":
-        gemm(tokens, cfg.d_ff, d)                       # gate / up (identical)
-        gemm(tokens, d, cfg.d_ff)                       # down
-    if cfg.n_experts and cfg.moe_top_k:
-        # expert M: the grouped GEMMs' static row budget (tokens * k), or
-        # under a mesh the capacity-bounded dispatch buffer, at which each
-        # (group, expert) GEMM runs
-        rows = (tokens * cfg.moe_top_k if dp is None
-                else moe_dispatch_geometry(tokens, cfg, dp=dp)[1])
-        gemm(tokens, cfg.n_experts, d)                  # router
-        gemm(rows, cfg.moe_d_ff, d)                     # expert gate / up
-        gemm(rows, d, cfg.moe_d_ff)                     # expert down
-        if getattr(cfg, "n_shared_experts", 0):
-            sh_ff = cfg.moe_d_ff * cfg.n_shared_experts
-            gemm(tokens, sh_ff, d)
-            gemm(tokens, d, sh_ff)
-    # LM head
-    gemm(tokens, cfg.vocab, d)
-    return list(dict.fromkeys(shapes))
-
-
-def workload_coverage(predicted: Sequence[GEMMShape],
-                      observed: Sequence[GEMMShape]) -> Dict[str, object]:
-    """Cross-validate `model_workload` against what the model actually ran.
-
-    `observed` is the deduplicated shape list a `GemmContext` recorded
-    (`stats.observed_shapes()`). Returns the predicted shapes that never
-    executed (`missing` — warm-up tuned something useless), the executed
-    shapes the prediction did not cover (`extra` — warm-up skipped real
-    traffic), and the covered fraction of the observed workload.
-    """
-    pred, obs = set(predicted), set(observed)
-    covered = len(obs & pred) / len(obs) if obs else 1.0
-    return {
-        "missing": sorted(pred - obs, key=lambda s: (s.m, s.n, s.k)),
-        "extra": sorted(obs - pred, key=lambda s: (s.m, s.n, s.k)),
-        "covered": covered,
-    }
-
-
-def arch_workload(cfg, shape_name: str) -> List[GEMMShape]:
-    """`model_workload` with (batch, seq, kind) pulled from the registry's
-    shape suite (the same cells the dry-run sweep enumerates)."""
-    from repro.configs.registry import SHAPES
-    spec = SHAPES[shape_name]
-    return model_workload(cfg, batch=spec["global_batch"],
-                          seq=spec["seq_len"], kind=spec["kind"])

@@ -20,12 +20,13 @@ Usage:
   python -m repro.launch.dryrun --arch qwen3-14b --shape train_4k \
       [--multi-pod] [--skip-accounting] --out results/dryrun
 
-With --plan-cache the cell also cross-validates the deployment-plan
-workload (record-only). Adding --route compiles the cell with plan routing
-ON — every model matmul dispatches through its tuned dataflow's shard_map
-collectives on the 16x16 production mesh and the JSON reports per-reason
-lowering fallbacks (the ROADMAP routed-compile proof; pair with
---skip-accounting to keep the measurement to the one routed compile).
+With --plan-cache the cell also records the GEMMs it traces
+(record-only) and reports how many the plan cache resolves. Adding --route
+compiles the cell with plan routing ON — every model matmul dispatches
+through its tuned dataflow's shard_map collectives on the 16x16
+production mesh and the JSON reports per-reason lowering fallbacks (the
+ROADMAP routed-compile proof; pair with --skip-accounting to keep the
+measurement to the one routed compile).
 --route-dataflows restricts the warm-up's candidate search, e.g.
 `--route-dataflows systolic_over_summa` proves the Fig. 6c outer-systolic
 mode executes on the production mesh (see docs/dataflows.md).
@@ -39,7 +40,7 @@ docs/plan-lifecycle.md "Calibration".
 With --plan-cache the cell additionally installs the structured dispatch
 tracer (repro.obs) and writes, next to the cell JSON: <tag>.run_report.json
 (the versioned machine-readable report CI asserts on — routing counters,
-per-dispatch plan provenance, workload coverage, calibration fit,
+per-dispatch plan provenance, workload plan-resolve rate, calibration fit,
 predicted-vs-measured drift) and <tag>.trace.json (Chrome trace-event
 spans, loadable at ui.perfetto.dev). See docs/observability.md.
 """
@@ -335,22 +336,23 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         calibration_out = calibrate_plan_cache(plan_cache, plan_grid)
     if plan_cache:
         # Default: record-only gemm context — every pmm the cell traces is
-        # logged so the JSON can cross-validate model_workload (and the
-        # warmed plan cache) against the GEMMs this (arch x shape x mesh)
-        # really runs, while the compile measures the untouched production
-        # program. --route flips the context live: the cell's workload is
-        # warmed into the planner and every model matmul compiles through
-        # its tuned dataflow's shard_map collectives on the production mesh
-        # (the ROADMAP "16x16 routed compile proof"), with per-reason
-        # fallback counts in the JSON — no silent auto degrades.
+        # logged so the JSON can check the warmed plan cache against the
+        # GEMMs this (arch x shape x mesh) really runs, while the compile
+        # measures the untouched production program. --route flips the
+        # context live: the cell's workload is warmed into the planner and
+        # every model matmul compiles through its tuned dataflow's
+        # shard_map collectives on the production mesh (the ROADMAP "16x16
+        # routed compile proof"), with per-reason fallback counts in the
+        # JSON — no silent auto degrades.
         from repro.deploy.warmup import build_planner, warm_buckets
         planner = build_planner(plan_cache, plan_grid, max_candidates=12,
                                 dataflows=route_dataflows)
         if route or calibration_out is not None:
-            from repro.deploy import model_workload
-            specs0 = input_specs(cfg, shape_name)
-            workload = model_workload(cfg, specs0["batch"], specs0["seq"],
-                                      kind=specs0["kind"], dp=_dp_size(mesh))
+            from repro.models.workload import gemm_workload
+            spec = SHAPES[shape_name]
+            workload = gemm_workload(cfg, spec["global_batch"],
+                                     spec["seq_len"], kind=spec["kind"],
+                                     mesh=mesh)
         if calibration_out is not None:
             # how many of this cell's tuning decisions the measured scale
             # factors actually changed (fresh searches both sides)
@@ -400,22 +402,12 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     del compiled, lowered
 
     if gemm_ctx is not None:
-        from repro.deploy import model_workload, workload_coverage
         observed = gemm_ctx.stats.observed_shapes()
-        # dp matters: moe dispatch groups align to the mesh's DP axes, so
-        # the predicted expert capacity must use this cell's mesh geometry
-        predicted = model_workload(cfg, specs["batch"], specs["seq"],
-                                   kind=specs["kind"], dp=_dp_size(mesh))
-        cov = workload_coverage(predicted, observed)
         planner = gemm_ctx.planner
         resolved = sum(1 for s in observed
                        if planner.plan_cached(s) is not None)
         out["workload"] = {
             "observed": len(observed),
-            "predicted": len(predicted),
-            "covered": cov["covered"],
-            "extra": [[s.m, s.n, s.k] for s in cov["extra"]],
-            "missing": [[s.m, s.n, s.k] for s in cov["missing"]],
             "plan_resolved": resolved,
             "plan_resolve_rate": resolved / len(observed) if observed else 0.0,
         }
@@ -506,7 +498,7 @@ def main():
     ap.add_argument("--skip-accounting", action="store_true")
     ap.add_argument("--plan-cache", default="",
                     help="warmed plan-cache dir; enables the record-only "
-                         "gemm context + workload coverage report")
+                         "gemm context + workload plan-resolve report")
     ap.add_argument("--plan-grid", type=int, nargs=2, default=(4, 4),
                     metavar=("R", "C"),
                     help="pod grid the cache was warmed for (fingerprint)")

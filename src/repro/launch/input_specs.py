@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.configs.registry import SHAPES
 from repro.models.common import ModelConfig
 from repro.models.model import decode_init, init_params
+from repro.models.workload import frontend_inputs
 
 
 def sds(shape, dtype) -> jax.ShapeDtypeStruct:
@@ -39,32 +40,17 @@ def input_specs(cfg: ModelConfig, shape_name: str) -> Dict[str, Any]:
     out: Dict[str, Any] = {"kind": kind, "batch": b, "seq": s}
 
     if kind == "train":
-        batch = {
-            "tokens": sds((b, s), jnp.int32),
-            "targets": sds((b, s), jnp.int32),
-        }
-        if cfg.frontend == "vision_stub":
-            batch["prefix_embeds"] = sds((b, cfg.n_prefix, cfg.d_model),
-                                         jnp.bfloat16)
-        if cfg.is_encoder_decoder:
-            batch["encoder_embeds"] = sds((b, cfg.n_prefix, cfg.d_model),
-                                          jnp.bfloat16)
-        out["inputs"] = batch
+        out["inputs"] = {"tokens": sds((b, s), jnp.int32),
+                         "targets": sds((b, s), jnp.int32),
+                         **frontend_inputs(cfg, b, kind)}
     elif kind == "prefill":
         out["tokens"] = sds((b, s), jnp.int32)
-        if cfg.frontend == "vision_stub":
-            out["prefix_embeds"] = sds((b, cfg.n_prefix, cfg.d_model),
-                                       jnp.bfloat16)
-        if cfg.is_encoder_decoder:
-            out["encoder_embeds"] = sds((b, cfg.n_prefix, cfg.d_model),
-                                        jnp.bfloat16)
+        out.update(frontend_inputs(cfg, b, kind))
     elif kind == "decode":
         out["tokens"] = sds((b, 1), jnp.int32)
         out["position"] = sds((), jnp.int32)
         out["caches"] = cache_shapes(cfg, b, s)
-        if cfg.is_encoder_decoder:
-            out["encoder_out"] = sds((b, cfg.n_prefix, cfg.d_model),
-                                     jnp.bfloat16)
+        out.update(frontend_inputs(cfg, b, kind))
     else:
         raise ValueError(kind)
     return out

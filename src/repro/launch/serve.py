@@ -6,8 +6,7 @@ of re-tuning. The warmed planner is then installed as the model stack's
 `GemmContext`, so every `pmm` matmul dispatches through
 `dit_gemm(plan=...)` — the tuned dataflow, not a hardcoded mode, decides
 each GEMM's collective pattern. At shutdown the launcher reports the
-planner hit rate over the matmuls the model actually traced and
-cross-validates `model_workload`'s prediction against them
+planner hit rate over the matmuls the model actually traced
 (docs/architecture.md walks the full path).
 
   PYTHONPATH=src python -m repro.launch.serve --arch gemma-2b --smoke \
@@ -23,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, smoke_config
-from repro.deploy import Planner, model_workload, workload_coverage
+from repro.deploy import Planner
 from repro.deploy.batcher import BATCH_MODES, BatchPolicy
 from repro.deploy.warmup import (add_plan_args, build_planner,
                                  resolve_plan_grid, warm_buckets)
@@ -32,6 +31,7 @@ from repro.launch.mesh import host_mesh_grid, make_host_mesh
 from repro.models import shard_ctx
 from repro.models.matmul import pmm
 from repro.models.model import decode_init, init_params
+from repro.models.workload import gemm_workload
 from repro.obs import (DriftMonitor, Tracer, build_run_report,
                        render_run_report, set_tracer, write_run_report)
 from repro.obs.trace import CAT_STEP, maybe_span
@@ -46,11 +46,13 @@ def warm_plan_cache(cfg, batch: int, prompt_len: int, max_len: int,
     Warms BOTH the batched-prefill shapes (M = batch*prompt_len; a real
     deployment prefills in one pass, and the persisted cache is its
     artifact) and the decode shapes (M = batch) this launcher's
-    token-by-token loop actually executes."""
+    token-by-token loop actually executes, each named by a record-only
+    trace of that step (`models.workload.gemm_workload`)."""
     planner = build_planner(cache_dir, grid, max_candidates,
                             online_tune=online_tune)
-    decode = model_workload(cfg, batch, max_len, kind="decode")
-    workload = model_workload(cfg, batch, prompt_len, kind="prefill") + decode
+    prefill = gemm_workload(cfg, batch, prompt_len, kind="prefill")
+    decode = gemm_workload(cfg, batch, max_len, kind="decode")
+    workload = prefill + decode
     warm_buckets(planner, workload)
     plans = {shape: planner.plan(shape)          # exact shapes: warm hits or
              for shape in dict.fromkeys(workload)}   # cheap transfers
@@ -93,37 +95,13 @@ def load_drift(plan_cache: str, plan_grid) -> dict:
 def build_serve_report(ctx: shard_ctx.GemmContext, cfg, batch: int,
                        max_len: int, plan_cache: str = "",
                        plan_grid=(4, 4), tracer=None) -> dict:
-    """The versioned run report: routing stats + model_workload
-    cross-validation + calibration drift + per-dispatch provenance.
-
-    The coverage prediction is the decode workload only: this launcher
-    prefills token-by-token through the cache, so every executed step is a
-    decode-shaped trace (M = batch). The batched-prefill shapes warmed at
-    startup are a cache artifact for real deployments, not something this
-    loop runs — comparing against them would report phantom gaps."""
-    stats = ctx.stats
-    predicted = model_workload(cfg, batch, max_len, kind="decode")
-    cov = workload_coverage(predicted, stats.observed_shapes())
-    workload = {
-        "observed": len(stats.observed_shapes()),
-        "predicted": len(predicted),
-        "covered": cov["covered"],
-        "extra": [[s.m, s.n, s.k] for s in cov["extra"]],
-        "missing": [[s.m, s.n, s.k] for s in cov["missing"]],
-    }
+    """The versioned run report: routing stats + calibration drift +
+    per-dispatch provenance."""
     drift = load_drift(plan_cache, plan_grid) if plan_cache else None
-    return build_run_report("serve", stats=stats.to_dict(),
-                            workload=workload, drift=drift, tracer=tracer,
+    return build_run_report("serve", stats=ctx.stats.to_dict(),
+                            drift=drift, tracer=tracer,
                             extra={"arch": cfg.name, "batch": batch,
                                    "max_len": max_len})
-
-
-def report_routing(ctx: shard_ctx.GemmContext, cfg, batch: int,
-                   max_len: int) -> None:
-    """Shutdown print, rendered from the same dict the run report writes."""
-    for line in render_run_report(build_serve_report(ctx, cfg, batch,
-                                                     max_len)):
-        print(line)
 
 
 def generate(serve, params, caches, prompts, gen: int,

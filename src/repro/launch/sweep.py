@@ -18,19 +18,21 @@ import subprocess
 import sys
 import time
 
-from repro.configs import cells, get_config, list_archs
+from repro.configs import SHAPES, cells, get_config, list_archs
 
 
 def warm_plans(archs, cache_dir: str, grid, max_candidates: int) -> None:
     """Batch-tune the bucketed union of all archs' GEMM shapes."""
-    from repro.deploy import arch_workload
     from repro.deploy.warmup import build_planner, warm_buckets
+    from repro.models.workload import gemm_workload
 
     workload = []
     for arch in archs:
         cfg = get_config(arch)
         for shape_name in cells(arch):
-            workload += arch_workload(cfg, shape_name)
+            spec = SHAPES[shape_name]
+            workload += gemm_workload(cfg, spec["global_batch"],
+                                      spec["seq_len"], kind=spec["kind"])
     warm_buckets(build_planner(cache_dir, grid, max_candidates), workload)
 
 
@@ -53,7 +55,7 @@ def run_one(out: str, arch: str, shape: str, mp: bool, timeout: int,
            "--arch", arch, "--shape", shape, "--out", out]
     if plan_cache:
         # cells record their traced GEMM workload against the warmed cache
-        # and report model_workload coverage in their JSON
+        # and report how much of it the cache resolves in their JSON
         cmd += ["--plan-cache", plan_cache,
                 "--plan-grid", str(plan_grid[0]), str(plan_grid[1])]
     if mp:
@@ -102,7 +104,8 @@ def main():
         warm_plans(archs, args.plan_cache, args.plan_grid,
                    args.plan_candidates)
         # cells (subprocesses) get the warmed cache via --plan-cache: each
-        # installs a record-only gemm context and reports workload coverage
+        # installs a record-only gemm context and reports how much of the
+        # GEMM workload it traced the cache resolves
         plan_cache = args.plan_cache
     todo = []
     for arch in archs:

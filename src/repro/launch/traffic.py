@@ -1,19 +1,20 @@
 """Traffic-replay serving harness: seeded request generation + a
-virtual-clock continuous-batching loop over the real planner (jax-free).
+virtual-clock continuous-batching loop over the real planner.
 
 This is where "millions of users" becomes a measured number. A deterministic
 generator emits Poisson arrivals for multiple concurrent tenants (each its
 own model config, all sharing one planner cache and one engine), and the
 simulation loop replays them through the REAL serving dispatch path: every
-admitted batch's GEMM workload (`deploy.planner.model_workload` at the
-batch's M) is resolved through `Planner.plan_cached` — exact hit, bucketed
-transfer, online analytic tune, or fallback — exactly as `models.matmul.pmm`
-would at trace time. Only the *clock* is virtual: per-batch service time is
-the resolved plans' predicted cost plus explicit, configurable charges for
-the things live traffic actually pays when the shape stream fragments
-(per-new-shape compile, online-tune latency, transfer pricing, auto-fallback
-penalty). Everything else — bucketing legality, transfer rejection on
-ragged M, analytic shortlist pricing — is the production code deciding.
+admitted batch's GEMM workload (`batch_workload`: one record-only trace of
+the model step at the batch's M) is resolved through `Planner.plan_cached`
+— exact hit, bucketed transfer, online analytic tune, or fallback — exactly
+as `models.matmul.pmm` would at trace time. Only the *clock* is virtual:
+per-batch service time is the resolved plans' predicted cost plus
+explicit, configurable charges for the things live traffic actually pays
+when the shape stream fragments (per-new-shape compile, online-tune
+latency, transfer pricing, auto-fallback penalty). Everything else —
+bucketing legality, transfer rejection on ragged M, analytic shortlist
+pricing — is the production code deciding.
 
 The admission policy under test is `deploy.batcher.ContinuousBatcher`:
 bucket-aware admission keeps batched Ms on the warmed pow-2 pool; the
@@ -32,13 +33,14 @@ the traffic model, the admission policy, and every serving-section field.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import random
 from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 from repro.deploy.batcher import (Batch, BatchPolicy, ContinuousBatcher,
                                   Request, bucket_pool, decode_m)
-from repro.deploy.planner import model_workload
+from repro.models.workload import gemm_workload
 from repro.obs.metrics import MetricsRegistry
 
 PHASES = ("prefill", "decode")
@@ -237,6 +239,15 @@ def serving_section(result: ServingResult) -> Dict[str, object]:
 # Warm pool
 # ---------------------------------------------------------------------------
 
+@functools.lru_cache(maxsize=4096)
+def batch_workload(cfg, m: int, phase: str) -> Tuple[object, ...]:
+    """The GEMM shapes one admitted batch of `m` token rows runs in
+    `phase`, traced once per (config, M, phase) however often the replay
+    admits that batch (a replay admits at most a few thousand distinct
+    ones: M stays under max_batch x the longest prompt)."""
+    return tuple(gemm_workload(cfg, batch=m, seq=1, kind=phase))
+
+
 def warm_pool(planner, cfgs: Dict[str, object], policy: BatchPolicy,
               max_rows: int) -> List[object]:
     """Batch-tune every GEMM shape the bucket policy can emit for `cfgs`'
@@ -247,8 +258,8 @@ def warm_pool(planner, cfgs: Dict[str, object], policy: BatchPolicy,
     shapes: List[object] = []
     for m in bucket_pool(max_rows, policy):
         for cfg in cfgs.values():
-            shapes += model_workload(cfg, batch=m, seq=1, kind="prefill")
-            shapes += model_workload(cfg, batch=m, seq=1, kind="decode")
+            for phase in PHASES:
+                shapes += batch_workload(cfg, m, phase)
     shapes = list(dict.fromkeys(shapes))
     planner.batch_tune(shapes)
     return shapes
@@ -345,7 +356,7 @@ class _Engine:
 
     def _serve(self, batch: Batch) -> float:
         cfg = self.cfgs[batch.tenant]
-        shapes = model_workload(cfg, batch=batch.m, seq=1, kind=batch.phase)
+        shapes = batch_workload(cfg, batch.m, batch.phase)
         dt = self.costs.step_overhead_s
         for shape in shapes:
             plan = self.planner.plan_cached(shape)
@@ -445,7 +456,7 @@ def simulate(trace: Sequence[Request], planner, cfgs: Dict[str, object],
              dispatch: Optional[Callable] = None) -> ServingResult:
     """Replay `trace` through the continuous batcher against `planner`.
 
-    `cfgs` maps tenant name -> model config (duck-typed, jax-free).
+    `cfgs` maps tenant name -> model config.
     `precompiled` seeds the engine's seen-shape set (the warmed pool — those
     shapes never pay the virtual compile charge). `dispatch(shape, phase)`,
     when given, is invoked once per cold shape — `serve --traffic` uses it

@@ -75,20 +75,12 @@ def main():
     gemm_ctx = None
     tracer = None
     if not args.skip_plan_warmup:
-        from repro.deploy import model_workload
         from repro.deploy.warmup import build_planner, warm_buckets
+        from repro.models.workload import gemm_workload
         planner = build_planner(args.plan_cache, args.plan_grid,
                                 args.plan_candidates)
-        # dp: MoE dispatch groups align to the mesh's DP axes when the
-        # activation-sharding context is installed (production runs)
-        dp = None
-        if shard_ctx.get_mesh() is not None:
-            dp = 1
-            for a in ("pod", "data"):
-                if a in mesh.axis_names:
-                    dp *= mesh.shape[a]
-        workload = model_workload(cfg, args.batch, args.seq, kind="train",
-                                  dp=dp)
+        workload = gemm_workload(cfg, args.batch, args.seq, kind="train",
+                                 mesh=shard_ctx.get_mesh())
         warm_buckets(planner, workload)
         # exact shapes: warm hits or cheap bucketed transfers, never a
         # second full search on top of the bucket tunes above

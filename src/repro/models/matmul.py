@@ -7,8 +7,8 @@ each GEMM executes (the paper's core claim, applied to the model stack).
 - with no `GemmContext` installed it IS `x @ w` (bit-for-bit — smoke tests
   and meshless tracing are unchanged);
 - with a record-only context (mesh=None) it additionally logs the
-  (tag, GEMMShape) pair it would have routed, the ground truth for
-  cross-validating `repro.deploy.planner.model_workload`;
+  (tag, GEMMShape) pair it would have routed: the workload
+  `repro.models.workload.gemm_workload` hands the planner to warm;
 - with a live mesh+planner context it flattens leading batch/seq dims to a
   2-D GEMM, consults the planner's warmed cache (exact hit, else bucketed
   transfer, else an online tune over the closed-form analytic shortlist —

@@ -17,8 +17,8 @@ Two layers, both set by launchers before tracing and no-ops when absent:
    the deployment `Planner` whose warmed plan cache decides how each model
    matmul executes; `repro.models.matmul.pmm` consults it at trace time and
    dispatches through `repro.core.gemm.dit_gemm`. The context also records
-   every (tag, GEMMShape) the model actually traces — the ground truth that
-   `repro.deploy.planner.model_workload` is cross-validated against — and
+   every (tag, GEMMShape) the model actually traces — under a record-only
+   context, the workload `repro.models.workload.gemm_workload` warms — and
    keeps routing stats (exact hit / bucketed / analytic online-tune /
    fallback) for the launcher's shutdown report. With no context installed, `pmm` is exactly `x @ w`, so
    smoke tests and meshless tracing are unchanged.
@@ -56,8 +56,8 @@ class GemmStats:
 
     Counts are per *traced* `pmm` call (shapes are static, so each jit trace
     consults the planner once per callsite per layer group); `observed` maps
-    (tag, GEMMShape) -> trace count and is the model-side record that
-    `model_workload` predictions are checked against.
+    (tag, GEMMShape) -> trace count: the workload a record-only trace
+    hands the planner to warm (`repro.models.workload`).
     """
     hits: int = 0          # served a fully-tuned plan
     bucketed: int = 0      # served a bucket-transferred plan
@@ -69,9 +69,9 @@ class GemmStats:
     observed: Dict[Tuple[str, object], int] = dataclasses.field(
         default_factory=dict)
     # attention dispatches (pattn) keep a separate observed map: the GEMM
-    # `observed` feeds `workload_coverage`/`observed_shapes`, whose consumers
-    # sort on (m, n, k) and rebuild `GEMMShape(*shape)` — an AttnShape there
-    # would crash them. Keys are (tag, AttnShape).
+    # `observed` feeds `observed_shapes`, whose consumers bucket and tune
+    # (m, n, k) GEMMs — an AttnShape there would crash them. Keys are
+    # (tag, AttnShape).
     attn_observed: Dict[Tuple[str, object], int] = dataclasses.field(
         default_factory=dict)
     # schedule->mesh lowering outcomes (repro.core.lower.ExecPlan): which
@@ -198,8 +198,8 @@ class GemmContext:
     """What `pmm` needs to route a model matmul through `dit_gemm`.
 
     mesh=None makes the context record-only: every pmm call is logged in
-    `stats.observed` but executes as plain `x @ w` (used by dry-runs and the
-    workload cross-validation tests, which trace without devices to spare).
+    `stats.observed` but executes as plain `x @ w` (used by dry-runs and
+    `models.workload.gemm_workload`, which trace without devices to spare).
     """
     mesh: Optional[Mesh] = None
     planner: Optional[object] = None      # repro.deploy.Planner (duck-typed)

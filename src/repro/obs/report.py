@@ -1,11 +1,11 @@
 """Versioned machine-readable run reports (jax-free).
 
 Everything the launchers used to report print-only — routing counters,
-executed modes, degrade reasons, workload coverage, calibration fit
-quality, drift — lands in one `run_report.json` document that CI asserts
-on directly instead of scraping stdout. The human-facing prints re-render
-from the SAME dict (`describe_routing`, `render_run_report`), so the two
-surfaces cannot drift apart.
+executed modes, degrade reasons, the dry-run's workload plan-resolve
+rate, calibration fit quality, drift — lands in one `run_report.json`
+document that CI asserts on directly instead of scraping stdout. The
+human-facing prints re-render from the SAME dict (`describe_routing`,
+`render_run_report`), so the two surfaces cannot drift apart.
 
 Schema (see docs/observability.md for the field-by-field reference):
 
@@ -14,7 +14,7 @@ Schema (see docs/observability.md for the field-by-field reference):
       "launcher": "serve" | "train" | "dryrun",
       "routing":     GemmStats.to_dict()         (counters + modes +
                                                   degrades + observed),
-      "workload":    coverage section            (optional),
+      "workload":    plan-resolve section        (optional, dryrun),
       "drift":       DriftMonitor.summary()      (optional),
       "calibration": fit-quality section         (optional),
       "dispatches":  per-pmm-span provenance     (optional, from the
@@ -131,13 +131,6 @@ def render_run_report(report: Dict[str, Any]) -> List[str]:
             lines.append(f"stacked layer weights: read in place "
                          f"{routing['in_place']}, sliced out "
                          f"{routing['sliced']}")
-    workload = report.get("workload")
-    if workload is not None:
-        lines.append(
-            f"workload cross-validation: model_workload predicted "
-            f"{workload['covered']:.0%} of the {workload['observed']} "
-            f"executed GEMM shapes ({len(workload['extra'])} unpredicted, "
-            f"{len(workload['missing'])} predicted-but-unexecuted)")
     serving = report.get("serving")
     if serving is not None:
         lines.append(

@@ -34,8 +34,8 @@ from repro.core.attention import attn_candidates, attn_tune, flat_attention
 from repro.core.lower import lower_attention
 from repro.core.schedule import (ATTN_DATAFLOW, AttnSchedule, AttnShape,
                                  GEMMShape)
-from repro.deploy import (PlanCache, Planner, model_workload,
-                          schedule_from_dict, schedule_to_dict)
+from repro.deploy import (PlanCache, Planner, schedule_from_dict,
+                          schedule_to_dict)
 from repro.deploy.plan import SOURCE_ANALYTIC, SOURCE_TUNED
 from repro.hw.config import AcceleratorConfig, HBMConfig, NoCConfig, TileConfig
 from repro.launch.mesh import make_mesh
@@ -45,6 +45,7 @@ from repro.models.attention import (_chunk, _sdpa, chunked_sdpa,
                                     mla_params)
 from repro.models.matmul import pattn, pmm
 from repro.models.shard_ctx import GemmContext, GemmStats
+from repro.models.workload import gemm_workload
 
 MINI = AcceleratorConfig(name="mini", grid=(4, 4),
                          tile=TileConfig(l1_bytes=4 * 1024 * 1024),
@@ -437,9 +438,9 @@ def test_mla_absorbed_decode_counts_per_head():
         == cfg.n_heads
     assert ctx.stats.observed[("mla.v_unabsorb", GEMMShape(b, dn, r))] \
         == cfg.n_heads
-    # and the planner's workload agrees (membership-based: multiplicity
+    # and the decode step's workload names them once each (multiplicity
     # lives in the observed counts)
-    workload = model_workload(cfg, b, max_len, kind="decode")
+    workload = gemm_workload(cfg, b, max_len, kind="decode")
     assert GEMMShape(b, r, dn) in workload
     assert GEMMShape(b, dn, r) in workload
     # the attention problem itself lands in the attention workload
@@ -458,13 +459,14 @@ ROUTED_ATTENTION_BODY = textwrap.dedent("""
     import jax.numpy as jnp
     import numpy as np
     from repro.configs import smoke_config
-    from repro.deploy import Planner, model_workload
+    from repro.deploy import Planner
     from repro.hw.config import (AcceleratorConfig, HBMConfig, NoCConfig,
                                  TileConfig)
     from repro.launch.mesh import make_mesh
     from repro.models import shard_ctx
     from repro.models.model import decode_init, decode_step, init_params
     from repro.models.shard_ctx import GemmContext
+    from repro.models.workload import gemm_workload
 
     MINI = AcceleratorConfig(name="mini", grid=(4, 1),
                              tile=TileConfig(l1_bytes=4 * 1024 * 1024),
@@ -482,7 +484,7 @@ ROUTED_ATTENTION_BODY = textwrap.dedent("""
         base = np.asarray(base, np.float32)
 
         planner = Planner(MINI, elem_bytes=4, max_candidates=8)
-        planner.batch_tune(model_workload(cfg, 4, 8, kind="decode"),
+        planner.batch_tune(gemm_workload(cfg, 4, 8, kind="decode"),
                            skip_illegal=True)
         mesh = make_mesh((4, 1), ("data", "model"))
         ctx = GemmContext(mesh=mesh, planner=planner)

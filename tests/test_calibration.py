@@ -365,13 +365,14 @@ MULTIDEVICE_BODY = textwrap.dedent("""
     import jax.numpy as jnp
     import numpy as np
     from repro.configs import smoke_config
-    from repro.deploy import Planner, hw_fingerprint, model_workload
+    from repro.deploy import Planner, hw_fingerprint
     from repro.hw.config import (AcceleratorConfig, HBMConfig, NoCConfig,
                                  TileConfig)
     from repro.launch.mesh import make_mesh
     from repro.models import shard_ctx
     from repro.models.model import forward, init_params
     from repro.models.shard_ctx import GemmContext
+    from repro.models.workload import gemm_workload
     from repro.sim.calibrate import CalibrationProfile
 
     MINI = AcceleratorConfig(name="mini", grid=(4, 4),
@@ -386,7 +387,7 @@ MULTIDEVICE_BODY = textwrap.dedent("""
     params = init_params(jax.random.PRNGKey(0), cfg)
     toks = jnp.asarray(rng.integers(0, cfg.vocab, (4, 16)), jnp.int32)
     base = np.asarray(forward(params, toks, cfg), np.float32)
-    workload = model_workload(cfg, 4, 16, kind="prefill")
+    workload = gemm_workload(cfg, 4, 16, kind="prefill")
 
     plain = Planner(MINI, elem_bytes=4, max_candidates=24)
     calib = Planner(MINI, elem_bytes=4, max_candidates=24,

@@ -16,7 +16,7 @@ from repro.core.remap import ClusterRemap
 from repro.core.schedule import GEMMShape, Schedule, Tiling, build_program
 from repro.deploy import (BucketingPolicy, DeploymentPlan, PlanCache, Planner,
                           SOURCE_BUCKETED, SOURCE_TUNED, adapt, bucket_of,
-                          hw_fingerprint, model_workload, plan_from_tuning)
+                          hw_fingerprint, plan_from_tuning)
 from repro.hw.config import AcceleratorConfig, HBMConfig, NoCConfig, TileConfig
 from repro.sim.perf import estimate
 
@@ -320,27 +320,25 @@ def test_lower_schedule_mapping():
     assert ep.mode == "splitk" and "grid_mismatch" in ep.reasons()
 
 
-def test_model_workload_extraction():
-    cfg = SimpleNamespace(d_model=64, hd=16, n_heads=4, n_kv_heads=2,
-                          d_ff=256, vocab=1000, attn="gqa", n_experts=0,
-                          moe_top_k=0, moe_d_ff=0, q_lora_rank=0,
-                          kv_lora_rank=0, rope_head_dim=0, nope_head_dim=0)
-    shapes = model_workload(cfg, batch=2, seq=8, kind="prefill")
+def test_gemm_workload_extraction():
+    from repro.configs import smoke_config
+    from repro.models.workload import gemm_workload
+    cfg = smoke_config("olmo-1b")                   # d 64, ffn 160, vocab 256
+    shapes = gemm_workload(cfg, batch=2, seq=8, kind="prefill")
     assert len(shapes) == len(set(shapes))          # deduped
-    assert GEMMShape(16, 256, 64) in shapes         # FFN up at 16 tokens
-    assert GEMMShape(16, 1000, 64) in shapes        # LM head
-    decode = model_workload(cfg, batch=2, seq=8, kind="decode")
-    assert GEMMShape(2, 256, 64) in decode          # M = batch for decode
+    assert GEMMShape(16, 160, 64) in shapes         # FFN up at 16 tokens
+    assert GEMMShape(16, 256, 64) in shapes         # LM head
+    decode = gemm_workload(cfg, batch=2, seq=8, kind="decode")
+    assert GEMMShape(2, 160, 64) in decode          # M = batch for decode
 
 
 def test_planner_end_to_end_batch_then_rerequest():
     """ISSUE acceptance: batch-tune a workload, re-request the same shapes,
     and observe pure cache hits (zero enumeration on the second pass)."""
-    cfg = SimpleNamespace(d_model=64, hd=16, n_heads=4, n_kv_heads=2,
-                          d_ff=128, vocab=512, attn="gqa", n_experts=0,
-                          moe_top_k=0, moe_d_ff=0, q_lora_rank=0,
-                          kv_lora_rank=0, rope_head_dim=0, nope_head_dim=0)
-    workload = model_workload(cfg, batch=4, seq=16, kind="prefill")
+    from repro.configs import smoke_config
+    from repro.models.workload import gemm_workload
+    workload = gemm_workload(smoke_config("olmo-1b"), batch=4, seq=16,
+                             kind="prefill")
     planner = make_planner()
     first = planner.batch_tune(workload)
     hits_before = planner.cache.stats.hits

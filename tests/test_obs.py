@@ -368,9 +368,9 @@ SERVE_REPORT_BODY = textwrap.dedent("""
     assert routing["calls"] == routing["routed"], routing
     assert routing["unrouted"] == 0 and routing["resolve_rate"] == 1.0
     assert routing["silent_degrades"] == 0, routing
-    assert r["workload"]["covered"] == 1.0, r["workload"]
     # every dispatch carries full plan provenance. GEMM rows come from the
-    # warmed cache (hits); attention rows (pattn.*) resolve online from the
+    # warmed cache (tuned or bucketed plans: the warm-up covered every GEMM
+    # the run executed); attention rows (pattn.*) resolve online from the
     # closed-form menu, so "analytic" joins their vocabulary, and their
     # shape is the 7-dim attention problem, not (m, n, k)
     assert r["dispatches"], "no pmm spans recorded"
@@ -384,7 +384,7 @@ SERVE_REPORT_BODY = textwrap.dedent("""
             if d["provenance"] != "fallback":
                 assert d["attn_schedule"], d
         else:
-            assert d["provenance"] in ("hit", "bucketed", "fallback"), d
+            assert d["provenance"] in ("hit", "bucketed"), d
             assert d["tag"] and len(d["shape"]) == 3, d
             assert d["plan_digest"], d
         assert d["plan_resolve_us"] >= 0 and d["dur_us"] >= 0, d

@@ -6,9 +6,11 @@ Covers the PR-2 contracts:
 - the tied-embedding logits refactor (einsum -> x @ embed.T) is exact;
 - dit_gemm derives the planner GEMMShape from flattened leading dims
   (regression: batched operands used to read a.shape[0]/b.shape[1] raw);
-- model_workload is cross-validated against the (tag, GEMMShape) pairs the
-  model actually traces — exact coverage for gqa/MLA/MoE/mamba2/xlstm/vlm
-  and the encoder-decoder stack (seamless, incl. cross-attention K/V);
+- a planner warmed from `gemm_workload` (the model's record-only trace of
+  a step) serves every routed GEMM of the live trace of that step from a
+  tuned or bucketed plan, for gqa/MLA/MoE/mamba2/xlstm/vlm and the
+  encoder-decoder stack (seamless, incl. cross-attention K/V), prefill and
+  decode;
 - a serve-style installed context routes matmuls through dit_gemm with
   plan hits for the model's workload shapes (multidevice, subprocess).
 """
@@ -24,22 +26,23 @@ import pytest
 
 from repro.configs import smoke_config
 from repro.core.schedule import GEMMShape
-from repro.deploy import (Planner, model_workload, moe_dispatch_geometry,
-                          workload_coverage)
+from repro.deploy import Planner
+from repro.deploy.warmup import warm_buckets
 from repro.hw.config import AcceleratorConfig, HBMConfig, NoCConfig, TileConfig
 from repro.launch.mesh import make_mesh
 from repro.models import shard_ctx
 from repro.models.matmul import pmm
-from repro.models.model import forward, init_params
+from repro.models.model import decode_init, decode_step, forward, init_params
 from repro.models.shard_ctx import GemmContext
+from repro.models.workload import frontend_inputs, gemm_workload
+from repro.obs import Tracer, dispatch_provenance, set_tracer
 
 MINI = AcceleratorConfig(name="mini", grid=(4, 4),
                          tile=TileConfig(l1_bytes=4 * 1024 * 1024),
                          noc=NoCConfig(), hbm=HBMConfig(n_channels=8))
 
-# one smoke arch per block kind the satellite names (vlm joined when
-# model_workload learned the modality-frontend projection; encdec joined
-# when it learned the encoder blocks + cross-attention K/V projections)
+# one smoke arch per block kind: attention, MLA, MoE, the mamba2 hybrid,
+# xLSTM, the VLM frontend and the encoder-decoder stack
 BLOCK_KINDS = {
     "gqa": "gemma-2b",
     "mla": "deepseek-v2-236b",
@@ -49,32 +52,6 @@ BLOCK_KINDS = {
     "vlm": "phi-3-vision-4.2b",
     "encdec": "seamless-m4t-medium",
 }
-
-
-def _stub_embeds(cfg, batch: int, key: str, abstract: bool):
-    shape = (batch, cfg.n_prefix, cfg.d_model)
-    if abstract:
-        return {key: jax.ShapeDtypeStruct(shape, jnp.bfloat16)}
-    rng = np.random.default_rng(9)
-    return {key: jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)}
-
-
-def _prefill_kwargs(cfg, batch: int, abstract: bool = True):
-    """Extra forward() inputs a modality-frontend arch needs (the VLM stub's
-    precomputed patch embeddings / the enc-dec stub's frame embeddings)."""
-    if getattr(cfg, "frontend", "none") == "vision_stub":
-        return _stub_embeds(cfg, batch, "prefix_embeds", abstract)
-    if getattr(cfg, "is_encoder_decoder", False):
-        return _stub_embeds(cfg, batch, "encoder_embeds", abstract)
-    return {}
-
-
-def _decode_kwargs(cfg, batch: int):
-    """Extra decode_step() inputs: enc-dec archs attend to the precomputed
-    encoder output every step (cross-attention K/V re-project it)."""
-    if getattr(cfg, "is_encoder_decoder", False):
-        return _stub_embeds(cfg, batch, "encoder_out", abstract=True)
-    return {}
 
 
 # ---------------------------------------------------------------------------
@@ -127,7 +104,8 @@ def test_forward_parity_no_mesh(kind):
     rng = np.random.default_rng(3)
     params = init_params(jax.random.PRNGKey(0), cfg)
     toks = jnp.asarray(rng.integers(0, cfg.vocab, (2, 16)), jnp.int32)
-    kwargs = _prefill_kwargs(cfg, 2, abstract=False)
+    kwargs = {k: jnp.asarray(rng.standard_normal(v.shape), v.dtype)
+              for k, v in frontend_inputs(cfg, 2, "prefill").items()}
     base = forward(params, toks, cfg, **kwargs)
     ctx = GemmContext(mesh=None)
     with shard_ctx.gemm_context(ctx):
@@ -194,87 +172,95 @@ def test_dit_gemm_modes_differentiable():
 
 
 # ---------------------------------------------------------------------------
-# model_workload cross-validation against the recorded workload
+# gemm_workload: the warm-up it names serves the live routed trace
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("kind", sorted(BLOCK_KINDS))
-def test_model_workload_cross_validation(kind):
-    """model_workload must describe exactly the GEMMs the model runs: every
-    predicted shape is observed and every observed shape predicted — at
-    100% coverage for every block kind, including the encoder-decoder
-    stack (encoder blocks + decoder cross-attention K/V projections)."""
-    cfg = smoke_config(BLOCK_KINDS[kind])
-    b, s = 2, 16
-    kwargs = _prefill_kwargs(cfg, b)
-    ctx = GemmContext(mesh=None)
-    with shard_ctx.gemm_context(ctx):
-        pshapes = jax.eval_shape(
-            lambda: init_params(jax.random.PRNGKey(0), cfg))
-        toks = jax.ShapeDtypeStruct((b, s), jnp.int32)
-        jax.eval_shape(lambda p, t, **kws: forward(p, t, cfg, **kws),
-                       pshapes, toks, **kwargs)
-    observed = ctx.stats.observed_shapes()
-    predicted = model_workload(cfg, b, s, kind="prefill")
-    cov = workload_coverage(predicted, observed)
-    assert cov["covered"] == 1.0, f"unpredicted shapes: {cov['extra']}"
-    assert cov["missing"] == [], f"never-executed shapes: {cov['missing']}"
+def _trace_step(cfg, b: int, seq: int, kind: str) -> None:
+    """Trace one step of `cfg` abstractly under whatever context is
+    installed: `forward` over (b, seq) tokens, or `decode_step` against a
+    cache of `seq` positions."""
+    extra = frontend_inputs(cfg, b, kind)
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    if kind == "decode":
+        caches = jax.eval_shape(lambda: decode_init({}, cfg, b, seq))
+        jax.eval_shape(
+            lambda p, c, t, i, kw: decode_step(p, c, t, i, cfg, **kw),
+            params, caches, jax.ShapeDtypeStruct((b, 1), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32), extra)
+    else:
+        jax.eval_shape(lambda p, t, kw: forward(p, t, cfg, **kw), params,
+                       jax.ShapeDtypeStruct((b, seq), jnp.int32), extra)
+
+
+def _check_warmup_serves_live_trace(block_kind: str, step: str) -> None:
+    """Warm a fresh planner from `gemm_workload` as the serve launcher
+    does, then trace the same step live on a 1x1 mesh in this process:
+    every GEMM the live trace routes resolves from a tuned or bucketed
+    plan, nothing falls back, and the live trace runs every GEMM the
+    workload named (the record-only trace left no cached, unrouted program
+    behind for it to reuse)."""
+    cfg = smoke_config(BLOCK_KINDS[block_kind])
+    b, seq = 2, 16
+    shapes = gemm_workload(cfg, b, seq, kind=step)
+    assert shapes, "the step traced no GEMMs"
+    planner = Planner(MINI, max_candidates=4)
+    warm_buckets(planner, shapes)
+    for shape in shapes:
+        planner.plan(shape)
+    ctx = GemmContext(mesh=make_mesh((1, 1), ("data", "model")),
+                      planner=planner)
+    tracer = Tracer(process_name="test")
+    set_tracer(tracer)
+    try:
+        with shard_ctx.gemm_context(ctx):
+            _trace_step(cfg, b, seq, step)
+    finally:
+        set_tracer(None)
+    # attention (pattn.*) resolves online from its closed-form menu, which
+    # `stats.analytic` counts too: the GEMM dispatches are read on their own
+    gemm_rows = [d for d in dispatch_provenance(tracer)
+                 if d["name"].startswith("pmm.")]
+    assert gemm_rows, "no GEMM was routed"
+    assert {d["provenance"] for d in gemm_rows} <= {"hit", "bucketed"}, \
+        [d for d in gemm_rows if d["provenance"] not in ("hit", "bucketed")]
+    assert ctx.stats.fallback == 0 and ctx.stats.unrouted == 0, \
+        ctx.stats.describe()
+    assert set(ctx.stats.observed_shapes()) == set(shapes)
 
 
 @pytest.mark.parametrize("kind", sorted(BLOCK_KINDS))
-def test_model_workload_cross_validation_decode(kind):
-    """Decode kind must match the decode path — including MLA's absorbed
-    form (q-absorb / v-un-absorb contractions instead of K/V up-projection)
-    and the recurrent SSM/xLSTM mixers."""
-    from repro.models.model import decode_init, decode_step
-    cfg = smoke_config(BLOCK_KINDS[kind])
-    b, max_len = 2, 16
-    kwargs = _decode_kwargs(cfg, b)
-    ctx = GemmContext(mesh=None)
-    with shard_ctx.gemm_context(ctx):
-        pshapes = jax.eval_shape(
-            lambda: init_params(jax.random.PRNGKey(0), cfg))
-        caches = jax.eval_shape(
-            lambda: decode_init({}, cfg, b, max_len))
-        toks = jax.ShapeDtypeStruct((b, 1), jnp.int32)
-        pos = jax.ShapeDtypeStruct((), jnp.int32)
-        jax.eval_shape(lambda p, c, t, i, **kws: decode_step(
-                           p, c, t, i, cfg, encoder_out=kws.get("encoder_out")),
-                       pshapes, caches, toks, pos, **kwargs)
-    observed = ctx.stats.observed_shapes()
-    predicted = model_workload(cfg, b, max_len, kind="decode")
-    cov = workload_coverage(predicted, observed)
-    assert cov["covered"] == 1.0, f"unpredicted shapes: {cov['extra']}"
-    assert cov["missing"] == [], f"never-executed shapes: {cov['missing']}"
+def test_gemm_workload_warms_routed_prefill(kind):
+    _check_warmup_serves_live_trace(kind, "prefill")
+
+
+@pytest.mark.parametrize("kind", sorted(BLOCK_KINDS))
+def test_gemm_workload_warms_routed_decode(kind):
+    """Decode includes MLA's absorbed form (q-absorb / v-un-absorb
+    contractions instead of the K/V up-projection) and the recurrent
+    SSM/xLSTM mixers."""
+    _check_warmup_serves_live_trace(kind, "decode")
 
 
 @pytest.mark.parametrize("meshed", [False, True],
                          ids=["held_experts", "expert_sharded_mesh"])
 def test_moe_geometry_prediction_matches_model(meshed):
-    """The expert GEMM shapes model_workload (deploy, jax-free) predicts
-    are the ones moe.apply_moe records: with no activation mesh, the held
-    experts' grouped GEMMs at the static budget of tokens * k rows; under
-    one, the capacity-bounded dispatch buffers (moe_dispatch_geometry)."""
+    """`gemm_workload`'s expert GEMMs follow the activation mesh it is
+    given, as moe.apply_moe does: with none, the held experts' grouped
+    GEMMs at the static budget of tokens * k rows; under one, the
+    capacity-bounded buffers of moe's own dispatch groups. The trace puts
+    the mesh in place back afterwards."""
+    from repro.models import moe
     cfg = smoke_config("deepseek-moe-16b")
     b, s = 2, 16
-    rows = (moe_dispatch_geometry(b * s, cfg)[1] if meshed
-            else b * s * cfg.moe_top_k)
-    predicted = set(model_workload(cfg, b, s, dp=1 if meshed else None))
-    ctx = GemmContext(mesh=None)
-    mesh = make_mesh((1, 1), ("data", "model")) if meshed else None
-    shard_ctx.set_mesh(mesh)
-    try:
-        with shard_ctx.gemm_context(ctx):
-            pshapes = jax.eval_shape(
-                lambda: init_params(jax.random.PRNGKey(0), cfg))
-            toks = jax.ShapeDtypeStruct((b, s), jnp.int32)
-            jax.eval_shape(lambda p, t: forward(p, t, cfg), pshapes, toks)
-    finally:
-        shard_ctx.set_mesh(None)
-    expert_shapes = {shape for (tag, shape) in ctx.stats.observed
-                     if tag.startswith("moe.expert")}
-    assert expert_shapes == {GEMMShape(rows, cfg.moe_d_ff, cfg.d_model),
-                             GEMMShape(rows, cfg.d_model, cfg.moe_d_ff)}
-    assert expert_shapes <= predicted
+    mesh = make_mesh((1, 1), ("data", "model"))
+    cap = moe._capacity(b * s // moe._dp_groups(b * s, mesh), cfg)
+    held = b * s * cfg.moe_top_k
+    rows, other = (cap, held) if meshed else (held, cap)
+    shapes = set(gemm_workload(cfg, b, s, mesh=mesh if meshed else None))
+    assert shard_ctx.get_mesh() is None
+    d, f = cfg.d_model, cfg.moe_d_ff
+    assert {GEMMShape(rows, f, d), GEMMShape(rows, d, f)} <= shapes
+    assert not {GEMMShape(other, f, d), GEMMShape(other, d, f)} & shapes
 
 
 # ---------------------------------------------------------------------------
@@ -291,7 +277,7 @@ def test_routed_forward_matches_baseline_single_device():
     base = forward(params, toks, cfg)
 
     planner = Planner(MINI, elem_bytes=4, max_candidates=8)
-    planner.batch_tune(model_workload(cfg, 2, 16, kind="prefill"))
+    planner.batch_tune(gemm_workload(cfg, 2, 16, kind="prefill"))
     mesh = make_mesh((1, 1), ("data", "model"))
     ctx = GemmContext(mesh=mesh, planner=planner)
     with shard_ctx.gemm_context(ctx):
@@ -312,13 +298,14 @@ MULTIDEVICE_BODY = textwrap.dedent("""
     import jax.numpy as jnp
     import numpy as np
     from repro.configs import smoke_config
-    from repro.deploy import Planner, model_workload
+    from repro.deploy import Planner
     from repro.hw.config import (AcceleratorConfig, HBMConfig, NoCConfig,
                                  TileConfig)
     from repro.launch.mesh import make_mesh
     from repro.models import shard_ctx
     from repro.models.model import forward, init_params
     from repro.models.shard_ctx import GemmContext
+    from repro.models.workload import gemm_workload
 
     MINI = AcceleratorConfig(name="mini", grid=(4, 4),
                              tile=TileConfig(l1_bytes=4 * 1024 * 1024),
@@ -332,7 +319,7 @@ MULTIDEVICE_BODY = textwrap.dedent("""
     # serve-style: warm the planner for the model workload, install the
     # context, trace on a 2x2 mesh
     planner = Planner(MINI, elem_bytes=4, max_candidates=8)
-    planner.batch_tune(model_workload(cfg, 4, 16, kind="prefill"))
+    planner.batch_tune(gemm_workload(cfg, 4, 16, kind="prefill"))
     mesh = make_mesh((2, 2), ("data", "model"))
     ctx = GemmContext(mesh=mesh, planner=planner)
     shard_ctx.set_gemm_context(ctx)

@@ -28,22 +28,21 @@ from types import SimpleNamespace
 
 import pytest
 
+from repro.configs import smoke_config
 from repro.deploy.batcher import (BatchPolicy, ContinuousBatcher, Request,
                                   bucket_pool, decode_m)
 from repro.hw.config import (AcceleratorConfig, HBMConfig, NoCConfig,
                              TileConfig)
-from repro.launch.traffic import (RequestRecord, ServingCosts, TenantSpec,
-                                  TrafficConfig, generate_trace,
-                                  serving_section, simulate, slo_summary)
+from repro.launch.traffic import (PHASES, RequestRecord, ServingCosts,
+                                  TenantSpec, TrafficConfig, batch_workload,
+                                  generate_trace, serving_section, simulate,
+                                  slo_summary)
 
 MINI = AcceleratorConfig(name="mini", grid=(4, 4),
                          tile=TileConfig(l1_bytes=4 * 1024 * 1024),
                          noc=NoCConfig(), hbm=HBMConfig(n_channels=8))
 
-STUB_CFG = SimpleNamespace(
-    name="stub", d_model=64, hd=16, n_heads=4, n_kv_heads=2, d_ff=256,
-    vocab=1000, attn="gqa", n_experts=0, moe_top_k=0, moe_d_ff=0,
-    q_lora_rank=0, kv_lora_rank=0, rope_head_dim=0, nope_head_dim=0)
+STUB_CFG = smoke_config("olmo-1b")
 
 
 class StubPlanner:
@@ -266,14 +265,14 @@ def test_simulate_deterministic_section():
 def test_simulate_first_encounter_charges_once():
     """Cold shapes pay the virtual compile exactly once; a fully
     precompiled pool pays none and finishes strictly earlier."""
-    from repro.deploy.planner import model_workload
     trace = generate_trace(_traffic())
     cold = _simulate(trace=trace)
     assert cold.cold_shapes == cold.distinct_shapes > 0
+    policy = BatchPolicy()                  # the bucket mode _simulate runs
     pool = []
-    for m in range(1, 200):
-        pool += model_workload(STUB_CFG, batch=m, seq=1, kind="prefill")
-        pool += model_workload(STUB_CFG, batch=m, seq=1, kind="decode")
+    for m in bucket_pool(_traffic().max_rows(policy), policy):
+        for phase in PHASES:
+            pool += batch_workload(STUB_CFG, m, phase)
     warm = _simulate(trace=trace, precompiled=pool)
     assert warm.cold_shapes == 0
     assert warm.makespan_s < cold.makespan_s

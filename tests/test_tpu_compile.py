@@ -39,13 +39,13 @@ from repro.configs import get_config
 from repro.core.attention import flat_attention
 from repro.core.lower import lower_attention, lower_schedule
 from repro.core.schedule import AttnSchedule, AttnShape, GEMMShape
-from repro.deploy import model_workload
 from repro.deploy.warmup import build_planner, warm_buckets
 from repro.kernels.ops import local_matmul
 from repro.launch import serve
 from repro.launch.mesh import make_mesh
 from repro.models import shard_ctx
 from repro.models.model import decode_init, init_params
+from repro.models.workload import gemm_workload
 from repro.train.steps import make_serve_step
 from serve_scopes import (_buffers, assert_names_every_part,
                           compile_donated_steps, kv_copies, weight_copies)
@@ -88,8 +88,8 @@ def planner(tmp_path_factory):
     """Plans priced for the one chip the serve launcher holds."""
     p = build_planner(str(tmp_path_factory.mktemp("plans")), (1, 1),
                       max_candidates=12)
-    warm_buckets(p, model_workload(OLMO, BATCH, DECODE_MAX_LEN, "decode")
-                 + model_workload(OLMO, BATCH, PREFILL_LEN, "prefill"))
+    warm_buckets(p, gemm_workload(OLMO, BATCH, DECODE_MAX_LEN, "decode")
+                 + gemm_workload(OLMO, BATCH, PREFILL_LEN, "prefill"))
     return p
 
 

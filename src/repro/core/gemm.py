@@ -35,11 +35,11 @@ Schedule-driven dispatch goes through `repro.core.lower.lower_schedule`,
 which resolves the tuned dataflow + logical grid into an `ExecPlan` (mode,
 mesh view, kwargs, explicit fallback chain); `dit_gemm` consumes the plan.
 
-A layer loop's weight may come as its stack and the layer's index
-(`layer=`): where one device holds the stack whole and the plan's kernel
-can read the layer where it lies (`reads_layer_in_place`), the mode's
-one-device body is that kernel alone; the caller slices the layer out
-otherwise.
+A weight may come as it is stored: a layer loop's as its stack and the
+layer's index (`layer=`), a tied LM head's as the embedding (N, K), its
+transpose (`transposed=`). Where one device holds it whole and the plan's
+kernel can read it so (`reads_in_place`), the mode's one-device body is
+that kernel alone; the caller copies the (K, N) matrix out otherwise.
 
 All modes are numerically validated against each other on a multi-device CPU
 mesh (tests/test_gemm_modes.py, tests/test_lowering.py; subprocess with fake
@@ -508,43 +508,63 @@ def auto_gemm(a: jax.Array, b: jax.Array, mesh: Mesh,
 # at 4 row blocks (phi4-mini's and DeepSeek-V2's prefill chunks, 9-11%
 # more tokens/s) and lost at 12 (olmo-1b's, 1.9% later first tokens).
 MAX_LAYER_READS = 4
+# The most row blocks a kernel may have and still read a weight stored as
+# its transpose (a tied LM head's embedding) with no transposed copy: one.
+# On v5e (chip runs) a tied head read so
+# took 1.70 ms against 9.02 for the transposed, padded copy and the kernel
+# at phi4-mini's decode (16x200064x3072, one row block), and lost where the
+# GEMM is compute-bound: 51.0 against 42.7 ms at its 2,048-row prefill (4
+# row blocks), 21.5 against 15.8 ms at olmo-1b's 6,144 rows (12).
+MAX_TRANSPOSED_READS = 1
 
 
-def reads_layer_in_place(exec_plan: ExecPlan, mesh: Mesh, m: int, k: int,
-                         n: int, dtype) -> bool:
-    """Whether the plan's (M, K, N) GEMM reads a layer of a stacked weight
-    where it lies: one device holds the stack whole (no mode reshards it
-    there), the plan's Pallas kernel can index it (`kernels.ops.
-    reads_layer_in_place`), and its row blocks read the layer at most
-    `MAX_LAYER_READS` times. Otherwise the GEMM is handed a slice; XLA's
-    `dot` (no inner kernel) is handed a slice too, which it may fuse."""
-    from repro.kernels.ops import reads_layer_in_place as kernel_reads
+def reads_in_place(exec_plan: ExecPlan, mesh: Mesh, m: int, k: int,
+                   n: int, dtype, stacked: bool = True,
+                   transposed: bool = False) -> bool:
+    """Whether the plan's (M, K, N) GEMM reads its weight as it is stored:
+    one device holds it whole (no mode reshards it there), and the plan's
+    Pallas kernel can read it so (`kernels.ops.reads_in_place`). A layer
+    of a stack (`stacked`) is read so only where the kernel's row blocks
+    read it at most `MAX_LAYER_READS` times, a transpose (`transposed`) at
+    most `MAX_TRANSPOSED_READS` times. Otherwise the GEMM is handed the
+    (K, N) matrix copied out; XLA's `dot` (no inner kernel) is handed that
+    copy too, which it may fuse."""
+    from repro.kernels.ops import reads_in_place as kernel_reads
     ik = exec_plan.inner_kernel
-    return (mesh.devices.size == 1 and ik is not None
-            and -(-m // ik.bm) <= MAX_LAYER_READS
-            and kernel_reads(ik, k, n, dtype))
+    if mesh.devices.size != 1 or ik is None:
+        return False
+    reads = -(-m // ik.bm)
+    return ((not stacked or reads <= MAX_LAYER_READS)
+            and (not transposed or reads <= MAX_TRANSPOSED_READS)
+            and kernel_reads(ik, k, n, dtype, transposed))
 
 
 def exec_plan_gemm(a: jax.Array, b: jax.Array, mesh: Mesh,
-                   exec_plan: ExecPlan, layer=None) -> jax.Array:
+                   exec_plan: ExecPlan, layer=None,
+                   transposed: bool = False) -> jax.Array:
     """Run one 2-D GEMM exactly as a resolved `ExecPlan` prescribes. With
-    `layer`, `b` is a stack (L, K, N) and the GEMM reads b[layer] in place,
-    where `reads_layer_in_place` holds."""
+    `layer`, `b` is a stack (L, K, N) and the GEMM reads b[layer] in place;
+    with `transposed`, `b` is stored as B's transpose (N, K) and read so;
+    either where `reads_in_place` holds."""
     ax = exec_plan.axes
     emesh = (exec_plan.view.materialize(mesh) if exec_plan.view is not None
              else mesh)
     mode = exec_plan.mode
     ik = exec_plan.inner_kernel
     ov = exec_plan.overlap
-    if layer is not None:
-        if not reads_layer_in_place(exec_plan, mesh, a.shape[0],
-                                    *b.shape[1:], b.dtype):
+    if layer is not None or transposed:
+        k, n = b.shape[-2:]
+        if transposed:
+            k, n = n, k
+        if not reads_in_place(exec_plan, mesh, a.shape[0], k, n, b.dtype,
+                              layer is not None, transposed):
             raise ValueError(f"{exec_plan.describe()} cannot read a "
-                             f"{b.shape[1:]} layer of its stack in place")
+                             f"{b.shape} weight as stored")
         from repro.kernels.ops import local_matmul
         with _mode_scope(mode):
             # every mode's body on one device is its local product
-            return local_matmul(a, b, ik, layer=layer).astype(a.dtype)
+            return local_matmul(a, b, ik, layer=layer,
+                                transposed=transposed).astype(a.dtype)
     with _mode_scope(mode):
         if mode == "auto":
             return auto_gemm(a, b, mesh, ax["row"], ax["col"])
@@ -578,7 +598,7 @@ def exec_plan_gemm(a: jax.Array, b: jax.Array, mesh: Mesh,
 def dit_gemm(a: jax.Array, b: jax.Array, mesh: Mesh, mode: str = "auto",
              row_axis: str = "data", col_axis: str = "model",
              plan=None, planner=None, exec_plan: Optional[ExecPlan] = None,
-             layer=None, **kw) -> jax.Array:
+             layer=None, transposed: bool = False, **kw) -> jax.Array:
     """Dispatch on the deployment schedule's dataflow pattern.
 
     Three override layers, strongest first:
@@ -598,13 +618,14 @@ def dit_gemm(a: jax.Array, b: jax.Array, mesh: Mesh, mode: str = "auto",
     `a` may carry leading batch/seq dims (B, S, K): they flatten into M for
     both the planner's GEMMShape and the shard_map dispatch, and the result
     is reshaped back to (B, S, N). `b` must be 2-D (K, N), or with
-    `exec_plan` and `layer` a stack (L, K, N) whose layer `layer` the plan
-    reads in place (`exec_plan_gemm`).
+    `exec_plan` a weight as stored that the plan reads so
+    (`exec_plan_gemm`): with `layer` a stack (L, K, N) and the layer's
+    index, with `transposed` B's transpose (N, K).
     """
     if b.ndim != (2 if layer is None else 3) or (
-            layer is not None and exec_plan is None):
-        raise ValueError(f"dit_gemm expects a 2-D weight, or a stacked one "
-                         f"with a layer and an exec_plan; got {b.shape}")
+            (layer is not None or transposed) and exec_plan is None):
+        raise ValueError(f"dit_gemm expects a 2-D weight, or one as stored "
+                         f"with an exec_plan; got {b.shape}")
     lead = a.shape[:-1]
     if a.ndim != 2:
         a = a.reshape(-1, a.shape[-1])
@@ -617,7 +638,7 @@ def dit_gemm(a: jax.Array, b: jax.Array, mesh: Mesh, mode: str = "auto",
                                    shape=(a.shape[0], b.shape[1], a.shape[1]),
                                    overrides=kw)
     if exec_plan is not None:
-        out = exec_plan_gemm(a, b, mesh, exec_plan, layer)
+        out = exec_plan_gemm(a, b, mesh, exec_plan, layer, transposed)
     elif mode not in MODES:
         raise KeyError(f"unknown mode {mode!r}; have {MODES}")
     else:
@@ -635,5 +656,5 @@ def dit_gemm(a: jax.Array, b: jax.Array, mesh: Mesh, mode: str = "auto",
             else:
                 out = allgather_gemm(a, b, mesh, row_axis, col_axis)
     if len(lead) != 1:
-        out = out.reshape(*lead, b.shape[-1])
+        out = out.reshape(*lead, out.shape[-1])
     return out

@@ -18,8 +18,11 @@ quantizing to fp8/int8 is the model's decision, not the scheduler's.
 Both take B as one matrix, or as a stack of layers (L, K, N) with the layer
 to read (the serve step's layer loop): the kernel then reads that layer
 where it lies, which needs blocks that divide its K and N and no cast
-(`reads_layer_in_place`), since a pad or a cast of the stack would copy all
-of it.
+(`reads_in_place`), since a pad or a cast of the stack would copy all of
+it. B may also be stored as its transpose, (N, K), as a tied LM head's
+embedding is (`transposed`): the kernel then reads it as stored, which
+needs a K block that divides K and no cast; N may be ragged, since that
+form's grid runs past N and pads nothing.
 """
 from __future__ import annotations
 
@@ -81,39 +84,52 @@ def pick_block_shape(m: int, n: int, k: int, elem_bytes: int = 2
     return bm, bn, min(bk, kp)
 
 
+def _stored(b: jax.Array, layer: Optional[jax.Array],
+            transposed: bool) -> jax.Array:
+    """The (K, N) matrix a stored B stands for, copied out (the oracles'
+    operand): b[layer] of a stack, transposed back if stored so."""
+    b = b if layer is None else b[layer]
+    return b.T if transposed else b
+
+
 @functools.partial(jax.jit,
                    static_argnames=("block_shape", "interpret", "use_kernel",
-                                    "out_dtype"))
+                                    "out_dtype", "transposed"))
 def tile_matmul(a: jax.Array, b: jax.Array,
                 block_shape: Optional[Tuple[int, int, int]] = None,
                 interpret: bool = False,
                 use_kernel: Optional[bool] = None,
                 out_dtype=None,
-                layer: Optional[jax.Array] = None) -> jax.Array:
+                layer: Optional[jax.Array] = None,
+                transposed: bool = False) -> jax.Array:
     """C = A @ B via the Pallas MMAD kernel with padding to block multiples.
 
     With `layer`, `b` is a stack (L, K, N) and C = A @ b[layer], read in
-    place: the blocks must divide K and N, since B is never padded then."""
+    place: the blocks must divide K and N, since B is never padded then.
+    With `transposed`, `b` is stored as B's transpose, (N, K) (or a stack
+    (L, N, K)), and read as stored: the K block must divide K, and N needs
+    no pad (`mmad`'s grid runs past it)."""
     m, k = a.shape
-    n = b.shape[-1]
+    n = b.shape[-2] if transposed else b.shape[-1]
     on_tpu = jax.default_backend() == "tpu"
     if use_kernel is None:
         use_kernel = on_tpu or interpret
     if not use_kernel:
-        out = ref.mmad_ref(a, b if layer is None else b[layer])
+        out = ref.mmad_ref(a, _stored(b, layer, transposed))
         return out.astype(out_dtype) if out_dtype is not None else out
 
     bs = block_shape or pick_block_shape(m, n, k, a.dtype.itemsize)
     bm, bn, bk = bs
-    mp, np_, kp = _round_up(m, bm), _round_up(n, bn), _round_up(k, bk)
+    mp, kp = _round_up(m, bm), _round_up(k, bk)
+    np_ = n if transposed else _round_up(n, bn)
     ap = jnp.pad(a, ((0, mp - m), (0, kp - k))) if (mp != m or kp != k) else a
-    if layer is not None and (kp != k or np_ != n):
-        raise ValueError(f"blocks {bs} do not divide a stacked B's layer "
-                         f"{b.shape[1:]}: slice the layer out instead")
+    if (layer is not None or transposed) and (kp != k or np_ != n):
+        raise ValueError(f"blocks {bs} do not divide B {b.shape} as stored: "
+                         f"hand over the matrix copied out instead")
     bp = jnp.pad(b, ((0, kp - k), (0, np_ - n))) if (kp != k or np_ != n) else b
     out = mmad(ap, bp, block_shape=(bm, bn, bk), interpret=not on_tpu,
-               out_dtype=out_dtype, layer=layer)
-    return out[:m, :n]
+               out_dtype=out_dtype, layer=layer, transposed=transposed)
+    return out[:m] if transposed else out[:m, :n]
 
 
 # ---------------------------------------------------------------------------
@@ -146,19 +162,22 @@ def _cast_operand(x: jax.Array, kernel: InnerKernel) -> jax.Array:
     return x.astype(_compute_dtype(x.dtype, kernel))
 
 
-def reads_layer_in_place(kernel: InnerKernel, k: int, n: int, dtype) -> bool:
-    """Whether `local_matmul` under `kernel` can read a (K, N) layer of a
-    stacked weight of `dtype` where it lies: the kernel's blocks divide K
-    and N, and it computes in the weight's own dtype. A pad or a cast of
-    the stack would copy all of it."""
+def reads_in_place(kernel: InnerKernel, k: int, n: int, dtype,
+                   transposed: bool = False) -> bool:
+    """Whether `local_matmul` under `kernel` can read a (K, N) weight of
+    `dtype` as it is stored, a layer of a stack or (`transposed`) an
+    (N, K) matrix: the kernel's blocks divide K, and N unless the weight
+    is stored transposed, and it computes in the weight's own dtype. A
+    pad or a cast of the stored array would copy all of it."""
     _, bn, bk = kernel.geometry()
-    return (k % bk == 0 and n % bn == 0
+    return (k % bk == 0 and (transposed or n % bn == 0)
             and jnp.dtype(_compute_dtype(dtype, kernel)) == jnp.dtype(dtype))
 
 
 def local_matmul(a: jax.Array, b: jax.Array, kernel: InnerKernel,
                  interpret: bool = False,
-                 layer: Optional[jax.Array] = None) -> jax.Array:
+                 layer: Optional[jax.Array] = None,
+                 transposed: bool = False) -> jax.Array:
     """Per-device C = A @ B under a planner-resolved inner kernel, fp32 out.
 
     On TPU (or under `interpret=True`) this is the Pallas `mmad` kernel at
@@ -169,15 +188,20 @@ def local_matmul(a: jax.Array, b: jax.Array, kernel: InnerKernel,
     routed training works through the Pallas path too.
 
     With `layer`, `b` is a stack (L, K, N) and C = A @ b[layer], the layer
-    read where it lies (`reads_layer_in_place` must hold). That form serves
-    the decode layer loop and has no VJP: training slices its layers.
+    read where it lies; with `transposed`, `b` is stored as B's transpose,
+    (N, K), and read as stored (`reads_in_place` must hold). These forms
+    serve the serve step's layer loop and tied LM head and have no VJP:
+    training hands over the matrix copied out.
     """
-    if layer is None:
+    if layer is None and not transposed:
         return _local_matmul(a, b, kernel, interpret)
-    if not reads_layer_in_place(kernel, *b.shape[1:], b.dtype):
+    k, n = b.shape[-2:]
+    if transposed:
+        k, n = n, k
+    if not reads_in_place(kernel, k, n, b.dtype, transposed):
         raise ValueError(f"kernel {kernel.describe()} cannot read a "
-                         f"{b.shape[1:]} {b.dtype} layer in place")
-    return _local_matmul_impl(a, b, kernel, interpret, layer)
+                         f"{b.shape} {b.dtype} B as stored")
+    return _local_matmul_impl(a, b, kernel, interpret, layer, transposed)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -185,16 +209,18 @@ def _local_matmul(a, b, kernel, interpret):
     return _local_matmul_impl(a, b, kernel, interpret)
 
 
-def _local_matmul_impl(a, b, kernel, interpret, layer=None):
+def _local_matmul_impl(a, b, kernel, interpret, layer=None,
+                       transposed=False):
     a = _cast_operand(a, kernel)
     b = _cast_operand(b, kernel)
     on_tpu = jax.default_backend() == "tpu"
     if not (on_tpu or interpret):
-        return jnp.dot(a, b if layer is None else b[layer],
+        return jnp.dot(a, _stored(b, layer, transposed),
                        preferred_element_type=jnp.float32)
     return tile_matmul(a, b, block_shape=kernel.geometry(),
                        interpret=interpret, use_kernel=True,
-                       out_dtype=jnp.float32, layer=layer)
+                       out_dtype=jnp.float32, layer=layer,
+                       transposed=transposed)
 
 
 def _local_matmul_fwd(a, b, kernel, interpret):

@@ -156,8 +156,9 @@ def build_lowered(cfg: ModelConfig, shape_name: str, mesh,
                 kwargs["encoder_embeds"] = extra[i]; i += 1
             hidden = forward(params, tokens, cfg, remat=False,
                              return_hidden=True, **kwargs)
+            from repro.models.matmul import weight_value
             from repro.models.model import lm_head_weight
-            return (hidden[:, -1] @ lm_head_weight(params, cfg)
+            return (hidden[:, -1] @ weight_value(lm_head_weight(params, cfg))
                     ).astype(jnp.float32)
 
         args = [pshapes, specs["tokens"]]

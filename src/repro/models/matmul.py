@@ -24,11 +24,14 @@ Batched einsums that are not single dense GEMMs (MoE expert batches, MLA's
 absorbed-form contractions) keep their einsum form but log their per-GEMM
 shape via `record_gemm` so the observed workload stays complete.
 
-`w` may be a `LayerWeight`: one layer of a stacked weight, as the serve
-step's layer loop hands it over. A routed GEMM whose kernel can index the
-stack, and would not re-read it from HBM for too many row blocks, reads
-the layer where it lies (counted `in_place`); any other slices the layer
-out first (counted `sliced` when routed; `core.gemm.reads_layer_in_place`).
+`w` may be a `StoredWeight`: the weight as it is stored, not copied out.
+The serve step's layer loop hands each layer's GEMMs one layer of a
+stacked weight; a tied LM head is handed the embedding (vocab, D), the
+transpose of what it multiplies by. A routed GEMM whose kernel can read
+the weight so, in few enough row blocks, does (counted `in_place` for a
+layer, `read_transposed` for a transpose); any other is handed the
+(K, N) matrix copied out (counted `sliced`, `copied_transposed`;
+`core.gemm.reads_in_place`).
 
 See docs/architecture.md (routing path) and docs/plan-lifecycle.md (how the
 plans pmm consults are produced, cached, and refined).
@@ -41,6 +44,7 @@ import time
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 
 from repro.core.schedule import AttnShape, GEMMShape
 from repro.models import shard_ctx
@@ -48,33 +52,39 @@ from repro.obs import trace as obs_trace
 
 
 @dataclasses.dataclass(frozen=True)
-class LayerWeight:
-    """Layer `layer` of a stacked weight `stack` (L, ...), not sliced out:
-    GEMMs that can index the stack read the layer where it lies, and the
-    rest take `value()`."""
-    stack: jax.Array
-    layer: jax.Array
+class StoredWeight:
+    """A (K, N) GEMM weight where and as it is stored: layer `layer` of a
+    stacked weight `stored` (L, ...), or `stored` itself with no layer;
+    with `transposed`, stored as its transpose (N, K), as a tied LM head's
+    embedding is. GEMMs that can read it so do; the rest take `value()`,
+    the (K, N) matrix copied out."""
+    stored: jax.Array
+    layer: Optional[jax.Array] = None
+    transposed: bool = False
 
     @property
     def shape(self):
-        return self.stack.shape[1:]
+        s = self.stored.shape if self.layer is None else self.stored.shape[1:]
+        return (*s[:-2], s[-1], s[-2]) if self.transposed else s
 
     @property
     def ndim(self) -> int:
-        return self.stack.ndim - 1
+        return len(self.shape)
 
     @property
     def dtype(self):
-        return self.stack.dtype
+        return self.stored.dtype
 
     def value(self) -> jax.Array:
-        return jax.lax.dynamic_index_in_dim(self.stack, self.layer,
-                                            keepdims=False)
+        w = self.stored
+        if self.layer is not None:
+            w = jax.lax.dynamic_index_in_dim(w, self.layer, keepdims=False)
+        return jnp.swapaxes(w, -1, -2) if self.transposed else w
 
 
-def layer_value(w) -> jax.Array:
-    """`w` as an array: a `LayerWeight`'s layer sliced out, else `w`."""
-    return w.value() if isinstance(w, LayerWeight) else w
+def weight_value(w) -> jax.Array:
+    """`w` as an array: a `StoredWeight`'s matrix copied out, else `w`."""
+    return w.value() if isinstance(w, StoredWeight) else w
 
 
 def _gemm_shape(x: jax.Array, w: jax.Array) -> GEMMShape:
@@ -149,15 +159,15 @@ def _dispatch_routed(ctx, x: jax.Array, w: jax.Array, shape: GEMMShape,
             ctx.stats.analytic += 1
     if plan is None:
         ctx.stats.fallback += 1
-        if isinstance(w, LayerWeight):
-            ctx.stats.sliced += 1
+        if isinstance(w, StoredWeight):
+            ctx.stats.record_stored(w.layer is not None, w.transposed, False)
         # inner_kernel/overlap are part of the per-dispatch contract: every
         # dispatch record carries them (None/False = XLA-picked local GEMM),
         # so drift monitoring can attribute a regression to the inner level
         # without special-casing fallbacks
         prov.update(provenance="fallback", mode="auto",
                     inner_kernel=None, overlap=False)
-        return dit_gemm(x, layer_value(w), ctx.mesh, mode="auto",
+        return dit_gemm(x, weight_value(w), ctx.mesh, mode="auto",
                         row_axis=ctx.row_axis, col_axis=ctx.col_axis)
     # lower the tuned schedule here (not inside dit_gemm) so the resolved
     # mode and any fallback reasons land in the context stats — launchers
@@ -178,22 +188,25 @@ def _dispatch_routed(ctx, x: jax.Array, w: jax.Array, shape: GEMMShape,
         if hasattr(plan, "digest"):
             prov["plan_digest"] = plan.digest()
         prov["calibration_digest"] = getattr(plan, "calibration_digest", "")
-    layer = None
-    if isinstance(w, LayerWeight):
-        from repro.core.gemm import reads_layer_in_place
-        if reads_layer_in_place(exec_plan, ctx.mesh, shape.m, *w.shape,
-                                w.dtype):
-            ctx.stats.in_place += 1
-            w, layer = w.stack, w.layer
+    layer, transposed = None, False
+    if isinstance(w, StoredWeight):
+        from repro.core.gemm import reads_in_place
+        as_stored = reads_in_place(exec_plan, ctx.mesh, shape.m, *w.shape,
+                                   w.dtype, w.layer is not None,
+                                   w.transposed)
+        ctx.stats.record_stored(w.layer is not None, w.transposed,
+                                as_stored)
+        if as_stored:
+            w, layer, transposed = w.stored, w.layer, w.transposed
         else:
-            ctx.stats.sliced += 1
             w = w.value()
     return dit_gemm(x, w, ctx.mesh, row_axis=ctx.row_axis,
-                    col_axis=ctx.col_axis, exec_plan=exec_plan, layer=layer)
+                    col_axis=ctx.col_axis, exec_plan=exec_plan, layer=layer,
+                    transposed=transposed)
 
 
 def pmm(x: jax.Array, w, tag: str = "") -> jax.Array:
-    """Plan-routed `x @ w`. x: (..., K); w: (K, N), or a `LayerWeight`
+    """Plan-routed `x @ w`. x: (..., K); w: (K, N), or a `StoredWeight`
     of that shape -> (..., N).
 
     Every call, routed or not, emits its ops under
@@ -206,7 +219,7 @@ def pmm(x: jax.Array, w, tag: str = "") -> jax.Array:
 def _pmm(x: jax.Array, w: jax.Array, tag: str) -> jax.Array:
     ctx = shard_ctx.get_gemm_context()
     if ctx is None:
-        return x @ layer_value(w)
+        return x @ weight_value(w)
     if not _routable(x, w):
         # not a single dense GEMM this layer understands; stay out of the
         # way — but record it first, or the observed workload silently
@@ -216,7 +229,7 @@ def _pmm(x: jax.Array, w: jax.Array, tag: str) -> jax.Array:
                 and all(int(d) > 0 for d in w.shape)):
             ctx.stats.record(tag, _gemm_shape(x, w))
         ctx.stats.unroutable += 1
-        return x @ layer_value(w)
+        return x @ weight_value(w)
     shape = _gemm_shape(x, w)
     ctx.stats.record(tag, shape)
     tracer = obs_trace.get_tracer()
@@ -227,7 +240,7 @@ def _pmm(x: jax.Array, w: jax.Array, tag: str) -> jax.Array:
                            shape=[shape.m, shape.n, shape.k],
                            provenance="unrouted")
             tracer.metrics.counter("pmm.provenance.unrouted").inc()
-        return x @ layer_value(w)
+        return x @ weight_value(w)
     if tracer is None:
         return _dispatch_routed(ctx, x, w, shape, {}, None)
     # spans measure the TRACE-TIME dispatch cost (shapes are static under

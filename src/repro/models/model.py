@@ -30,7 +30,7 @@ from repro.models import moe as moe_lib
 from repro.models import ssm
 from repro.models.common import (ModelConfig, Params, apply_mlp, apply_norm,
                                  dense_init, mlp_params, norm_params)
-from repro.models.matmul import LayerWeight, pmm
+from repro.models.matmul import StoredWeight, pmm
 
 
 # ---------------------------------------------------------------------------
@@ -307,18 +307,22 @@ def forward(params: Params, tokens: jax.Array, cfg: ModelConfig,
     if return_hidden:
         return x
     # tied-embedding logits: x @ embed.T is the same dot_general the einsum
-    # lowered to, expressed as a routable dense GEMM (lm_head_weight
-    # transposes for the tied case)
+    # lowered to, expressed as a routable dense GEMM (lm_head_weight hands
+    # the tied case over as the embedding, stored transposed)
     with jax.named_scope("lm_head"):
         logits = pmm(x, lm_head_weight(params, cfg), tag="lm_head")
         return logits.astype(jnp.float32)
 
 
-def lm_head_weight(params: Params, cfg: ModelConfig) -> jax.Array:
-    """(D, vocab) projection used by the fused loss."""
+def lm_head_weight(params: Params, cfg: ModelConfig):
+    """The (D, vocab) LM head: the untied `lm_head`, or the tied embedding
+    (vocab, D) as it is stored, marked as the transpose of what the head
+    multiplies by (`StoredWeight`), so that a kernel reads it in place.
+    A caller that needs the matrix takes `matmul.weight_value`."""
     head = params.get("lm_head")
     if head is None:
-        return params["embed"].astype(cfg.dtype).T
+        return StoredWeight(params["embed"].astype(cfg.dtype),
+                            transposed=True)
     return head
 
 
@@ -327,7 +331,7 @@ def lm_head_weight(params: Params, cfg: ModelConfig) -> jax.Array:
 # rides in the carry beside the layer counter, so each layer reads its K/V
 # where they lie and writes its rows in place. Its GEMM weights stay out of
 # the scan's xs: each layer's GEMMs get their stack and the layer counter
-# (`LayerWeight`) and read the layer where it lies. The small leaves (norms,
+# (`StoredWeight`) and read the layer where it lies. The small leaves (norms,
 # biases, the router, MLA's w_uk / w_uv that XLA's einsums read) are the
 # xs. Recurrent states (mamba2, xLSTM), KB to MB a layer, stay in xs/ys.
 # ---------------------------------------------------------------------------
@@ -339,7 +343,7 @@ _SLICED_WEIGHTS = ("router", "w_uk", "w_uv")
 
 def _by_reference(path, leaf) -> bool:
     """Whether the layer loop hands a stacked leaf to its layer as a
-    `LayerWeight`: the matrices its GEMMs read, (L, K, N) or the experts'
+    `StoredWeight`: the matrices its GEMMs read, (L, K, N) or the experts'
     (L, E, K, N). Under an activation mesh every leaf is sliced: its
     partitioned GEMMs and the capacity dispatch read whole arrays."""
     return (shard_ctx.get_mesh() is None and leaf.ndim >= 3
@@ -426,7 +430,7 @@ def decode_step(params: Params, caches: Dict[str, Any], tokens: jax.Array,
         def step(carry, lp):
             h, layer, c = carry
             lp = jax.tree_util.tree_unflatten(tree, [
-                LayerWeight(leaf, layer) if r else sliced
+                StoredWeight(leaf, layer) if r else sliced
                 for r, (_, leaf), sliced in zip(by_ref, flat, lp)])
             h, c = block(block_fn, lp, h, c, layer)
             return (h, layer + 1, c), None

@@ -35,7 +35,7 @@ from jax.experimental.pallas.ops.tpu.megablox import gmm as megablox_gmm
 
 from repro.models import shard_ctx
 from repro.models.common import ModelConfig, Params, activation, dense_init, mlp_params
-from repro.models.matmul import LayerWeight, pmm, record_gemm
+from repro.models.matmul import StoredWeight, pmm, record_gemm
 
 # (rows, contraction, columns) of a grouped-GEMM kernel step on the TPU:
 # the fastest of six tried on v5e at DeepSeek-V2's expert shapes
@@ -87,15 +87,15 @@ def _grouped(x: jax.Array, w, sizes: jax.Array) -> jax.Array:
     a group's rows: the rows past the last group are neither computed nor
     written. Elsewhere `jax.lax.ragged_dot`, which gives them zeros.
 
-    `w` may be one layer of a stacked (L, G, K, N) weight (`LayerWeight`):
+    `w` may be one layer of a stacked (L, G, K, N) weight (`StoredWeight`):
     the GEMM then reads it where it lies, from the (L·G, K, N) view of the
     stack (a bitcast), with the sizes at the layer's groups and zero at
     every other layer's, whose experts the kernel never visits."""
-    if isinstance(w, LayerWeight):
-        n_layers, g = w.stack.shape[:2]
+    if isinstance(w, StoredWeight):
+        n_layers, g = w.stored.shape[:2]
         sizes = jax.lax.dynamic_update_slice(
             jnp.zeros((n_layers * g,), sizes.dtype), sizes, (w.layer * g,))
-        w = w.stack.reshape(n_layers * g, *w.shape[1:])
+        w = w.stored.reshape(n_layers * g, *w.shape[1:])
     if jax.default_backend() != "tpu":
         return jax.lax.ragged_dot(x, w, sizes)
     tiling = tuple(min(t, n) for t, n in zip(GMM_TILING,

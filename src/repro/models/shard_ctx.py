@@ -83,11 +83,16 @@ class GemmStats:
     #                            (structurally 0: every ExecPlan fallback
     #                            carries a reason; kept as the cross-check)
     # routed GEMMs handed one layer of a stacked weight (models.matmul.
-    # LayerWeight): read where it lies by the kernel, or sliced out first
+    # StoredWeight): read where it lies by the kernel, or sliced out first
     # (blocks that do not divide it, a cast, a mesh that reshards it, or
     # XLA's dot, which is handed the slice)
     in_place: int = 0
     sliced: int = 0
+    # routed GEMMs handed a weight stored as its transpose (a tied LM
+    # head's embedding): read as stored by the kernel, or handed the
+    # transposed copy (for the same reasons)
+    read_transposed: int = 0
+    copied_transposed: int = 0
 
     def record(self, tag: str, shape, count: int = 1) -> None:
         """`count` > 1 logs one traced call that stands for `count` GEMMs of
@@ -99,6 +104,21 @@ class GemmStats:
     def record_attn(self, tag: str, shape) -> None:
         key = (tag, shape)
         self.attn_observed[key] = self.attn_observed.get(key, 0) + 1
+
+    def record_stored(self, layered: bool, transposed: bool,
+                      as_stored: bool) -> None:
+        """Count a routed GEMM handed a weight as stored (a layer of a
+        stack, a transpose, or both): read so, or copied out."""
+        if layered:
+            if as_stored:
+                self.in_place += 1
+            else:
+                self.sliced += 1
+        if transposed:
+            if as_stored:
+                self.read_transposed += 1
+            else:
+                self.copied_transposed += 1
 
     def record_lowering(self, exec_plan) -> None:
         """Count an ExecPlan's executed mode + its fallback-chain reasons."""
@@ -148,6 +168,8 @@ class GemmStats:
             "silent_degrades": self.silent_degrades,
             "in_place": self.in_place,
             "sliced": self.sliced,
+            "read_transposed": self.read_transposed,
+            "copied_transposed": self.copied_transposed,
             "observed": [
                 {"tag": tag,
                  "shape": ([int(s.m), int(s.n), int(s.k)]
@@ -176,7 +198,9 @@ class GemmStats:
                     degrades=dict(d.get("degrades", {})),
                     silent_degrades=int(d.get("silent_degrades", 0)),
                     in_place=int(d.get("in_place", 0)),
-                    sliced=int(d.get("sliced", 0)))
+                    sliced=int(d.get("sliced", 0)),
+                    read_transposed=int(d.get("read_transposed", 0)),
+                    copied_transposed=int(d.get("copied_transposed", 0)))
         for rec in d.get("observed", []):
             key = (rec["tag"], GEMMShape(*rec["shape"]))
             stats.observed[key] = int(rec["count"])

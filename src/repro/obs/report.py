@@ -56,6 +56,9 @@ def describe_routing(d: Dict[str, Any]) -> str:
     if d.get("in_place") or d.get("sliced"):
         out += (f" layer-weights in-place={d['in_place']} "
                 f"sliced={d['sliced']}")
+    if d.get("read_transposed") or d.get("copied_transposed"):
+        out += (f" transposed-weights as-stored={d['read_transposed']} "
+                f"copied={d['copied_transposed']}")
     return out
 
 
@@ -131,6 +134,10 @@ def render_run_report(report: Dict[str, Any]) -> List[str]:
             lines.append(f"stacked layer weights: read in place "
                          f"{routing['in_place']}, sliced out "
                          f"{routing['sliced']}")
+        if routing.get("read_transposed") or routing.get("copied_transposed"):
+            lines.append(f"weights stored transposed: read as stored "
+                         f"{routing['read_transposed']}, copied "
+                         f"{routing['copied_transposed']}")
     serving = report.get("serving")
     if serving is not None:
         lines.append(

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import ModelConfig
+from repro.models.matmul import weight_value
 from repro.models.model import decode_step, forward, lm_head_weight
 from repro.optim import adamw, compress
 
@@ -72,7 +73,7 @@ def loss_fn(params, batch: Dict[str, jax.Array], cfg: ModelConfig,
                      return_hidden=True, **kwargs)
     if cfg.frontend == "vision_stub":
         hidden = hidden[:, -batch["tokens"].shape[1]:]   # drop prefix positions
-    return chunked_xent(hidden, lm_head_weight(params, cfg),
+    return chunked_xent(hidden, weight_value(lm_head_weight(params, cfg)),
                         batch["targets"], cfg.vocab)
 
 

@@ -9,6 +9,8 @@ The property-based tests need hypothesis (requirements-dev.txt); the
 parity and contract tests run without it so the local fast lane still
 covers the dispatch path.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -100,6 +102,73 @@ def test_stacked_weight_is_never_padded():
     with pytest.raises(ValueError, match="cannot read"):
         local_matmul(a, stack, InnerKernel(8, 128, 256, dtype="float32"),
                      layer=jnp.int32(1))
+
+
+# B stored as its transpose (N, K), as a tied LM head's embedding (V, D) is:
+# blocks of 256 columns divide V = 1024 and leave V = 1000 a ragged last
+# block; M of 8 and 16 fill one or two row blocks of 8, 40 fills five
+NT_BLOCK = (8, 256, 128)
+
+
+def _nt_product(entry, a, e):
+    if entry == "mmad":
+        return mmad(a, e, block_shape=NT_BLOCK, interpret=True,
+                    out_dtype=jnp.float32, transposed=True)
+    if entry == "tile_matmul":
+        return tile_matmul(a, e, block_shape=NT_BLOCK, interpret=True,
+                           use_kernel=True, out_dtype=jnp.float32,
+                           transposed=True)
+    return local_matmul(a, e, InnerKernel(*NT_BLOCK, dtype="float32"),
+                        interpret=True, transposed=True)
+
+
+@pytest.mark.parametrize("entry", ["mmad", "tile_matmul", "local_matmul"])
+@pytest.mark.parametrize("v", [1024, 1000], ids=["v-divides", "v-ragged"])
+@pytest.mark.parametrize("m", [8, 16, 40])
+def test_kernel_reads_b_stored_transposed(entry, v, m):
+    """B handed over as stored, its transpose E (V, K): every entry point
+    gives A @ E.T, the ragged last block of V included, and the output is
+    (M, V)."""
+    a = jnp.asarray(RNG.standard_normal((m, 256)), jnp.float32)
+    e = jnp.asarray(RNG.standard_normal((v, 256)), jnp.float32)
+    out = _nt_product(entry, a, e)
+    assert out.shape == (m, v)
+    np.testing.assert_allclose(out, _oracle(a, e.T), rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_mmad_reads_a_transposed_layer_of_a_stack(layer):
+    """A stack of transposed matrices (L, N, K) and a layer index: the
+    same numbers as the layer sliced out."""
+    a = jnp.asarray(RNG.standard_normal((16, 256)), jnp.float32)
+    stack = jnp.asarray(RNG.standard_normal((3, 1000, 256)), jnp.float32)
+    out = mmad(a, stack, block_shape=NT_BLOCK, interpret=True,
+               layer=jnp.int32(layer), transposed=True)
+    np.testing.assert_array_equal(
+        out, mmad(a, stack[layer], block_shape=NT_BLOCK, interpret=True,
+                  transposed=True))
+
+
+def test_transposed_weight_is_never_padded():
+    """A stored-transposed B is read as it lies: the traced product pads
+    and transposes nothing (a ragged V included), and a K block that does
+    not divide K is refused, not padded."""
+    a = jnp.ones((16, 256), jnp.float32)
+    e = jnp.ones((1000, 256), jnp.float32)
+    jaxpr = str(jax.make_jaxpr(lambda a, e: tile_matmul(
+        a, e, block_shape=NT_BLOCK, interpret=True, use_kernel=True,
+        transposed=True))(a, e))
+    assert "pallas_call" in jaxpr
+    assert not re.findall(r"\b(pad|transpose|slice)\[", jaxpr), jaxpr
+    e384 = jnp.ones((1000, 384), jnp.float32)
+    with pytest.raises(ValueError, match="do not divide"):
+        tile_matmul(jnp.ones((16, 384), jnp.float32), e384,
+                    block_shape=(8, 256, 256), interpret=True,
+                    use_kernel=True, transposed=True)
+    with pytest.raises(ValueError, match="cannot read"):
+        local_matmul(jnp.ones((16, 384), jnp.float32), e384,
+                     InnerKernel(8, 256, 256, dtype="float32"),
+                     transposed=True)
 
 
 # ---------------------------------------------------------------------------

@@ -22,7 +22,7 @@ import pytest
 from repro.configs import get_config, smoke_config
 from repro.models.attention import _mla_scale
 from repro.models.common import activation, rope_tables
-from repro.models.matmul import LayerWeight
+from repro.models.matmul import StoredWeight
 from repro.models.moe import _grouped, apply_moe, moe_params, route
 
 V2 = get_config("deepseek-v2-236b")
@@ -178,7 +178,7 @@ def test_grouped_reads_its_layer_of_the_expert_stack(layer):
     stack = jnp.asarray(rng.standard_normal((n_layers, e, k, n)), jnp.float32)
     x = jnp.asarray(rng.standard_normal((40, k)), jnp.float32)
     sizes = jnp.asarray([7, 0, 12, 3, 9], jnp.int32)       # 31 of 40 rows
-    got = _grouped(x, LayerWeight(stack, jnp.int32(layer)), sizes)
+    got = _grouped(x, StoredWeight(stack, jnp.int32(layer)), sizes)
     want = jax.lax.ragged_dot(x, stack[layer], sizes)
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
     assert not np.asarray(got[31:]).any()

@@ -9,6 +9,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import smoke_config
 from repro.launch.mesh import make_mesh
+from repro.models.matmul import weight_value
 from repro.models.model import init_params, lm_head_weight
 from repro.train.steps import chunked_xent
 
@@ -79,7 +80,7 @@ def test_chunked_xent_matches_direct():
     hidden = jnp.asarray(rng.standard_normal((2, 24, cfg.d_model)) * 0.1,
                          cfg.dtype)
     targets = jnp.asarray(rng.integers(0, cfg.vocab, (2, 24)), jnp.int32)
-    w = lm_head_weight(params, cfg)
+    w = weight_value(lm_head_weight(params, cfg))
     fused = chunked_xent(hidden, w, targets, cfg.vocab, chunk=8)
     logits = (hidden @ w).astype(jnp.float32)
     logp = jax.nn.log_softmax(logits, -1)
@@ -94,7 +95,7 @@ def test_chunked_xent_gradients_flow():
     hidden = jnp.asarray(rng.standard_normal((2, 16, cfg.d_model)) * 0.1,
                          jnp.float32)
     targets = jnp.asarray(rng.integers(0, cfg.vocab, (2, 16)), jnp.int32)
-    w = lm_head_weight(params, cfg).astype(jnp.float32)
+    w = weight_value(lm_head_weight(params, cfg)).astype(jnp.float32)
     g = jax.grad(lambda h: chunked_xent(h, w, targets, cfg.vocab, chunk=8))(hidden)
     assert np.isfinite(np.asarray(g)).all()
     assert float(jnp.abs(g).max()) > 0

@@ -226,6 +226,9 @@ def _check_warmup_serves_live_trace(block_kind: str, step: str) -> None:
     assert ctx.stats.fallback == 0 and ctx.stats.unrouted == 0, \
         ctx.stats.describe()
     assert set(ctx.stats.observed_shapes()) == set(shapes)
+    # a tied head is handed the embedding as stored, once a step
+    assert (ctx.stats.read_transposed + ctx.stats.copied_transposed
+            == int(cfg.tie_embeddings)), ctx.stats.describe()
 
 
 @pytest.mark.parametrize("kind", sorted(BLOCK_KINDS))

@@ -47,8 +47,9 @@ from repro.models import shard_ctx
 from repro.models.model import decode_init, init_params
 from repro.models.workload import gemm_workload
 from repro.train.steps import make_serve_step
-from serve_scopes import (_buffers, assert_names_every_part,
-                          compile_donated_steps, kv_copies, weight_copies)
+from serve_scopes import (_ARRAY, _NO_BUFFER, _buffers,
+                          assert_names_every_part, compile_donated_steps,
+                          kv_copies, weight_copies)
 
 from bench import harness  # on the path serve_scopes sets
 from bench.reduce import routed_gemms
@@ -187,22 +188,18 @@ def test_routed_decode_step_compiles(planner, chip_mesh, one_chip,
     assert_names_every_part(compiled.as_text())
 
 
-GUARD_VOCAB = 4096
-
-
 @pytest.fixture(scope="module",
                 params=["olmo-1b.azure-code", "phi4-mini-3.8b.azure-conv"])
 def cell_steps(request, chip_mesh, one_chip, tmp_path_factory):
     """A benchmark cell's routed serve step, compiled as the benchmark
     compiles it (cache donated) for the cell's prefill chunk and decode
-    step: two layers at full width. The vocabulary is cut to 4096 so that
-    the tied LM head's transpose and padding (2.46 GB at phi4-mini's
-    200064) do not set the temporaries the cache guard reads."""
+    step: two layers at full width and the full vocabulary. The tied LM
+    head reads the embedding as stored, so a decode step's temporaries
+    stay under one layer's K at phi4-mini's 200,064 tokens too."""
     cell = harness.load_cell(request.param)
     batch, chunk, max_len = (cell.traffic[k]
                              for k in ("batch", "chunk", "max_len"))
-    cfg = dataclasses.replace(get_config(cell.config["arch"]), n_layers=2,
-                              vocab=GUARD_VOCAB)
+    cfg = dataclasses.replace(get_config(cell.config["arch"]), n_layers=2)
     planner = serve.warm_plan_cache(
         cfg, batch, chunk, max_len, str(tmp_path_factory.mktemp("plans")),
         (1, 1), max_candidates=12)
@@ -272,6 +269,50 @@ def test_step_reads_layer_weights_in_place(cell_steps, kind):
     if kind == "prefill" and sliced_prefill:
         shapes = {s for s in shapes if len(s) == 3}
     assert weight_copies(steps[kind].as_text(), shapes, moves=True) == []
+
+
+def head_reads(hlo_text: str, k: int, n: int):
+    """What a compiled step's LM head reads of its (K, N) weight: the B
+    operand of each Pallas kernel under `lm_head`, and every buffer other
+    than the parameter and its views that holds the weight in any layout
+    (transposed, padded by up to a block in N, or with a unit axis)."""
+    kernels = []
+    for line in hlo_text.splitlines():
+        if ('custom_call_target="tpu_custom_call"' in line
+                and "/lm_head/" in line):
+            seg = line.split("operand_layout_constraints=")[1]
+            operands = [tuple(int(d) for d in a.split(",") if d)
+                        for a in _ARRAY.findall(seg.split("}}")[0])]
+            kernels.append(operands[-1])
+    copies = [(opcode, dims, op_name)
+              for _, opcode, dims, _, op_name in _buffers(hlo_text)
+              if opcode not in _NO_BUFFER
+              for d in dims
+              if len([x for x in d if x != 1]) == 2 and k in d
+              and any(n <= x < n + 1024 for x in d if x != k)]
+    return kernels, copies
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_step_reads_the_tied_head_as_stored(cell_steps, kind):
+    """A decode step's tied LM head (one row block) reads the embedding
+    (V, D) as stored: one Pallas kernel under `lm_head`, whose B is the
+    embedding's (1, V, D) view, and no buffer outside the parameter holds
+    the embedding transposed (D, V), padded (D, V') or broadcast
+    (1, D, V'). A prefill chunk's head, compute-bound at 4 and 12 row
+    blocks, is handed the one transposed copy (`MAX_TRANSPOSED_READS`),
+    unpadded: the plan's 128-column block divides both vocabularies. Each
+    is counted once."""
+    cfg, _, steps, stats = cell_steps
+    assert (stats.read_transposed, stats.copied_transposed) == (1, 1)
+    kernels, copies = head_reads(steps[kind].as_text(), cfg.d_model,
+                                 cfg.vocab)
+    if kind == "decode":
+        assert kernels == [(1, cfg.vocab, cfg.d_model)]
+        assert copies == []
+    else:
+        assert kernels == [(1, cfg.d_model, cfg.vocab)]
+        assert [opcode for opcode, _, _ in copies] == ["copy"], copies
 
 
 MLA_MOE_CELL = "deepseek-v2-236b-ep8.azure-conv-b128"
@@ -363,3 +404,16 @@ def test_mla_moe_step_reads_layer_weights_in_place(mla_moe_steps, kind):
     assert all(opcode == "copy" and dims[0][-1] < 128
                and "/while/" not in op_name
                for opcode, dims, op_name in left), left
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_mla_moe_step_reads_the_untied_head_as_before(mla_moe_steps, kind):
+    """DeepSeek-V2's untied head is a (D, V) matrix: its one kernel reads
+    `lm_head` (5120, 102400) as it did, with no copy, and nothing counts a
+    transposed weight."""
+    _, cfg, _, steps, stats = mla_moe_steps
+    assert (stats.read_transposed, stats.copied_transposed) == (0, 0)
+    kernels, copies = head_reads(steps[kind].as_text(), cfg.d_model,
+                                 cfg.vocab)
+    assert kernels == [(1, cfg.d_model, cfg.vocab)]
+    assert copies == []
